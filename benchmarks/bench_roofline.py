@@ -25,8 +25,10 @@ def run(emit) -> None:
     recs = load_records()
     if not recs:
         emit("roofline/missing", 0,
-             "run: PYTHONPATH=src python -m repro.launch.dryrun --all "
-             "--both-meshes --out results/dryrun")
+             "run: JAX_PLATFORMS=cpu XLA_FLAGS="
+             "--xla_force_host_platform_device_count=512 PYTHONPATH=src "
+             "python -m repro.launch.dryrun --all --both-meshes "
+             "--out results/dryrun")
         return
     n_ok = sum(r["status"] == "ok" for r in recs)
     n_skip = sum(r["status"] == "skipped" for r in recs)

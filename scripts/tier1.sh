@@ -1,7 +1,6 @@
 #!/usr/bin/env sh
-# Tier-1 verify (see ROADMAP.md). Runs everywhere: the test suite ships a
-# deterministic fallback for hypothesis (tests/optional_deps.py), so no
-# extra dependencies are required.
+# Tier-1 verify (see ROADMAP.md). Runs on the CPU; the property tests need
+# hypothesis.
 set -e
 cd "$(dirname "$0")/.."
 # docs gate: every package documented, every link/module/CLI-flag

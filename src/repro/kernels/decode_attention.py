@@ -22,6 +22,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.mosaic import BUDGET_BYTES, largest_block, tile_bytes
+
 Array = jax.Array
 NEG_INF = -1e30
 
@@ -78,17 +80,32 @@ def _decode_kernel(pos_ref, q_ref, k_ref, v_ref, o_ref,
         o_ref[0, ...] = out.astype(o_ref.dtype)
 
 
+def dense_block_k(w: int, kv: int, d: int, dtype) -> int:
+    """Cache rows per grid step: the largest divisor of the window whose
+    blocks fit the VMEM budget. Counted: the k and v blocks, each double-
+    buffered, plus their f32 working copies (upcast and relayout), all at
+    padded tile size — kv=2 heads pad to a full 8-row sublane tile."""
+    def fits(bk: int) -> bool:
+        stream = 4 * tile_bytes((bk, kv, d), dtype)
+        work = 4 * tile_bytes((bk, kv, d), jnp.float32)
+        return stream + work <= BUDGET_BYTES
+    return largest_block(w, fits, align=8)
+
+
 def decode_attention(
     q: Array, k_cache: Array, v_cache: Array, pos: Array, *,
     window: Optional[int] = None,
-    block_k: int = 512,
+    block_k: Optional[int] = None,
     interpret: bool = False,
 ) -> Array:
     """q: (B, H, D); caches: (B, W, KV, D); pos: (B,) int32 (uniform).
+    ``block_k`` None sizes the cache block from VMEM (``dense_block_k``).
     Returns (B, H, D)."""
     b, h, d = q.shape
     _, w, kv, _ = k_cache.shape
     groups = h // kv
+    if block_k is None:
+        block_k = dense_block_k(w, kv, d, k_cache.dtype)
     block_k = min(block_k, w)
     if w % block_k:
         raise ValueError(f"cache window {w} % block {block_k}")

@@ -22,6 +22,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.mosaic import dot_precision
+
 Array = jax.Array
 NEG_INF = -1e30
 
@@ -62,6 +64,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
     l_ref[...] = l_ref[...] * correction + p.sum(axis=-1)
     pv = jax.lax.dot_general(
         p.astype(v_ref.dtype), v_ref[0], (((1,), (0,)), ((), ())),
+        precision=dot_precision(v_ref.dtype),
         preferred_element_type=jnp.float32)
     acc_ref[...] = acc_ref[...] * correction[:, None] + pv
     m_ref[...] = m_new

@@ -21,6 +21,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.mosaic import dot_precision
+
 Array = jax.Array
 
 
@@ -32,7 +34,8 @@ def _matmul_kernel(a_ref, b_ref, o_ref, acc_ref, *, n_k: int):
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     acc_ref[...] += jnp.dot(
-        a_ref[...], b_ref[...], preferred_element_type=jnp.float32)
+        a_ref[...], b_ref[...], precision=dot_precision(a_ref.dtype),
+        preferred_element_type=jnp.float32)
 
     @pl.when(k == n_k - 1)
     def _store():

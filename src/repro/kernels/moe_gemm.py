@@ -29,6 +29,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.mosaic import (BUDGET_BYTES, dot_precision, largest_block,
+                                  tile_bytes)
+
 Array = jax.Array
 
 
@@ -36,6 +39,7 @@ def _grouped_kernel(gid_ref, x_ref, w_ref, o_ref):
     i = pl.program_id(0)
     g = gid_ref[i]
     acc = jnp.dot(x_ref[...], w_ref[0],
+                  precision=dot_precision(x_ref.dtype),
                   preferred_element_type=jnp.float32)
     # Pad-only / non-local tile: the weight DMA fetched expert 0's tile
     # (index_map clamps the sentinel); discard it and write zeros.
@@ -52,15 +56,28 @@ def _grouped_kernel_scaled(gid_ref, scale_ref, x_ref, w_ref, o_ref):
     o_ref[...] = jnp.where(g >= 0, acc, 0.0).astype(o_ref.dtype)
 
 
+def grouped_block_f(block_m: int, d: int, f: int, x_dtype, w_dtype,
+                    out_dtype) -> int:
+    """Output columns per grid step: the largest 128-multiple divisor of
+    F whose double-buffered x, weight and output blocks fit the VMEM
+    budget (a full-depth (D, block_f) weight tile dominates)."""
+    def fits(bf: int) -> bool:
+        return 2 * (tile_bytes((block_m, d), x_dtype)
+                    + tile_bytes((d, bf), w_dtype)
+                    + tile_bytes((block_m, bf), out_dtype)) <= BUDGET_BYTES
+    return largest_block(f, fits, align=128)
+
+
 def grouped_matmul(x: Array, w: Array, group_ids: Array, *,
                    w_scale: Optional[Array] = None,
-                   block_f: int = 512,
+                   block_f: Optional[int] = None,
                    out_dtype=None,
                    interpret: bool = False) -> Array:
     """x: (M, D) sorted+padded token rows; w: (E, D, F) expert weights;
     group_ids: (M // block_m,) int32 expert id per m-tile (-1 sentinel
     for pad-only tiles). block_m is implied by M // len(group_ids).
-    ``w_scale`` (E,) fp32 dequantizes int8 ``w`` per expert. -> (M, F).
+    ``w_scale`` (E,) fp32 dequantizes int8 ``w`` per expert. ``block_f``
+    None sizes the column block from VMEM (``grouped_block_f``). -> (M, F).
     """
     m, d = x.shape
     e, d2, f = w.shape
@@ -70,11 +87,13 @@ def grouped_matmul(x: Array, w: Array, group_ids: Array, *,
     if nb == 0 or m % nb:
         raise ValueError(f"M={m} not divisible into {nb} m-tiles")
     block_m = m // nb
+    out_dtype = out_dtype or x.dtype
+    if block_f is None:
+        block_f = grouped_block_f(block_m, d, f, x.dtype, w.dtype, out_dtype)
     block_f = min(block_f, f)
     if f % block_f:
         raise ValueError(f"F={f} not divisible by block_f={block_f}")
     group_ids = group_ids.astype(jnp.int32)
-    out_dtype = out_dtype or x.dtype
 
     if w_scale is None:
         kernel = _grouped_kernel

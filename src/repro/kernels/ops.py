@@ -12,7 +12,6 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.kernels import ref
@@ -60,7 +59,7 @@ def flash_attention(q: Array, k: Array, v: Array, *, impl: str = "pallas",
 def decode_attention(q: Array, k_cache: Array, v_cache: Array, pos: Array,
                      *, impl: str = "pallas",
                      window: Optional[int] = None,
-                     block_k: int = 512) -> Array:
+                     block_k: Optional[int] = None) -> Array:
     if impl == "ref":
         return ref.decode_attention_ref(q, k_cache, v_cache, pos,
                                         window=window)
@@ -178,8 +177,8 @@ def _paged_sharded(local_fn, mesh, data_axis, model_axis, head_axis, q,
                                              model_axis, h, kv, m)
         return local_fn(ql, kp, vp, tab, posl, ks, vs)
 
-    return shard_map(body, mesh=mesh, in_specs=tuple(specs),
-                     out_specs=qspec, check_rep=False)(*operands)
+    return jax.shard_map(body, mesh=mesh, in_specs=tuple(specs),
+                         out_specs=qspec, check_vma=False)(*operands)
 
 
 @partial(jax.jit, static_argnames=("impl", "window", "mesh", "data_axis",
@@ -248,7 +247,7 @@ def _grouped_local(x, w, group_ids, w_scale, impl, block_f):
                                    "expert_axis"))
 def grouped_matmul(x: Array, w: Array, group_ids: Array, *,
                    w_scale: Optional[Array] = None,
-                   impl: str = "pallas", block_f: int = 512,
+                   impl: str = "pallas", block_f: Optional[int] = None,
                    mesh=None, expert_axis: str = "data") -> Array:
     """m-grouped contiguous GEMM over expert-sorted token rows.
 
@@ -285,8 +284,8 @@ def grouped_matmul(x: Array, w: Array, group_ids: Array, *,
     if has_scale:
         operands.append(w_scale)
         specs.append(P(expert_axis))
-    return shard_map(body, mesh=mesh, in_specs=tuple(specs),
-                     out_specs=P(None, None), check_rep=False)(*operands)
+    return jax.shard_map(body, mesh=mesh, in_specs=tuple(specs),
+                         out_specs=P(None, None), check_vma=False)(*operands)
 
 
 @partial(jax.jit, static_argnames=("impl", "chunk"))

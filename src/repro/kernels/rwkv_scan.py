@@ -38,8 +38,16 @@ def _wkv_kernel(r_ref, k_ref, v_ref, lw_ref, u_ref, o_ref, s_ref, *,
     lw = lw_ref[0]  # per-step log decay, <= 0, clamped
     u = u_ref[0]  # (1, hd) bonus
 
-    cum = jnp.cumsum(lw, axis=0)  # (c, hd) within-chunk cumulative
-    total = cum[-1]  # (hd,)
+    # (c, hd) within-chunk inclusive prefix sum, as a lower-triangular
+    # matmul (Mosaic lowers no cumsum); HIGHEST keeps it f32, since the
+    # sums feed exp()
+    t_row = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
+    t_col = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
+    cum = jax.lax.dot_general(
+        (t_row >= t_col).astype(jnp.float32), lw, (((1,), (0,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32)
+    total = jnp.sum(lw, axis=0, keepdims=True)  # (1, hd)
     cum_excl = cum - lw
 
     # inter-chunk: r_t reads state decayed from chunk start to t-1
@@ -65,11 +73,16 @@ def _wkv_kernel(r_ref, k_ref, v_ref, lw_ref, u_ref, o_ref, s_ref, *,
     o_ref[0, ...] = inter + intra + bonus * v
 
     # state update: S' = diag(exp(total)) S + sum_s (k_s exp(total-cum_s))^T v_s
-    k_out = k * jnp.exp(total[None, :] - cum)
+    k_out = k * jnp.exp(total - cum)
     delta = jax.lax.dot_general(
         k_out, v, (((0,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)  # (hd_k, hd_v)
-    s_ref[...] = s_ref[...] * jnp.exp(total)[:, None] + delta
+    # the same total as a (hd_k, 1) column, to scale the state's rows
+    total_col = jax.lax.dot_general(
+        lw, jnp.ones((chunk, 1), jnp.float32), (((0,), (0,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32)
+    s_ref[...] = s_ref[...] * jnp.exp(total_col) + delta
 
 
 def rwkv_wkv(
@@ -93,10 +106,12 @@ def rwkv_wkv(
             pl.BlockSpec((1, chunk, hd), lambda h, c: (h, c, 0)),
             pl.BlockSpec((1, chunk, hd), lambda h, c: (h, c, 0)),
             pl.BlockSpec((1, chunk, hd), lambda h, c: (h, c, 0)),
-            pl.BlockSpec((1, hd), lambda h, c: (h, 0)),
+            # (1, 1, hd): a block's last two dims must be (8k, 128k) or
+            # the array's own, so u carries a unit axis
+            pl.BlockSpec((1, 1, hd), lambda h, c: (h, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, chunk, hd), lambda h, c: (h, c, 0)),
         out_shape=jax.ShapeDtypeStruct((bh, s, hd), jnp.float32),
         scratch_shapes=[pltpu.VMEM((hd, hd), jnp.float32)],
         interpret=interpret,
-    )(r, k, v, logw, u)
+    )(r, k, v, logw, u[:, None, :])

@@ -1,13 +1,11 @@
-import os
-os.environ["XLA_FLAGS"] = (
-    "--xla_force_host_platform_device_count=512 "
-    + os.environ.get("XLA_FLAGS", ""))
-
 """Multi-pod dry-run: lower + compile every (arch x shape) cell on the
 production meshes and extract memory/cost/collective artifacts.
 
-MUST be the first import in the process (XLA locks device count at init):
-the two lines above run before any jax import, per the task spec.
+A CPU run over fake host devices, which the caller provides (XLA fixes
+the device count when it starts, and this module never sets it):
+
+  JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=512 \
+  PYTHONPATH=src python -m repro.launch.dryrun --all
 
 For every cell this emits a JSON record with:
   - compile status and wall time;
@@ -19,7 +17,7 @@ For every cell this emits a JSON record with:
   - trip-count-aware FLOPs / HBM bytes / collective bytes (hlo_analysis);
   - the three roofline terms vs the TPU v5e target (core/roofline).
 
-Usage:
+Usage (with the environment above):
   python -m repro.launch.dryrun --arch qwen2_5_3b --shape train_4k
   python -m repro.launch.dryrun --all [--multi-pod|--single-pod]
   python -m repro.launch.dryrun --all --both-meshes --out results/dryrun
@@ -183,6 +181,12 @@ def main() -> None:
         os.makedirs(args.out, exist_ok=True)
         out_path = os.path.join(args.out, "dryrun.jsonl")
 
+    need = 512 if any(meshes) else 256
+    if jax.device_count() < need:
+        raise SystemExit(
+            f"dry-run needs {need} devices, have {jax.device_count()}: run "
+            f"with JAX_PLATFORMS=cpu XLA_FLAGS="
+            f"--xla_force_host_platform_device_count=512")
     mesh_cache = {}
     n_ok = n_skip = n_fail = 0
     for arch, shape, mp in cells:

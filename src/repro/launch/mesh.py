@@ -1,49 +1,39 @@
-"""Production mesh construction.
+"""Mesh construction: every mesh in the repo is built here.
 
 Single pod: (16, 16) = 256 chips (data, model) — one TPU v5e pod.
 Multi-pod: (2, 16, 16) = 512 chips (pod, data, model) — two pods; the
 "pod" axis carries synchronous data parallelism exactly as the paper's
 multi-pod Gemini training does.
 
-``make_production_mesh`` is a function (not a module constant) so importing
-this module never touches jax device state; the dry-run sets
-XLA_FLAGS=--xla_force_host_platform_device_count=512 before any jax import.
+Axes are ``Auto``: ``jax.make_mesh`` defaults to ``Explicit`` axes, under
+which the serving code's ``with_sharding_constraint`` annotations are
+rejected. The constructors are functions (not module constants), so
+importing this module never touches jax device state.
 """
 
 from __future__ import annotations
 
-from typing import Optional, Sequence, Tuple
+from typing import Sequence
 
 import numpy as np
 
 
-def _auto_axis_types(n: int) -> dict:
-    """axis_types kwarg when this jax has AxisType (>=0.5); Auto is already
-    the default on older versions, so omit it there."""
+def make_mesh(shape: Sequence[int], axes: Sequence[str]):
+    """Mesh of ``prod(shape)`` devices with ``Auto`` axis types."""
     import jax
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    return {"axis_types": (axis_type.Auto,) * n}
+    return jax.make_mesh(
+        tuple(shape), tuple(axes),
+        axis_types=(jax.sharding.AxisType.Auto,) * len(shape))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
-    import jax
-    shape = (2, 16, 16) if multi_pod else (16, 16)
-    axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **_auto_axis_types(len(axes)))
-
-
-def make_mesh(shape: Sequence[int], axes: Sequence[str]):
-    """Arbitrary mesh (tests use small ones, e.g. (2,2,2) on 8 devices)."""
-    import jax
-    return jax.make_mesh(tuple(shape), tuple(axes),
-                         **_auto_axis_types(len(shape)))
+    if multi_pod:
+        return make_mesh((2, 16, 16), ("pod", "data", "model"))
+    return make_mesh((16, 16), ("data", "model"))
 
 
 def mesh_for_devices(n: int, *, multi_pod: bool = False):
     """Scaled-down mesh with the production axis structure for n devices."""
-    import jax
     if multi_pod:
         if n % 2:
             raise ValueError("multi-pod mesh needs even device count")

@@ -8,14 +8,18 @@ Two modes:
     the continuous-batching scheduler, reporting tokens/s, occupancy and
     preemptions.
 
-``--mesh D,M`` serves on a (data, model) mesh — on a CPU host the device
-count is forced to D*M fake devices BEFORE jax initializes (same trick as
-dryrun/mesh), so the sharded datapath is exercisable anywhere. Add
+``--mesh D,M`` serves on a (data, model) mesh of D*M devices (on a CPU
+host, give the process fake devices yourself:
+``XLA_FLAGS=--xla_force_host_platform_device_count=N``). Add
 ``--disaggregate`` for prefill/decode disaggregation with ``N``
 ``--prefill-workers`` handing pages over a modeled ``--link`` (ici|dcn).
 
+The kernels follow the platform: on a TPU the paged attention and the
+grouped MoE GEMM run as compiled Pallas kernels, elsewhere as jnp.
+
   PYTHONPATH=src python -m repro.launch.serve --arch qwen2_0_5b --smoke \
       --batch 4 --prompt-len 32 --max-new 16
+  XLA_FLAGS=--xla_force_host_platform_device_count=8 JAX_PLATFORMS=cpu \
   PYTHONPATH=src python -m repro.launch.serve --arch qwen2_0_5b --smoke \
       --trace 16 --max-batch 4 --chunk 8 --mesh 4,2 --disaggregate
 """
@@ -23,40 +27,18 @@ dryrun/mesh), so the sharded datapath is exercisable anywhere. Add
 from __future__ import annotations
 
 import argparse
-import os
-import re
-import sys
 import time
-
-
-def _parse_mesh_argv() -> tuple:
-    """Pre-parse ``--mesh D,M`` from argv (before the jax import below:
-    XLA locks the device count at first init, so the host-platform fake
-    device count must be in XLA_FLAGS already)."""
-    for i, a in enumerate(sys.argv):
-        m = (re.fullmatch(r"--mesh=(\d+),(\d+)", a)
-             or (re.fullmatch(r"(\d+),(\d+)", sys.argv[i + 1])
-                 if a == "--mesh" and i + 1 < len(sys.argv) else None))
-        if m:
-            return int(m.group(1)), int(m.group(2))
-    return None
-
-
-_MESH_SHAPE = _parse_mesh_argv()
-if _MESH_SHAPE is not None and "--xla_force_host_platform_device_count" \
-        not in os.environ.get("XLA_FLAGS", ""):
-    os.environ["XLA_FLAGS"] = (
-        f"--xla_force_host_platform_device_count="
-        f"{_MESH_SHAPE[0] * _MESH_SHAPE[1]} "
-        + os.environ.get("XLA_FLAGS", ""))
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.registry import get_arch, get_smoke
+from repro.launch.mesh import make_mesh
+from repro.launch.runtime import use_compile_cache
 from repro.models import api
 from repro.models.blocks import ModelContext
+from repro.models.config import ModelConfig
 from repro.models.params import init_params
 from repro.obs.trace import SpanTracer
 from repro.serve.admission import AdmissionController, AdmissionPolicy
@@ -113,6 +95,43 @@ def dump_telemetry(engine, args) -> None:
               f"({len(engine.steptrace)} events)")
 
 
+def build_engine(cfg: ModelConfig, *, window: int, max_batch: int = 4,
+                 chunk: int = 8, page_size: int = 16, draft_k: int = 0,
+                 prefix_cache: bool = True, prefill_chunk: int = 128,
+                 kv_int8: bool = False, moe_dispatch: str = "grouped",
+                 temperature: float = 0.0, mesh=None,
+                 rules: str = "baseline_dp_tp", disaggregate: bool = False,
+                 prefill_workers: int = 1, link: str = "ici",
+                 tracer=None, faults=None, admission=None,
+                 attn_impl: str | None = None) -> ServeEngine:
+    """The serving engine the CLI runs, for ``cfg`` with a ``window``-token
+    budget per request (prompt + generated).
+
+    ``attn_impl`` None picks the kernels from the platform: the compiled
+    Pallas kernels on a TPU, jnp elsewhere; a caller names it only to
+    build a reference engine. Speculation and the prefix cache apply to
+    the paged families and are switched off for the others."""
+    if attn_impl is None:
+        attn_impl = "pallas" if jax.default_backend() == "tpu" else "xla"
+    ctx = ModelContext(
+        compute_dtype=jnp.float32, q_chunk=1024, mamba_chunk=16,
+        rwkv_chunk=8, attn_impl=attn_impl,
+        decode_cache_dtype=jnp.int8 if kv_int8 else None,
+        moe_dispatch=moe_dispatch)
+    paged = api.supports_paged_decode(cfg)
+    return ServeEngine(cfg, ctx, window=window, max_batch=max_batch,
+                       chunk=chunk, page_size=page_size,
+                       temperature=temperature,
+                       draft_k=draft_k if paged else 0,
+                       prefix_cache=paged and prefix_cache,
+                       prefill_chunk=prefill_chunk,
+                       mesh=mesh, rules=rules,
+                       disaggregate=disaggregate,
+                       prefill_workers=prefill_workers,
+                       transfer_link=link, tracer=tracer,
+                       faults=faults, admission=admission)
+
+
 def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
@@ -144,8 +163,7 @@ def main() -> None:
     ap.add_argument("--temperature", type=float, default=0.0)
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--mesh", default=None, metavar="D,M",
-                    help="serve on a (data, model) mesh of D*M devices "
-                         "(forced as fake host devices on CPU)")
+                    help="serve on a (data, model) mesh of D*M devices")
     ap.add_argument("--rules", default="baseline_dp_tp",
                     help="AxisRules set for the serving mesh")
     ap.add_argument("--disaggregate", action="store_true",
@@ -179,16 +197,19 @@ def main() -> None:
                          "via fleet.perf.StepTimeModel.from_trace)")
     args = ap.parse_args()
 
+    use_compile_cache()
     mesh = None
     if args.mesh:
         d, m = (int(x) for x in args.mesh.split(","))
         if jax.device_count() < d * m:
             raise SystemExit(f"--mesh {d},{m} needs {d * m} devices, "
                              f"have {jax.device_count()}")
-        mesh = jax.make_mesh((d, m), ("data", "model"))
+        mesh = make_mesh((d, m), ("data", "model"))
 
     if args.bist:
-        res = startup_bist(interpret=True)
+        # on a TPU the goldens go through the compiled kernels, so the
+        # self-test screens the hardware and not the interpreter
+        res = startup_bist(interpret=jax.default_backend() != "tpu")
         print(f"bist: matmul max_err={res.matmul_report.max_abs_err:.3e} "
               f"paged_decode max_err={res.paged_decode_max_err:.3e} "
               f"-> {'PASS' if res.passed else 'FAIL'}")
@@ -197,19 +218,12 @@ def main() -> None:
                 "bist: kernel self-test failed; refusing to serve")
 
     cfg = get_smoke(args.arch) if args.smoke else get_arch(args.arch)
-    ctx = ModelContext(
-        compute_dtype=jnp.float32, q_chunk=1024, mamba_chunk=16,
-        rwkv_chunk=8,
-        decode_cache_dtype=jnp.int8 if args.kv_int8 else None,
-        moe_dispatch=args.moe_dispatch)
     params = init_params(jax.random.key(args.seed), api.model_specs(cfg))
     if args.quantize == "fp8":
         params = quantize_weights(params, jnp.float8_e4m3fn)
     elif args.quantize == "int8":
         params = quantize_weights(params, jnp.int8)  # storage demo only
 
-    window = args.prompt_len + args.max_new
-    paged = api.supports_paged_decode(cfg)
     tracer = SpanTracer() if args.trace_out else None
     faults = None
     if args.chaos is not None:
@@ -221,17 +235,16 @@ def main() -> None:
         admission = AdmissionController(AdmissionPolicy(
             ttft_deadline_steps=args.ttft_deadline,
             spec_off_queue_depth=args.spec_off_depth))
-    engine = ServeEngine(cfg, ctx, window=window, max_batch=args.max_batch,
-                         chunk=args.chunk, page_size=args.page_size,
-                         temperature=args.temperature,
-                         draft_k=args.draft_k if paged else 0,
-                         prefix_cache=(paged and not args.no_prefix_cache),
-                         prefill_chunk=args.prefill_chunk,
-                         mesh=mesh, rules=args.rules,
-                         disaggregate=args.disaggregate,
-                         prefill_workers=args.prefill_workers,
-                         transfer_link=args.link, tracer=tracer,
-                         faults=faults, admission=admission)
+    engine = build_engine(
+        cfg, window=args.prompt_len + args.max_new,
+        max_batch=args.max_batch, chunk=args.chunk,
+        page_size=args.page_size, draft_k=args.draft_k,
+        prefix_cache=not args.no_prefix_cache,
+        prefill_chunk=args.prefill_chunk, kv_int8=args.kv_int8,
+        moe_dispatch=args.moe_dispatch, temperature=args.temperature,
+        mesh=mesh, rules=args.rules, disaggregate=args.disaggregate,
+        prefill_workers=args.prefill_workers, link=args.link,
+        tracer=tracer, faults=faults, admission=admission)
     mode = "paged" if engine.paged else "dense"
     if mesh is not None:
         mode += "/sharded"

@@ -5,7 +5,7 @@ the full configs on the production meshes (that path is exercised by
 launch/dryrun.py, which only compiles).
 
   PYTHONPATH=src python -m repro.launch.train --arch qwen2_0_5b --smoke \
-      --steps 50 --batch 8 --seq 64 --ckpt-dir /tmp/ckpt
+      --steps 50 --batch 8 --seq 64 --ckpt-dir ckpt/qwen_smoke
 """
 
 from __future__ import annotations
@@ -23,6 +23,7 @@ from repro.core.cci import CCI_BY_NAME, CarbonLedger
 from repro.core.ocs import OCSPodScheduler
 from repro.data.pipeline import DataConfig, DataPipeline
 from repro.launch.cells import make_optimizer
+from repro.launch.runtime import CHECKOUT_ROOT, use_compile_cache
 from repro.models.blocks import ModelContext
 from repro.models.config import ModelConfig
 from repro.obs.trace import SpanTracer
@@ -38,8 +39,10 @@ def build_trainer(cfg: ModelConfig, *, batch: int, seq: int,
                   failures: Optional[Dict[int, int]] = None,
                   compute_dtype=jnp.float32,
                   metrics=None, tracer=None):
+    # jnp ("xla") attention on every platform: the Pallas kernels have no
+    # backward pass, so the train step cannot differentiate through them
     ctx = ModelContext(compute_dtype=compute_dtype, q_chunk=2048,
-                       mamba_chunk=64, rwkv_chunk=16)
+                       mamba_chunk=64, rwkv_chunk=16, attn_impl="xla")
     opt = make_optimizer(optimizer, total_steps=10_000)
     step_fn = jax.jit(make_train_step(
         cfg, ctx, opt, TrainSettings(microbatches=microbatches)),
@@ -72,7 +75,7 @@ def main() -> None:
     ap.add_argument("--batch", type=int, default=8)
     ap.add_argument("--seq", type=int, default=64)
     ap.add_argument("--microbatches", type=int, default=1)
-    ap.add_argument("--ckpt-dir", default="/tmp/repro_ckpt")
+    ap.add_argument("--ckpt-dir", default=str(CHECKOUT_ROOT / "ckpt"))
     ap.add_argument("--ckpt-every", type=int, default=20)
     ap.add_argument("--fail-at", type=int, default=None,
                     help="inject a cube failure at this step")
@@ -85,6 +88,7 @@ def main() -> None:
                     help="write the measured step-time trace (replayable "
                          "via fleet.perf.StepTimeModel.from_trace)")
     args = ap.parse_args()
+    use_compile_cache()
 
     cfg = get_smoke(args.arch) if args.smoke else get_arch(args.arch)
     failures = {args.fail_at: 0} if args.fail_at is not None else None

@@ -35,7 +35,6 @@ from typing import Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 
 from repro.kernels import ops as kops
 from repro.models.config import ModelConfig
@@ -297,8 +296,8 @@ def _moe_ffn_grouped(params: Dict[str, Array], x: Array, cfg: ModelConfig,
         wspec = P(expert_axis) if ep > 1 else P()
         in_specs = [P(), P(), P()] + [wspec] * len(names) * (
             2 if has_scale else 1)
-        out = shard_map(run, mesh=mesh, in_specs=tuple(in_specs),
-                        out_specs=P(), check_rep=False)(*args)
+        out = jax.shard_map(run, mesh=mesh, in_specs=tuple(in_specs),
+                            out_specs=P(), check_vma=False)(*args)
     return out.reshape(b, s, d), aux
 
 

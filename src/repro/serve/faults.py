@@ -194,14 +194,19 @@ def startup_bist(*, interpret: bool = True, tol: float = 5e-2,
     through the paged-attention kernel — both vs independent float64
     numpy oracles. ``interpret=True`` runs the kernels in interpret mode
     (CI / CPU hosts); on TPU pass False to screen the actual hardware.
-    ``matmul_fn``/``decode_fn`` exist for fault-injection tests
+    The goldens' tolerance assumes f32 products, so the kernels run under
+    "highest" matmul precision (a TPU's default rounds f32 operands to
+    bf16). ``matmul_fn``/``decode_fn`` exist for fault-injection tests
     (``core.sdc.faulty_wrap``)."""
+    import jax
+
     from repro.core.sdc import FBIST
     from repro.kernels.matmul import matmul
 
     mm = matmul_fn or (lambda a, b: matmul(a, b, interpret=interpret))
-    report = FBIST(m=128, k=128, n=128, tol=tol).run(mm)
-    pd_ok, pd_err = _paged_decode_check(interpret, tol, decode_fn)
+    with jax.default_matmul_precision("highest"):
+        report = FBIST(m=128, k=128, n=128, tol=tol).run(mm)
+        pd_ok, pd_err = _paged_decode_check(interpret, tol, decode_fn)
     return BISTResult(passed=report.passed and pd_ok,
                       matmul_report=report,
                       paged_decode_ok=pd_ok,

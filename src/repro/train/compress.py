@@ -69,7 +69,6 @@ def make_compressed_grad_fn(
     """
     if "pod" not in mesh.axis_names:
         raise ValueError("mesh has no 'pod' axis")
-    auto = frozenset(a for a in mesh.axis_names if a != "pod")
     vg = jax.value_and_grad(loss_fn, has_aux=True)
 
     def local(params, batch, err):
@@ -86,17 +85,9 @@ def make_compressed_grad_fn(
 
     in_specs = (P(), batch_specs, P())
     out_specs = ((P(), P()), P(), P())
-    if hasattr(jax, "shard_map"):  # jax >= 0.5
-        return jax.shard_map(local, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False,
-                             axis_names={"pod"})
-    # jax 0.4.x: partial-auto (auto={data,model}) trips an SPMD-partitioner
-    # check on the scalar-scale all_gather in this XLA build; run the whole
-    # exchange fully manual there instead (data/model stay unsharded inside).
-    del auto
-    from jax.experimental.shard_map import shard_map as _shard_map
-    return _shard_map(local, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, check_rep=False)
+    return jax.shard_map(local, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False,
+                         axis_names={"pod"})
 
 
 def wire_bytes_per_step(n_params: int, pods: int,

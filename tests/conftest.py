@@ -15,6 +15,9 @@ def run_in_subprocess(code: str, devices: int = 8, timeout: int = 420) -> str:
     count at first init), so anything needing a mesh > 1 runs here.
     """
     env = dict(os.environ)
+    # fake host devices are a CPU run by design: the child never reaches
+    # for an accelerator the parent may already hold
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = (f"--xla_force_host_platform_device_count={devices} "
                         + env.get("XLA_FLAGS", ""))
     env["PYTHONPATH"] = SRC + os.pathsep + env.get("PYTHONPATH", "")

@@ -1,7 +1,8 @@
 """Core paper-contribution modules: Table-1 claims, topology, OCS scheduler
 invariants (hypothesis), goodput, CCI relations, SDC detection."""
 
-from optional_deps import hypothesis, st  # real or deterministic shim
+import hypothesis
+import hypothesis.strategies as st
 import numpy as np
 import pytest
 

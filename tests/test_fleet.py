@@ -8,7 +8,8 @@ sim-vs-ResilientTrainer bridge."""
 import json
 
 import pytest
-from optional_deps import hypothesis, st  # real or deterministic shim
+import hypothesis
+import hypothesis.strategies as st
 
 from repro.core import hwspec
 from repro.core.goodput import GoodputLedger, modeled_goodput

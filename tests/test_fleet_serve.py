@@ -10,7 +10,8 @@ import json
 from pathlib import Path
 
 import pytest
-from optional_deps import hypothesis, st  # real or deterministic shim
+import hypothesis
+import hypothesis.strategies as st
 
 from repro.core import hwspec
 from repro.fleet import (ArrivalProcess, FleetConfig, FleetSimulator,

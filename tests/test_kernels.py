@@ -1,7 +1,8 @@
 """Per-kernel validation: interpret-mode Pallas vs pure-jnp oracles,
 swept over shapes and dtypes, plus hypothesis property tests."""
 
-from optional_deps import hypothesis, st  # real or deterministic shim
+import hypothesis
+import hypothesis.strategies as st
 import jax
 import jax.numpy as jnp
 import numpy as np

@@ -29,7 +29,8 @@ from repro.serve.faults import FaultInjector, FaultPlan, startup_bist
 from repro.serve.scheduler import (ContinuousBatchingScheduler,
                                    PrefillWorkerPool, Request)
 
-from optional_deps import hypothesis, st
+import hypothesis
+import hypothesis.strategies as st
 
 jax.config.update("jax_default_matmul_precision", "highest")
 

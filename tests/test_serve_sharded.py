@@ -12,6 +12,7 @@ import numpy as np
 import pytest
 
 from repro.configs.registry import get_smoke
+from repro.launch.mesh import make_mesh
 from repro.models import api
 from repro.models.blocks import ModelContext
 from repro.models.params import init_params
@@ -41,6 +42,7 @@ from repro.configs.registry import get_smoke
 from repro.models import api
 from repro.models.blocks import ModelContext
 from repro.models.params import init_params
+from repro.launch.mesh import make_mesh
 from repro.serve.engine import ServeEngine
 from repro.serve.scheduler import Request
 
@@ -51,7 +53,7 @@ ps = [rng.integers(0, cfg.vocab_size, int(rng.integers(8, 15)))
       for _ in range(4)]
 reqs = lambda: [Request(rid=i, prompt=p, max_new=8)
                 for i, p in enumerate(ps)]
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+mesh = make_mesh((4, 2), ("data", "model"))
 for cdt in (None, jnp.bfloat16, jnp.int8):
     ctx = ModelContext(compute_dtype=jnp.float32, q_chunk=64,
                        decode_cache_dtype=cdt)
@@ -82,6 +84,7 @@ from repro.configs.registry import get_smoke
 from repro.models import api
 from repro.models.blocks import ModelContext
 from repro.models.params import init_params
+from repro.launch.mesh import make_mesh
 from repro.serve.engine import ServeEngine
 from repro.serve.scheduler import Request
 
@@ -95,7 +98,7 @@ ps = [rng.integers(0, cfg.vocab_size, int(rng.integers(6, 12)))
 reqs = lambda: [Request(rid=i, prompt=p, max_new=6)
                 for i, p in enumerate(ps)]
 solo = ServeEngine(cfg, ctx, window=32, max_batch=2, chunk=4, page_size=4)
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_mesh((2, 4), ("data", "model"))
 shard = ServeEngine(cfg, ctx, window=32, max_batch=2, chunk=4,
                     page_size=4, mesh=mesh)
 drops = " ; ".join(shard.sharding_report["dropped_rules"])
@@ -302,7 +305,7 @@ def test_mrope_dense_chunked_prefill():
 
 
 def test_summarize_dropped_renders_fallbacks():
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     lines = summarize_dropped([("kv_heads", 2), ("kv_heads", 2),
                                ("vocab", 211)],
                               mesh, RULE_SETS["baseline_dp_tp"])
@@ -320,12 +323,13 @@ from repro.configs.registry import get_smoke
 from repro.models import api
 from repro.models.blocks import ModelContext
 from repro.models.params import init_params
+from repro.launch.mesh import make_mesh
 from repro.serve.engine import ServeEngine
 
 cfg = get_smoke("mixtral_8x22b")
 params = init_params(jax.random.key(0), api.model_specs(cfg))
 ctx = ModelContext(compute_dtype=jnp.float32, q_chunk=64)
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_mesh((2, 4), ("data", "model"))
 eng = ServeEngine(cfg, ctx, window=32, max_batch=2, chunk=4, page_size=4,
                   mesh=mesh)
 before = list(eng.sharding_report["dropped_rules"])

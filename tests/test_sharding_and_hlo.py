@@ -1,7 +1,8 @@
 """Sharding rule resolution (hypothesis properties) + HLO analyzer units +
 multi-device subprocess integration (mini dry-run, compressed grads)."""
 
-from optional_deps import hypothesis, st  # real or deterministic shim
+import hypothesis
+import hypothesis.strategies as st
 import numpy as np
 import pytest
 

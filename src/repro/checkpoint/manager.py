@@ -17,6 +17,12 @@ synchronous jobs survive failures. Design points implemented here:
     only sees complete manifests.
   * **Integrity**: each leaf records a CRC32; restore verifies (detects the
     paper's silent-corruption concern at the storage layer).
+
+With an enabled tracer, a save is four spans on the ``ckpt`` process
+row: ``gather`` (device_get and flatten), ``write`` (the ``.npy``
+files), ``crc`` (every leaf's checksum) and ``commit`` (manifest,
+rename, gc). The writer thread of an async save has its own lane;
+``train_ckpt_bytes`` counts the bytes gathered.
 """
 
 from __future__ import annotations
@@ -32,9 +38,13 @@ from typing import Any, Callable, Dict, List, Optional, Tuple
 import jax
 import numpy as np
 
+from repro.obs.metrics import MetricsRegistry
+from repro.obs.trace import SpanTracer
+
 PyTree = Any
 
 _SEP = "__"
+_WRITER_TID = 1  # trace lane of the async writer thread
 
 
 def _flatten(tree: PyTree) -> List[Tuple[str, np.ndarray]]:
@@ -58,9 +68,19 @@ def _path_str(entry) -> str:
 class CheckpointManager:
     directory: str
     keep: int = 3
+    tracer: Optional[SpanTracer] = None  # None -> disabled
+    metrics: Optional[MetricsRegistry] = None  # None -> fresh enabled one
 
     def __post_init__(self) -> None:
         os.makedirs(self.directory, exist_ok=True)
+        if self.tracer is None:
+            self.tracer = SpanTracer(enabled=False)
+        if self.metrics is None:
+            self.metrics = MetricsRegistry()
+        self._bytes = self.metrics.counter("train_ckpt_bytes")
+        self._trace_pid = self.tracer.process("ckpt")
+        self.tracer.thread(self._trace_pid, 0, "save")
+        self.tracer.thread(self._trace_pid, _WRITER_TID, "writer")
         self._thread: Optional[threading.Thread] = None
         self._error: Optional[BaseException] = None
         # the step restore() actually loaded — older than the requested
@@ -75,33 +95,40 @@ class CheckpointManager:
              ) -> None:
         """Snapshot to host and write asynchronously (unless blocking)."""
         self.wait()  # one outstanding write at a time
-        host_state = jax.device_get(state)
-        leaves = _flatten(host_state)
-        treedef = jax.tree_util.tree_structure(host_state)
+        tr, pid = self.tracer, self._trace_pid
+        with tr.span("gather", pid=pid, cat="ckpt", args={"step": step}):
+            host_state = jax.device_get(state)
+            leaves = _flatten(host_state)
+            treedef = jax.tree_util.tree_structure(host_state)
+            self._bytes.inc(sum(arr.nbytes for _, arr in leaves))
+        tid = 0 if blocking else _WRITER_TID
 
         def write() -> None:
             try:
                 final = os.path.join(self.directory, f"step_{step:08d}")
                 tmp = final + ".tmp"
-                if os.path.exists(tmp):
-                    shutil.rmtree(tmp)
-                os.makedirs(tmp)
-                manifest = {"step": step, "treedef": str(treedef),
-                            "leaves": {}}
-                for key, arr in leaves:
-                    fn = key + ".npy"
-                    np.save(os.path.join(tmp, fn), arr)
-                    manifest["leaves"][key] = {
-                        "file": fn, "shape": list(arr.shape),
-                        "dtype": str(arr.dtype),
-                        "crc32": zlib.crc32(arr.tobytes()),
-                    }
-                with open(os.path.join(tmp, "manifest.json"), "w") as fh:
-                    json.dump(manifest, fh)
-                if os.path.exists(final):
-                    shutil.rmtree(final)
-                os.rename(tmp, final)
-                self._gc()
+                with tr.span("write", pid=pid, tid=tid, cat="ckpt"):
+                    if os.path.exists(tmp):
+                        shutil.rmtree(tmp)
+                    os.makedirs(tmp)
+                    for key, arr in leaves:
+                        np.save(os.path.join(tmp, key + ".npy"), arr)
+                with tr.span("crc", pid=pid, tid=tid, cat="ckpt"):
+                    crcs = [zlib.crc32(arr.tobytes()) for _, arr in leaves]
+                with tr.span("commit", pid=pid, tid=tid, cat="ckpt"):
+                    manifest = {"step": step, "treedef": str(treedef),
+                                "leaves": {
+                                    key: {"file": key + ".npy",
+                                          "shape": list(arr.shape),
+                                          "dtype": str(arr.dtype),
+                                          "crc32": crc}
+                                    for (key, arr), crc in zip(leaves, crcs)}}
+                    with open(os.path.join(tmp, "manifest.json"), "w") as fh:
+                        json.dump(manifest, fh)
+                    if os.path.exists(final):
+                        shutil.rmtree(final)
+                    os.rename(tmp, final)
+                    self._gc()
             except BaseException as exc:  # surfaced on next wait()
                 self._error = exc
 

@@ -26,6 +26,7 @@ from repro.launch.cells import make_optimizer
 from repro.launch.runtime import CHECKOUT_ROOT, use_compile_cache
 from repro.models.blocks import ModelContext
 from repro.models.config import ModelConfig
+from repro.obs.metrics import MetricsRegistry
 from repro.obs.trace import SpanTracer
 from repro.resilience.driver import FailurePlan, ResilientTrainer
 from repro.train.step import TrainSettings, init_train_state, \
@@ -50,7 +51,9 @@ def build_trainer(cfg: ModelConfig, *, batch: int, seq: int,
     pipeline = DataPipeline(
         DataConfig(global_batch=batch, seq_len=seq,
                    vocab_size=cfg.vocab_size, seed=seed), cfg)
-    ckpt = CheckpointManager(ckpt_dir)
+    # the checkpoint writer shares the trainer's registry and tracer
+    metrics = MetricsRegistry() if metrics is None else metrics
+    ckpt = CheckpointManager(ckpt_dir, tracer=tracer, metrics=metrics)
     sched = OCSPodScheduler(total_cubes=144)  # Ironwood-scale cube count
     sched.allocate("train", 128 * 64)
     trainer = ResilientTrainer(

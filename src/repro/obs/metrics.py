@@ -135,6 +135,8 @@ CATALOG: Dict[str, Tuple[str, str, str]] = {
     "train_replayed_steps": ("counter", "steps",
                              "steps re-run after a restore"),
     "train_ckpt_saves": ("counter", "saves", "checkpoint snapshots issued"),
+    "train_ckpt_bytes": ("counter", "bytes",
+                         "checkpoint bytes gathered from the devices"),
     "train_failures": ("counter", "failures", "injected cube failures"),
     "train_restores": ("counter", "restores", "checkpoint restores"),
     "train_step_s": ("histogram", "s", "per-step wall time"),

@@ -13,6 +13,13 @@ fleet sim passes simulated time); when omitted, the injectable
 ``clock`` is sampled and rebased so the first event sits at t=0.
 Stored values follow the Chrome convention (microseconds).
 
+Measured work goes through ``span()``, which has two sinks: its B/E
+pair in this tracer's events, and a host span named
+``<process>.<name>`` (``train.step``, ``ckpt.write``, ``serve.drain``)
+in the JAX profiler's trace while one is being captured, so the same
+work is named on the device trace's clock too. ``complete()`` keeps
+post-hoc X events for callers that hold a duration, not the work.
+
 ``validate_chrome_trace`` is the tier-1 gate's checker: balanced and
 properly nested B/E per (pid, tid) lane, monotonic lane timestamps,
 non-negative X durations, required categories present.
@@ -21,26 +28,34 @@ non-negative X durations, required categories present.
 from __future__ import annotations
 
 import json
+import threading
 import time
-from contextlib import contextmanager
-from typing import Any, Dict, Iterator, List, Optional, Sequence, Tuple
+from contextlib import contextmanager, nullcontext
+from typing import (Any, ContextManager, Dict, Iterator, List, Optional,
+                    Sequence, Tuple)
 
 _US = 1e6
+_NO_SPAN = nullcontext()
 
 
 class SpanTracer:
     """Chrome-trace event sink with process/thread lanes.
 
     Disabled tracers record nothing and cost one attribute check per
-    call, so hot loops can call unconditionally."""
+    call, so hot loops can call unconditionally. Threads may emit into
+    one tracer as long as each keeps to its own lanes (the checkpoint
+    writer has its own); register processes and lanes before the
+    threads start."""
 
     def __init__(self, clock=time.monotonic, enabled: bool = True) -> None:
         self.clock = clock
         self.enabled = enabled
         self.events: List[Dict[str, Any]] = []
         self._pids: Dict[str, int] = {}
+        self._pid_names: Dict[int, str] = {}
         self._open: Dict[Tuple[int, int], List[str]] = {}
         self._t0: Optional[float] = None
+        self._t0_lock = threading.Lock()
 
     # -- lanes ---------------------------------------------------------------
 
@@ -52,6 +67,7 @@ class SpanTracer:
         if name not in self._pids:
             pid = len(self._pids)
             self._pids[name] = pid
+            self._pid_names[pid] = name
             self.events.append({"ph": "M", "pid": pid,
                                 "name": "process_name",
                                 "args": {"name": name}})
@@ -72,7 +88,9 @@ class SpanTracer:
             return ts * _US
         now = self.clock()
         if self._t0 is None:
-            self._t0 = now
+            with self._t0_lock:  # two threads' first events
+                if self._t0 is None:
+                    self._t0 = now
         return (now - self._t0) * _US
 
     # -- emitters ------------------------------------------------------------
@@ -106,13 +124,32 @@ class SpanTracer:
         self.events.append({"ph": "E", "pid": pid, "tid": tid,
                             "name": name, "ts": self._ts_us(ts)})
 
-    @contextmanager
     def span(self, name: str, *, pid: int = 0, tid: int = 0,
-             cat: str = "", args: Optional[Dict[str, Any]] = None
-             ) -> Iterator[None]:
+             cat: str = "", args: Optional[Dict[str, Any]] = None,
+             step: Optional[int] = None) -> ContextManager[None]:
+        """Context manager around measured work: a B/E pair on the
+        (pid, tid) lane and, inside it, the profiler host span
+        ``<process>.<name>`` (a ``StepTraceAnnotation`` numbered
+        ``step`` when given, so the profiler's step view lines up).
+        Disabled: a shared no-op context, no annotation."""
+        if not self.enabled:
+            return _NO_SPAN
+        return self._span(name, pid, tid, cat, args, step)
+
+    @contextmanager
+    def _span(self, name: str, pid: int, tid: int, cat: str,
+              args: Optional[Dict[str, Any]], step: Optional[int]
+              ) -> Iterator[None]:
+        from jax import profiler
+        proc = self._pid_names.get(pid)
+        label = f"{proc}.{name}" if proc is not None else name
+        annotation = (profiler.TraceAnnotation(label) if step is None
+                      else profiler.StepTraceAnnotation(label,
+                                                        step_num=step))
         self.begin(name, pid=pid, tid=tid, cat=cat, args=args)
         try:
-            yield
+            with annotation:
+                yield
         finally:
             self.end(pid=pid, tid=tid)
 

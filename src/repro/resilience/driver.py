@@ -104,9 +104,8 @@ class ResilientTrainer:
 
     def __post_init__(self) -> None:
         # Telemetry is host-side: counters/spans around the (unchanged)
-        # train_step calls, same phase names as the fleet sim's trace
-        # ("train"/"rework"/"restore"/"detect"/"ckpt") so both render
-        # alike in one timeline.
+        # train_step calls, with phase names shared with the fleet sim's
+        # trace ("restore"/"ckpt") so both render alike in one timeline.
         if self.metrics is None:
             self.metrics = MetricsRegistry()
         if self.tracer is None:
@@ -122,12 +121,28 @@ class ResilientTrainer:
         }
         self._trace_pid = self.tracer.process("train")
 
+    def _execute(self, state: PyTree, step: int
+                 ) -> Tuple[PyTree, float, float]:
+        """One ``train_step`` call on ``step``'s batch, ending in the
+        loss's host sync, under ``batch`` / ``dispatch`` / ``sync``
+        spans. Returns (state, loss, seconds from dispatch to sync)."""
+        tr, pid = self.tracer, self._trace_pid
+        with tr.span("batch", pid=pid, cat="train"):
+            batch = self.pipeline.batch_for_step(step)
+        t0 = time.monotonic()
+        with tr.span("dispatch", pid=pid, cat="train"):
+            state, metrics = self.train_step(state, batch)
+        with tr.span("sync", pid=pid, cat="train"):
+            loss = float(jax.device_get(metrics["loss"]))
+        return state, loss, time.monotonic() - t0
+
     def run(self, state: PyTree, num_steps: int,
             ledger: Optional[GoodputLedger] = None
             ) -> Tuple[PyTree, GoodputLedger, List[float]]:
         ledger = ledger or GoodputLedger()
         losses: List[float] = []
         self.records: List[StepRecord] = []
+        tr, pid = self.tracer, self._trace_pid
         step = int(jax.device_get(state["step"]))
         last_ckpt_step = self.ckpt.latest_step()
         if last_ckpt_step is None:
@@ -136,27 +151,26 @@ class ResilientTrainer:
             # snapshot exists, a failure would otherwise have nothing to
             # restore — write the starting state synchronously.
             t0 = time.monotonic()
-            self.ckpt.save(step, state, blocking=True)
+            with tr.span("ckpt", pid=pid, cat="train",
+                         args={"step": step, "bootstrap": True}):
+                self.ckpt.save(step, state, blocking=True)
             dt = time.monotonic() - t0
             ledger.record_idle(dt, note="bootstrap ckpt")
             self._m["ckpts"].inc()
-            self.tracer.complete("ckpt", dt, pid=self._trace_pid,
-                                 tid=0, cat="train",
-                                 args={"step": step, "bootstrap": True})
             last_ckpt_step = step
         while step < num_steps:
             cube = self.failure_plan.failure_at(step)
             if cube is not None:
                 # ---- failure path: detect -> map out -> restore -> replay
+                # Detection and the restore's extra cost are modelled:
+                # the ledger charges them, the trace records only what
+                # was measured (the detection time rides on the instant).
                 self._m["failures"].inc()
-                self.tracer.instant("cube_fail", pid=self._trace_pid,
-                                    tid=0, cat="train",
-                                    args={"cube": cube, "step": step})
+                tr.instant("cube_fail", pid=pid, tid=0, cat="train",
+                           args={"cube": cube, "step": step,
+                                 "detect_s": self.failure_plan.detect_s})
                 ledger.record_detection(self.failure_plan.detect_s,
                                         note=f"cube {cube} died")
-                self.tracer.complete("detect", self.failure_plan.detect_s,
-                                     pid=self._trace_pid, tid=0,
-                                     cat="train", args={"cube": cube})
                 impacted = self.scheduler.fail_cube(cube)
                 patched = self.scheduler.substitute(self.job) \
                     if impacted == self.job else None
@@ -164,40 +178,31 @@ class ResilientTrainer:
                     raise RuntimeError(
                         "no spare cubes: job cannot continue")
                 t0 = time.monotonic()
-                # Flush any in-flight async snapshot BEFORE asking what the
-                # latest checkpoint is: querying first races the writer
-                # thread, and losing that race silently "replays" from an
-                # older step than the state actually holds.
-                self.ckpt.wait()
-                restore_step = self.ckpt.latest_step()
-                assert restore_step is not None  # bootstrap guarantees one
-                state = self.ckpt.restore(restore_step, state)
+                with tr.span("restore", pid=pid, cat="train",
+                             args={"failed_step": step}):
+                    # Flush any in-flight async snapshot BEFORE asking what the
+                    # latest checkpoint is: querying first races the writer
+                    # thread, and losing that race silently "replays" from an
+                    # older step than the state actually holds.
+                    self.ckpt.wait()
+                    restore_step = self.ckpt.latest_step()
+                    assert restore_step is not None  # bootstrap guarantees one
+                    state = self.ckpt.restore(restore_step, state)
                 # restore() may have quarantined a corrupt checkpoint and
                 # fallen back to an older one — re-anchor the replay range
                 # on the step actually loaded or it silently starts late.
                 restore_step = self.ckpt.last_restored_step
                 last_ckpt_step = restore_step
-                restore_dt = (time.monotonic() - t0
-                              + self.failure_plan.restore_extra_s)
-                ledger.record_restore(restore_dt)
+                ledger.record_restore(time.monotonic() - t0
+                                      + self.failure_plan.restore_extra_s)
                 self._m["restores"].inc()
-                self.tracer.complete("restore", restore_dt,
-                                     pid=self._trace_pid, tid=0,
-                                     cat="train",
-                                     args={"from_step": restore_step})
                 # rework: re-run steps since the checkpoint
                 t0 = time.monotonic()
                 for replay in range(restore_step, step):
-                    batch = self.pipeline.batch_for_step(replay)
-                    t1 = time.monotonic()
-                    state, metrics = self.train_step(state, batch)
-                    loss_r = float(jax.device_get(metrics["loss"]))
-                    dt_r = time.monotonic() - t1
+                    with tr.span("replay", pid=pid, cat="train",
+                                 args={"step": replay}):
+                        state, loss_r, dt_r = self._execute(state, replay)
                     self._m["replayed"].inc()
-                    self.tracer.complete("replay", dt_r,
-                                         pid=self._trace_pid, tid=0,
-                                         cat="train",
-                                         args={"step": replay})
                     self.records.append(StepRecord(
                         step=replay, loss=loss_r, replayed=True,
                         duration_s=dt_r))
@@ -207,17 +212,13 @@ class ResilientTrainer:
                 del self.failure_plan.failures[step]
                 continue
 
-            batch = self.pipeline.batch_for_step(step)
-            t0 = time.monotonic()
-            state, metrics = self.train_step(state, batch)
-            loss = float(jax.device_get(metrics["loss"]))
-            dt = time.monotonic() - t0
+            with tr.span("step", pid=pid, cat="train", args={"step": step},
+                         step=step):
+                state, loss, dt = self._execute(state, step)
             ledger.record_steps(dt, steps=1)
             losses.append(loss)
             self._m["steps"].inc()
             self._m["step_hist"].observe(dt)
-            self.tracer.complete("step", dt, pid=self._trace_pid, tid=0,
-                                 cat="train", args={"step": step})
             self.records.append(StepRecord(step=step, loss=loss,
                                            duration_s=dt))
             if self.straggler.observe(dt):
@@ -226,13 +227,12 @@ class ResilientTrainer:
             if step % self.checkpoint_every == 0:
                 state = jax.block_until_ready(state)
                 t0 = time.monotonic()
-                self.ckpt.save(step, state)  # async
+                with tr.span("ckpt", pid=pid, cat="train",
+                             args={"step": step}):
+                    self.ckpt.save(step, state)  # async
                 dt = time.monotonic() - t0
                 ledger.record_idle(dt, note="ckpt snapshot")
                 self._m["ckpts"].inc()
-                self.tracer.complete("ckpt", dt, pid=self._trace_pid,
-                                     tid=0, cat="train",
-                                     args={"step": step})
                 last_ckpt_step = step
         self.ckpt.wait()
         return state, ledger, losses

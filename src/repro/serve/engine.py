@@ -953,6 +953,15 @@ class ServeEngine:
         steps = self.counters["spec_steps"]
         return (self.counters["spec_tokens"] / steps if steps else 1.0)
 
+    def request_times(self) -> Dict[int, Dict[str, float]]:
+        """Each request the latest ``run()`` finished, by rid: the
+        seconds on ``tracer.clock`` at which it was first ready
+        (``ready``), last admitted (``admit``: after any preemption),
+        had its first token drained (``first``) and its last
+        (``last``). Queue wait is ``admit - ready``."""
+        return {rid: dict(o) for rid, o in self._req_obs.items()
+                if "last" in o}
+
     def slo_summary(self) -> Dict[str, float]:
         """Serving SLO summary straight from the registry: TTFT/TPOT
         percentiles, queue wait, and the prefill/decode role split
@@ -999,6 +1008,9 @@ class ServeEngine:
         now = self.tracer.clock
         mtr = self._m
         pid = self._trace_pid
+        # host work between chunks is named by spans on the device lane,
+        # never on a slot lane (a slot lane's spans are its requests')
+        dev = self._device_tid
         self._req_obs = {}
         self._park_spans = set()
         run_t0 = now()
@@ -1014,212 +1026,219 @@ class ServeEngine:
         per_step = 1 + self.draft_k if self._use_spec else 1
         while sched.has_work() or (pool is not None and pool.pending()):
             boundary += 1
-            wall = now()
-            for r in sched.waiting:
-                # "ready": first boundary at which the request is live
-                # (arrived); queue-wait and e2e anchor here
-                if r.arrival <= clock:
-                    self._req_obs.setdefault(r.rid, {}) \
-                        .setdefault("ready", wall)
-            if self.admission is not None:
-                # enqueue-time load shedding: a request whose best-case
-                # first token already misses its TTFT deadline is dropped
-                # before it consumes a prefill worker or decode slot
-                for r in list(sched.waiting):
-                    if r.arrival <= clock and self.admission.should_shed(
-                            r, clock, chunk=self.chunk,
-                            span_len=self.span_len,
-                            disaggregated=pool is not None):
-                        sched.shed_request(r)
-                        self.fault_stats["shed_requests"] += 1
-                        self.tracer.instant(
-                            "shed", pid=pid, tid=self._device_tid,
-                            cat="serve", args={"rid": r.rid})
-            if pool is not None:
-                # 0) disaggregation bookkeeping: activate parked slots
-                #    whose modeled page transfer has landed (rewriting the
-                #    frozen position's k/v is idempotent — see chunk_body's
-                #    freeze contract — so activation is token-identical to
-                #    co-located admission); route cold arrivals to the
-                #    shallowest prefill worker queue; surface finished
-                #    prefills back into the decode-side admission queue.
-                for slot, ready in list(self._parked.items()):
-                    if clock >= ready:
-                        del self._parked[slot]
-                        self._done = self._done.at[slot].set(False)
-                        if slot in self._park_spans:
-                            self.tracer.end(pid=pid, tid=slot)
-                            self._park_spans.discard(slot)
-                for r in [r for r in sched.waiting
-                          if r.arrival <= clock and not r.prefill_done
-                          and r.not_before <= clock]:
-                    sched.waiting.remove(r)
-                    pool.place(r, clock)
-                for r in pool.pop_ready(clock):
-                    sched.add(r)
-                st = self.disagg_stats
-                st["boundaries"] += 1
-                depth = sum(pool.depths())
-                st["prefill_depth_sum"] += depth
-                st["prefill_depth_peak"] = max(st["prefill_depth_peak"],
-                                               depth)
-                st["decode_depth_sum"] += len(sched.waiting)
-                st["decode_depth_peak"] = max(st["decode_depth_peak"],
-                                              len(sched.waiting))
-            if self.faults is not None:
-                # inject this boundary's scheduled faults, then detect:
-                # quarantined pages and failed slots are settled before
-                # admission or the chunk can observe them
-                self._apply_faults(boundary, clock, sched, pool)
-            # 1) page headroom for running slots; preempt youngest on
-            #    pressure (its pages free up for the older requests)
-            if self.paged:
-                # grow oldest-first so preemption (youngest-first) never
-                # starves the requests with the most progress
-                order = sorted(
-                    sched.running,
-                    key=lambda s: (sched.running[s].arrival,
-                                   sched.running[s].rid))
-                for slot in order:
-                    if slot not in sched.running:
-                        continue  # already preempted this boundary
-                    req = sched.running[slot]
-                    # tokens cached after the next chunk: prompt +
-                    # emitted so far + chunk new writes (+1 boundary)
-                    target = int(len(req.prompt) + len(req.generated)
-                                 + self.chunk * per_step + 1)
-                    while not self.kv.grow(slot, min(target, self.window)):
-                        victim = sched.preempt_victim()
-                        if victim is None:
-                            raise RuntimeError(
-                                "page pool too small for a single request")
-                        vslot = victim.slot
-                        sched.preempt(victim)
-                        self.kv.release(vslot)
-                        self._done = self._done.at[vslot].set(True)
-                        # a parked victim's in-flight transfer is moot:
-                        # its pages are gone; it re-prefills on resume
-                        self._parked.pop(vslot, None)
-                        if vslot in self._park_spans:
-                            self.tracer.end(pid=pid, tid=vslot)
-                            self._park_spans.discard(vslot)
-                        self.tracer.end(pid=pid, tid=vslot)  # req span
-                        self.tracer.instant(
-                            "preempt", pid=pid, tid=vslot, cat="serve",
-                            args={"rid": victim.rid})
-                        mtr["preempted"].inc()
-                        if vslot == slot:
-                            break  # we were the youngest: self-preempted
-            # 2) admissions into free slots (never preempt to admit)
-            while True:
-                req = sched.next_admittable(clock)
-                slots = sched.free_slots()
-                if req is None or not slots:
-                    break
-                slot = slots[0]
-                if self.paged:
-                    rp = req.resume_prompt()
-                    # explicit-mrope requests bypass the content-addressed
-                    # prefix index (same tokens, different position rows
-                    # => different KV)
-                    use_pc = (self.prefix_cache
-                              and "positions" not in req.extras)
-                    cached, pids = ((0, []) if not use_pc
-                                    else self.kv.lookup_prefix(rp))
-                    if cached:
-                        self.kv.adopt_prefix(slot, pids)
-                    need = len(rp) + self.chunk * per_step + 1
-                    if not self.kv.grow(slot, min(need, self.window)):
-                        if use_pc:
-                            # undo adoption AND its counter bumps: the
-                            # retry next boundary repeats the lookup
-                            self.kv.abort_adoption(slot, cached, pids)
-                        break  # no pages: wait for completions
-                    req.cached_prefix_len = cached
-                    self.counters["prompt_tokens"] += len(rp)
-                    self.counters["cached_prompt_tokens"] += cached
+            with self.tracer.span("schedule", pid=pid, tid=dev,
+                                  cat="serve"):
                 wall = now()
-                o = self._req_obs.setdefault(req.rid, {})
-                o.setdefault("ready", wall)
-                resumed = "admit" in o  # re-admission after a preemption
-                o["admit"] = wall
-                mtr["admitted"].inc()
-                mtr["queue_wait"].observe(float(clock - req.arrival))
-                self.tracer.begin(
-                    f"req:{req.rid}", pid=pid, tid=slot, cat="serve",
-                    args={"rid": req.rid, "prompt": len(req.prompt),
-                          "resumed": resumed})
-                sched.admit(req, slot)
-                self._admit_into_slot(params, req, slot, key, temp)
-                dt = now() - wall
-                n_prefill = (len(req.prompt) + len(req.generated)
-                             - req.cached_prefix_len)
-                mtr["prefill_hist"].observe(dt)
-                mtr["prefill_time"].add(dt)
-                mtr["prefill_tokens"].add(n_prefill)
-                self.tracer.complete(
-                    "prefill", dt, pid=pid, tid=slot, cat="serve",
-                    args={"tokens": n_prefill,
-                          "cached": req.cached_prefix_len})
-                self.steptrace.record(
-                    "prefill", dt, tokens=n_prefill,
-                    cached=req.cached_prefix_len, batch=1)
+                for r in sched.waiting:
+                    # "ready": first boundary at which the request is live
+                    # (arrived); queue-wait and e2e anchor here
+                    if r.arrival <= clock:
+                        self._req_obs.setdefault(r.rid, {}) \
+                            .setdefault("ready", wall)
+                if self.admission is not None:
+                    # enqueue-time load shedding: a request whose best-case
+                    # first token already misses its TTFT deadline is dropped
+                    # before it consumes a prefill worker or decode slot
+                    for r in list(sched.waiting):
+                        if r.arrival <= clock and self.admission.should_shed(
+                                r, clock, chunk=self.chunk,
+                                span_len=self.span_len,
+                                disaggregated=pool is not None):
+                            sched.shed_request(r)
+                            self.fault_stats["shed_requests"] += 1
+                            self.tracer.instant(
+                                "shed", pid=pid, tid=self._device_tid,
+                                cat="serve", args={"rid": r.rid})
                 if pool is not None:
-                    # the prefill ran on the prefill role; its finished
-                    # pages now cross the modeled link. Park the slot
-                    # (frozen exactly like a finished one) until the
-                    # transfer's boundary count elapses.
-                    moved = (self.kv.pages_for(len(rp))
-                             - cached // self.page_size)
-                    delay = self.transfer_model.delay_boundaries(moved)
-                    self._parked[slot] = clock + delay * self.chunk
-                    self._done = self._done.at[slot].set(True)
+                    # 0) disaggregation bookkeeping: activate parked slots
+                    #    whose modeled page transfer has landed (rewriting the
+                    #    frozen position's k/v is idempotent — see chunk_body's
+                    #    freeze contract — so activation is token-identical to
+                    #    co-located admission); route cold arrivals to the
+                    #    shallowest prefill worker queue; surface finished
+                    #    prefills back into the decode-side admission queue.
+                    for slot, ready in list(self._parked.items()):
+                        if clock >= ready:
+                            del self._parked[slot]
+                            self._done = self._done.at[slot].set(False)
+                            if slot in self._park_spans:
+                                self.tracer.end(pid=pid, tid=slot)
+                                self._park_spans.discard(slot)
+                    for r in [r for r in sched.waiting
+                              if r.arrival <= clock and not r.prefill_done
+                              and r.not_before <= clock]:
+                        sched.waiting.remove(r)
+                        pool.place(r, clock)
+                    for r in pool.pop_ready(clock):
+                        sched.add(r)
                     st = self.disagg_stats
-                    st["transfers"] += 1
-                    st["transfer_pages"] += moved
-                    st["transfer_bytes"] += moved * self.page_bytes
+                    st["boundaries"] += 1
+                    depth = sum(pool.depths())
+                    st["prefill_depth_sum"] += depth
+                    st["prefill_depth_peak"] = max(st["prefill_depth_peak"],
+                                                   depth)
+                    st["decode_depth_sum"] += len(sched.waiting)
+                    st["decode_depth_peak"] = max(st["decode_depth_peak"],
+                                                  len(sched.waiting))
+                if self.faults is not None:
+                    # inject this boundary's scheduled faults, then detect:
+                    # quarantined pages and failed slots are settled before
+                    # admission or the chunk can observe them
+                    self._apply_faults(boundary, clock, sched, pool)
+                # 1) page headroom for running slots; preempt youngest on
+                #    pressure (its pages free up for the older requests)
+                if self.paged:
+                    # grow oldest-first so preemption (youngest-first) never
+                    # starves the requests with the most progress
+                    order = sorted(
+                        sched.running,
+                        key=lambda s: (sched.running[s].arrival,
+                                       sched.running[s].rid))
+                    for slot in order:
+                        if slot not in sched.running:
+                            continue  # already preempted this boundary
+                        req = sched.running[slot]
+                        # tokens cached after the next chunk: prompt +
+                        # emitted so far + chunk new writes (+1 boundary)
+                        target = int(len(req.prompt) + len(req.generated)
+                                     + self.chunk * per_step + 1)
+                        while not self.kv.grow(slot, min(target, self.window)):
+                            victim = sched.preempt_victim()
+                            if victim is None:
+                                raise RuntimeError(
+                                    "page pool too small for a single request")
+                            vslot = victim.slot
+                            sched.preempt(victim)
+                            self.kv.release(vslot)
+                            self._done = self._done.at[vslot].set(True)
+                            # a parked victim's in-flight transfer is moot:
+                            # its pages are gone; it re-prefills on resume
+                            self._parked.pop(vslot, None)
+                            if vslot in self._park_spans:
+                                self.tracer.end(pid=pid, tid=vslot)
+                                self._park_spans.discard(vslot)
+                            self.tracer.end(pid=pid, tid=vslot)  # req span
+                            self.tracer.instant(
+                                "preempt", pid=pid, tid=vslot, cat="serve",
+                                args={"rid": victim.rid})
+                            mtr["preempted"].inc()
+                            if vslot == slot:
+                                break  # we were the youngest: self-preempted
+                # 2) admissions into free slots (never preempt to admit)
+                while True:
+                    req = sched.next_admittable(clock)
+                    slots = sched.free_slots()
+                    if req is None or not slots:
+                        break
+                    slot = slots[0]
+                    if self.paged:
+                        rp = req.resume_prompt()
+                        # explicit-mrope requests bypass the content-addressed
+                        # prefix index (same tokens, different position rows
+                        # => different KV)
+                        use_pc = (self.prefix_cache
+                                  and "positions" not in req.extras)
+                        cached, pids = ((0, []) if not use_pc
+                                        else self.kv.lookup_prefix(rp))
+                        if cached:
+                            self.kv.adopt_prefix(slot, pids)
+                        need = len(rp) + self.chunk * per_step + 1
+                        if not self.kv.grow(slot, min(need, self.window)):
+                            if use_pc:
+                                # undo adoption AND its counter bumps: the
+                                # retry next boundary repeats the lookup
+                                self.kv.abort_adoption(slot, cached, pids)
+                            break  # no pages: wait for completions
+                        req.cached_prefix_len = cached
+                        self.counters["prompt_tokens"] += len(rp)
+                        self.counters["cached_prompt_tokens"] += cached
+                    wall = now()
+                    o = self._req_obs.setdefault(req.rid, {})
+                    o.setdefault("ready", wall)
+                    resumed = "admit" in o  # re-admission after a preemption
+                    o["admit"] = wall
+                    mtr["admitted"].inc()
+                    mtr["queue_wait"].observe(float(clock - req.arrival))
                     self.tracer.begin(
-                        "park", pid=pid, tid=slot, cat="serve",
-                        args={"pages": moved, "delay_boundaries": delay})
-                    self._park_spans.add(slot)
-            if not sched.running:
-                if sched.next_admittable(clock) is not None:
-                    raise RuntimeError(
-                        "admission stalled with an empty batch: the page "
-                        "pool cannot hold one request (shrink window or "
-                        "grow num_pages)")
-                if pool is not None and pool.pending():
-                    clock += self.chunk  # prefill workers still cooking
+                        f"req:{req.rid}", pid=pid, tid=slot, cat="serve",
+                        args={"rid": req.rid, "prompt": len(req.prompt),
+                              "resumed": resumed})
+                    sched.admit(req, slot)
+                    n_prefill = (len(req.prompt) + len(req.generated)
+                                 - req.cached_prefix_len)
+                    with self.tracer.span(
+                            "prefill", pid=pid, tid=dev, cat="serve",
+                            args={"rid": req.rid, "slot": slot,
+                                  "tokens": n_prefill,
+                                  "cached": req.cached_prefix_len}):
+                        self._admit_into_slot(params, req, slot, key, temp)
+                    dt = now() - wall
+                    mtr["prefill_hist"].observe(dt)
+                    mtr["prefill_time"].add(dt)
+                    mtr["prefill_tokens"].add(n_prefill)
+                    self.steptrace.record(
+                        "prefill", dt, tokens=n_prefill,
+                        cached=req.cached_prefix_len, batch=1)
+                    if pool is not None:
+                        # the prefill ran on the prefill role; its finished
+                        # pages now cross the modeled link. Park the slot
+                        # (frozen exactly like a finished one) until the
+                        # transfer's boundary count elapses.
+                        moved = (self.kv.pages_for(len(rp))
+                                 - cached // self.page_size)
+                        delay = self.transfer_model.delay_boundaries(moved)
+                        self._parked[slot] = clock + delay * self.chunk
+                        self._done = self._done.at[slot].set(True)
+                        st = self.disagg_stats
+                        st["transfers"] += 1
+                        st["transfer_pages"] += moved
+                        st["transfer_bytes"] += moved * self.page_bytes
+                        self.tracer.begin(
+                            "park", pid=pid, tid=slot, cat="serve",
+                            args={"pages": moved, "delay_boundaries": delay})
+                        self._park_spans.add(slot)
+                if not sched.running:
+                    if sched.next_admittable(clock) is not None:
+                        raise RuntimeError(
+                            "admission stalled with an empty batch: the page "
+                            "pool cannot hold one request (shrink window or "
+                            "grow num_pages)")
+                    if pool is not None and pool.pending():
+                        clock += self.chunk  # prefill workers still cooking
+                        continue
+                    if not sched.waiting:
+                        # shedding emptied the queue this boundary; the
+                        # loop condition settles whether work remains
+                        continue
+                    # idle: jump the trace clock to the next arrival (or the
+                    # earliest replay-backoff expiry, for requeued requests)
+                    nxt = min(max(r.arrival, r.not_before)
+                              for r in sched.waiting)
+                    clock = max(clock + self.chunk, nxt)
                     continue
-                if not sched.waiting:
-                    # shedding emptied the queue this boundary; the
-                    # loop condition settles whether work remains
+                if (pool is not None and sched.running
+                        and all(s in self._parked for s in sched.running)):
+                    # every running slot is frozen in transfer: the decode
+                    # role is idle, so skip the device chunk entirely (frozen
+                    # slots emit nothing and their state is untouched — the
+                    # skip is token-identical) and just advance the clock.
+                    clock += self.chunk
+                    st = self.disagg_stats
+                    st["transfer_stall_boundaries"] += 1
+                    st["decode_idle_boundaries"] += 1
                     continue
-                # idle: jump the trace clock to the next arrival (or the
-                # earliest replay-backoff expiry, for requeued requests)
-                nxt = min(max(r.arrival, r.not_before)
-                          for r in sched.waiting)
-                clock = max(clock + self.chunk, nxt)
-                continue
-            if (pool is not None and sched.running
-                    and all(s in self._parked for s in sched.running)):
-                # every running slot is frozen in transfer: the decode
-                # role is idle, so skip the device chunk entirely (frozen
-                # slots emit nothing and their state is untouched — the
-                # skip is token-identical) and just advance the clock.
-                clock += self.chunk
-                st = self.disagg_stats
-                st["transfer_stall_boundaries"] += 1
-                st["decode_idle_boundaries"] += 1
-                continue
             # 3) one device-resident chunk
             sched.record_occupancy(len(sched.running))
             chunk_t0 = now()
             live = sum(1 for s in sched.running if s not in self._parked)
             cache = self.kv.pages if self.paged else \
                 {k: v for k, v in self.kv.cache.items() if k != "pos"}
-            table = self.kv.table_device() if self.paged else jnp.zeros(
-                (self.max_batch, 1), jnp.int32)
+            if self.paged:
+                with self.tracer.span("table_upload", pid=pid, tid=dev,
+                                      cat="serve"):
+                    table = self.kv.table_device()
+            else:
+                table = jnp.zeros((self.max_batch, 1), jnp.int32)
             # graceful degradation under queue pressure: spend this
             # boundary's FLOPs on a plain chunk instead of the
             # (1 + draft_k)-query speculative span. Token-identical by
@@ -1231,18 +1250,20 @@ class ServeEngine:
                         len(sched.waiting)):
                 use_spec = False
                 self.fault_stats["shed_spec_chunks"] += 1
-            if use_spec:
-                (self._tok, self._pos, self._done, self._n_out, new_cache,
-                 self._hist, toks) = self._run_chunk_spec(
-                    params, table, self._tok, self._pos, self._done,
-                    self._n_out, self._max_new, key, temp,
-                    jnp.int32(self._t), cache, self._hist)
-            else:
-                (self._tok, self._pos, self._done, self._n_out, new_cache,
-                 toks) = self._run_chunk(
-                    params, table, self._tok, self._pos, self._done,
-                    self._n_out, self._max_new, key, temp,
-                    jnp.int32(self._t), cache)
+            with self.tracer.span("chunk", pid=pid, tid=dev, cat="serve",
+                                  args={"live": live, "spec": use_spec}):
+                if use_spec:
+                    (self._tok, self._pos, self._done, self._n_out,
+                     new_cache, self._hist, toks) = self._run_chunk_spec(
+                        params, table, self._tok, self._pos, self._done,
+                        self._n_out, self._max_new, key, temp,
+                        jnp.int32(self._t), cache, self._hist)
+                else:
+                    (self._tok, self._pos, self._done, self._n_out,
+                     new_cache, toks) = self._run_chunk(
+                        params, table, self._tok, self._pos, self._done,
+                        self._n_out, self._max_new, key, temp,
+                        jnp.int32(self._t), cache)
             if self.paged:
                 self.kv.pages = new_cache
             else:
@@ -1270,67 +1291,70 @@ class ServeEngine:
                 st["transfer_stall_boundaries"] += 1
                 if all(s in self._parked for s in sched.running):
                     st["decode_idle_boundaries"] += 1
-            # 4) drain: the single host sync per chunk
-            toks_h, done_h, pos_h = jax.device_get(
-                (toks, self._done, self._pos))
-            self.counters["host_syncs"] += 1
-            wall_drain = now()
-            chunk_dt = wall_drain - chunk_t0
-            emitted = 0
-            for slot in list(sched.running):
-                if slot in self._parked:
-                    continue  # frozen in transfer: emitted PADs only
-                req = sched.running[slot]
-                if use_spec:
-                    # toks_h[slot]: (chunk, 1+draft_k); emitted tokens
-                    # form a prefix of each step row
-                    for step_row in toks_h[slot]:
-                        cnt = 0
-                        for t in step_row:
+            # 4) drain: the single host sync per chunk, then token emission
+            with self.tracer.span("drain", pid=pid, tid=dev, cat="serve"):
+                toks_h, done_h, pos_h = jax.device_get(
+                    (toks, self._done, self._pos))
+                self.counters["host_syncs"] += 1
+                wall_drain = now()
+                chunk_dt = wall_drain - chunk_t0
+                emitted = 0
+                for slot in list(sched.running):
+                    if slot in self._parked:
+                        continue  # frozen in transfer: emitted PADs only
+                    req = sched.running[slot]
+                    if use_spec:
+                        # toks_h[slot]: (chunk, 1+draft_k); emitted tokens
+                        # form a prefix of each step row
+                        for step_row in toks_h[slot]:
+                            cnt = 0
+                            for t in step_row:
+                                if t != PAD_TOKEN:
+                                    req.generated.append(int(t))
+                                    cnt += 1
+                            if cnt:
+                                self.counters["spec_steps"] += 1
+                                self.counters["spec_tokens"] += cnt
+                                emitted += cnt
+                    else:
+                        for t in toks_h[slot]:
                             if t != PAD_TOKEN:
                                 req.generated.append(int(t))
-                                cnt += 1
-                        if cnt:
-                            self.counters["spec_steps"] += 1
-                            self.counters["spec_tokens"] += cnt
-                            emitted += cnt
-                else:
-                    for t in toks_h[slot]:
-                        if t != PAD_TOKEN:
-                            req.generated.append(int(t))
-                            emitted += 1
-                o = self._req_obs.get(req.rid, {})
-                if req.generated and "first" not in o:
-                    o["first"] = wall_drain
-                    mtr["ttft"].observe(wall_drain - o.get("ready", run_t0))
-                finished = bool(done_h[slot])
-                if finished:
-                    n = len(req.generated)
-                    if "first" in o and n > 1:
-                        mtr["tpot"].observe(
-                            (wall_drain - o["first"]) / (n - 1))
-                    mtr["e2e"].observe(wall_drain - o.get("ready", run_t0))
-                    mtr["finished"].inc()
-                    mtr["generated_tokens"].add(n)
-                    self.tracer.end(pid=pid, tid=slot)  # req span
-                    sched.complete(slot)
-                    if self.paged:
-                        if (self.prefix_cache
-                                and "positions" not in req.extras):
-                            # publish generated pages too: multi-turn
-                            # prompts extending this output will hit
-                            self.kv.register_prefix(
-                                slot, np.concatenate(
-                                    [req.prompt,
-                                     np.asarray(req.generated, np.int32)]))
-                        self.kv.release(slot)
-                elif self.paged and self.cfg.sliding_window is not None:
-                    # SWA: positions behind pos - window are masked out of
-                    # attention; release their pages back to the pool
-                    self.counters["pages_trimmed"] += self.kv.trim(
-                        slot, int(pos_h[slot]) - self.cfg.sliding_window)
-            # chunk-level telemetry: role time split, measured steptrace
-            # event, and one X span on the shared "device" lane
+                                emitted += 1
+                    o = self._req_obs.get(req.rid, {})
+                    if req.generated and "first" not in o:
+                        o["first"] = wall_drain
+                        mtr["ttft"].observe(
+                            wall_drain - o.get("ready", run_t0))
+                    finished = bool(done_h[slot])
+                    if finished:
+                        o["last"] = wall_drain
+                        n = len(req.generated)
+                        if "first" in o and n > 1:
+                            mtr["tpot"].observe(
+                                (wall_drain - o["first"]) / (n - 1))
+                        mtr["e2e"].observe(wall_drain - o.get("ready", run_t0))
+                        mtr["finished"].inc()
+                        mtr["generated_tokens"].add(n)
+                        self.tracer.end(pid=pid, tid=slot)  # req span
+                        sched.complete(slot)
+                        if self.paged:
+                            if (self.prefix_cache
+                                    and "positions" not in req.extras):
+                                # publish generated pages too: multi-turn
+                                # prompts extending this output will hit
+                                self.kv.register_prefix(
+                                    slot, np.concatenate(
+                                        [req.prompt,
+                                         np.asarray(req.generated, np.int32)]))
+                            self.kv.release(slot)
+                    elif self.paged and self.cfg.sliding_window is not None:
+                        # SWA: positions behind pos - window are masked out of
+                        # attention; release their pages back to the pool
+                        self.counters["pages_trimmed"] += self.kv.trim(
+                            slot, int(pos_h[slot]) - self.cfg.sliding_window)
+            # chunk-level telemetry: role time split and the measured
+            # steptrace event
             mtr["decode_time"].add(chunk_dt)
             mtr["chunk_hist"].observe(chunk_dt)
             mtr["decode_tokens"].add(emitted)
@@ -1338,9 +1362,6 @@ class ServeEngine:
                 "spec_decode" if use_spec else "decode", chunk_dt,
                 batch=live, steps=self.chunk, tokens=emitted,
                 queue_depth=len(sched.waiting))
-            self.tracer.complete(
-                "decode_chunk", chunk_dt, pid=pid, tid=self._device_tid,
-                cat="serve", args={"live": live, "tokens": emitted})
         return {r.rid: np.asarray(r.generated, np.int32)
                 for r in sched.finished}
 

@@ -4,7 +4,10 @@ the engine/trainer integration invariants (telemetry must not change
 tokens; one merged timeline must validate with serve+train+fleet
 categories)."""
 
+import glob
 import json
+import sys
+import threading
 
 import jax
 import jax.numpy as jnp
@@ -189,6 +192,112 @@ def test_disabled_tracer_records_nothing():
     assert tr.events == []
 
 
+def _host_events(tmp_path, fn):
+    """Run ``fn`` under a JAX profiler trace; returns the host plane's
+    events as {name: [(start_ns, end_ns, stats)]}."""
+    from jax.profiler import ProfileData
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        fn()
+    finally:
+        jax.profiler.stop_trace()
+    path, = glob.glob(str(tmp_path / "plugins/profile/*/*.xplane.pb"))
+    out = {}
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                for e in line.events:
+                    out.setdefault(e.name, []).append(
+                        (e.start_ns, e.start_ns + e.duration_ns,
+                         dict(e.stats)))
+    return out
+
+
+def test_span_reaches_the_profiler_trace(tmp_path):
+    """An enabled span lands in the profiler's host plane as
+    ``<process>.<name>``, nested in its parent, with the step number on a
+    step span; the tracer's own events keep the bare names."""
+    tr = SpanTracer()
+    pid = tr.process("train")
+    ck = tr.process("ckpt")
+
+    def write():
+        with tr.span("write", pid=ck, tid=1, cat="ckpt"):
+            pass
+
+    def work():
+        with tr.span("step", pid=pid, cat="train", step=7):
+            with tr.span("batch", pid=pid, cat="train"):
+                pass
+            # another thread on a lane of its own, as the ckpt writer
+            t = threading.Thread(target=write)
+            t.start()
+            t.join(timeout=10)
+        assert not t.is_alive()
+
+    host = _host_events(tmp_path, work)
+    (s0, s1, stats), = host["train.step"]
+    assert stats.get("step_num") == 7
+    (b0, b1, _), = host["train.batch"]
+    assert s0 <= b0 <= b1 <= s1
+    (w0, w1, _), = host["ckpt.write"]
+    assert b1 <= w0 <= w1 <= s1
+    assert validate_chrome_trace(tr.chrome_trace()) == []
+    assert [e["name"] for e in tr.events if e["ph"] == "B"
+            and e["pid"] == pid] == ["step", "batch"]
+
+
+def test_disabled_span_adds_nothing_to_the_profiler_trace(tmp_path):
+    tr = SpanTracer(enabled=False)
+    pid = tr.process("train")
+
+    def work():
+        with jax.profiler.TraceAnnotation("marker"):
+            with tr.span("step", pid=pid, step=1):
+                with tr.span("batch", pid=pid):
+                    pass
+
+    host = _host_events(tmp_path, work)
+    assert "marker" in host  # the trace was captured
+    assert not [n for n in host if n.startswith(("train.", "step", "batch"))]
+    assert tr.events == []
+    assert tr.span("a") is tr.span("b")  # one shared no-op context
+
+
+def test_span_lanes_from_many_threads_stay_valid():
+    """Threads emitting into one tracer, each on its own lane (as the
+    checkpoint writer does beside the trainer), with the interpreter
+    switching threads as often as it can: every span is recorded and
+    every lane nests."""
+    tr = SpanTracer()
+    pid = tr.process("p")
+    n_threads, n_spans = 16, 200
+
+    def lane(tid):
+        for i in range(n_spans):
+            with tr.span("outer", pid=pid, tid=tid):
+                with tr.span("inner", pid=pid, tid=tid, args={"i": i}):
+                    pass
+
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        threads = [threading.Thread(target=lane, args=(t,))
+                   for t in range(n_threads)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=60)
+    finally:
+        sys.setswitchinterval(old)
+    assert not any(t.is_alive() for t in threads)
+    assert validate_chrome_trace(tr.chrome_trace()) == []
+    assert sum(e["ph"] == "B" for e in tr.events) == \
+        2 * n_threads * n_spans
+
+
 def test_chrome_trace_roundtrip_and_merge(tmp_path):
     a = SpanTracer(clock=FakeClock())
     pa = a.process("serve")
@@ -312,6 +421,68 @@ def test_engine_telemetry_does_not_change_tokens(qwen):
     assert kinds == {"prefill", "decode"}
 
 
+def _span_seconds(events, pid):
+    """{name: [seconds of each B/E span on the process row]}."""
+    out, open_ = {}, {}
+    for e in events:
+        if e.get("pid") != pid or e["ph"] not in "BE":
+            continue
+        stack = open_.setdefault(e["tid"], [])
+        if e["ph"] == "B":
+            stack.append(e)
+        else:
+            b = stack.pop()
+            out.setdefault(b["name"], []).append((e["ts"] - b["ts"]) / 1e6)
+    return out
+
+
+def test_engine_spans_stay_off_slot_lanes(qwen):
+    """The engine's host work (schedule, prefill, table_upload, chunk,
+    drain) is spanned on its device lane; a slot lane holds only its
+    requests' lifecycle spans, whose ends mark completions."""
+    cfg, params = qwen
+    rng = np.random.default_rng(2)
+    ps = [rng.integers(0, cfg.vocab_size, n) for n in (9, 13, 6)]
+    eng, _ = _run_engine(cfg, params, ps, tracer=SpanTracer())
+    begun = [(e["tid"], e["name"]) for e in eng.tracer.events
+             if e["ph"] == "B"]
+    device = {n for t, n in begun if t == eng._device_tid}
+    assert device == {"schedule", "prefill", "table_upload", "chunk",
+                      "drain"}
+    assert all(n.startswith(("req:", "park")) for t, n in begun
+               if t != eng._device_tid)
+    assert validate_chrome_trace(eng.tracer.chrome_trace(),
+                                 require_cats=["serve"]) == []
+
+
+def test_request_times_after_preemption():
+    """``request_times`` gives each finished request's ready, admit,
+    first-token and completion seconds on the tracer's clock; a
+    preempted request's admit is its last admission."""
+    cfg = get_smoke("qwen2_0_5b")
+    params = init_params(jax.random.key(0), api.model_specs(cfg))
+    rng = np.random.default_rng(1)
+    reqs = [Request(rid=i, prompt=rng.integers(0, cfg.vocab_size,
+                                               int(rng.integers(8, 15))),
+                    max_new=14) for i in range(5)]
+    eng = ServeEngine(cfg, CTX, window=64, max_batch=3, chunk=4,
+                      page_size=8, num_pages=12,
+                      tracer=SpanTracer(clock=FakeClock(), enabled=False))
+    eng.run(params, reqs)
+    times = eng.request_times()
+    assert sorted(times) == [r.rid for r in reqs]
+    for r in reqs:
+        t = times[r.rid]
+        assert set(t) == {"ready", "admit", "first", "last"}
+        assert t["ready"] <= t["admit"] and t["ready"] < t["first"]
+        assert t["first"] < t["last"]
+        if r.preemptions:
+            assert t["first"] < t["admit"]  # re-admitted after a token
+        else:
+            assert t["admit"] < t["first"]
+    assert any(r.preemptions for r in reqs)
+
+
 def test_trainer_telemetry_matches_replay_summary(tmp_path):
     from repro.launch.train import build_trainer
     from repro.resilience.driver import StragglerPolicy
@@ -322,6 +493,10 @@ def test_trainer_telemetry_matches_replay_summary(tmp_path):
         ckpt_dir=str(tmp_path / "ckpt"), checkpoint_every=4,
         failures={5: 0}, tracer=tracer)
     trainer.straggler = StragglerPolicy(threshold=float("inf"))
+    # modelled costs far above anything measured here: the ledger
+    # charges them, the trace must not show them as spans
+    trainer.failure_plan.detect_s = 100.0
+    trainer.failure_plan.restore_extra_s = 100.0
     _, ledger, losses = trainer.run(state, 8)
     rs = trainer.replay_summary()
     snap = trainer.metrics.snapshot()
@@ -333,8 +508,18 @@ def test_trainer_telemetry_matches_replay_summary(tmp_path):
     assert snap["train_step_s"]["count"] == len(losses)
     assert validate_chrome_trace(tracer.chrome_trace(),
                                  require_cats=["train"]) == []
-    names = {e["name"] for e in tracer.events if e.get("ph") == "X"}
-    assert {"step", "ckpt", "detect", "restore", "replay"} <= names
+    pid = tracer.process("train")
+    spans = _span_seconds(tracer.events, pid)
+    assert {"step", "ckpt", "restore", "replay"} <= set(spans)
+    assert not any(e["name"] == "detect" for e in tracer.events)
+    fail, = [e for e in tracer.events
+             if e["ph"] == "i" and e["name"] == "cube_fail"]
+    assert fail["pid"] == pid and fail["args"]["detect_s"] == 100.0
+    restore_s, = spans["restore"]
+    totals = ledger.totals()
+    assert totals["detect"] == 100.0
+    assert totals["restore"] == pytest.approx(restore_s + 100.0, abs=1e-2)
+    assert restore_s < 100.0  # measured only
     st = trainer.steptrace()
     assert st.durations(("replay",)) and st.durations(("step",))
     assert len(st) == len(trainer.records)

@@ -162,27 +162,45 @@ _SLO_KEYS = ("requests", "ttft_p50_s", "ttft_p95_s", "tpot_p50_s",
              "decode_time_s", "prefill_tok_s", "decode_tok_s")
 
 
-def _golden_run(cfg, params):
+def _golden_run(cfg, params, traced=True):
     eng = ServeEngine(cfg, CTX, window=32, max_batch=2, chunk=4,
-                      page_size=8, tracer=SpanTracer(clock=_FakeClock()))
+                      page_size=8, tracer=SpanTracer(clock=_FakeClock(),
+                                                     enabled=traced))
     eng.run(params, _reqs(cfg, 4, lo=8, hi=12, max_new=8))
     return eng.slo_summary()
 
 
 # The snapshot under the unit-step clock: every wall-derived metric is
 # a deterministic count of the engine's observation points (p95 values
-# interpolate inside a histogram bucket). A change here means the
-# engine moved a measurement point — update deliberately.
+# interpolate inside a histogram bucket), and, with the tracer enabled,
+# of the clock samples of its spans (two per span: schedule, prefill,
+# table_upload, chunk and drain). A change here means the engine moved a
+# measurement point or a span — update deliberately.
 _GOLDEN_SLO = {
     "requests": 4.0,
-    "ttft_p50_s": 10.0,
-    "ttft_p95_s": 26.2,
-    "tpot_p50_s": 4.0 / 7.0,
-    "tpot_p95_s": 4.0 / 7.0,
+    "ttft_p50_s": 30.0,
+    "ttft_p95_s": 49.8,
+    "tpot_p50_s": 11.0 / 7.0,
+    "tpot_p95_s": 11.0 / 7.0,
     "queue_wait_p50_steps": 0.0,
-    "prefill_time_s": 8.0,
+    "prefill_time_s": 16.0,
+    "decode_time_s": 24.0,
+    "prefill_tok_s": 2.625,
+    "decode_tok_s": 4.0 / 3.0,
+}
+
+# The same run with the tracer disabled: the engine's own observation
+# points alone, which spans must never move.
+_GOLDEN_SLO_UNTRACED = {
+    "requests": 4.0,
+    "ttft_p50_s": 10.0,
+    "ttft_p95_s": 15.4,
+    "tpot_p50_s": 3.0 / 7.0,
+    "tpot_p95_s": 3.0 / 7.0,
+    "queue_wait_p50_steps": 0.0,
+    "prefill_time_s": 4.0,
     "decode_time_s": 4.0,
-    "prefill_tok_s": 5.25,
+    "prefill_tok_s": 10.5,
     "decode_tok_s": 8.0,
 }
 
@@ -198,6 +216,14 @@ def test_slo_summary_golden_snapshot(qwen):
     again = _golden_run(cfg, params)
     assert json.dumps(slo, sort_keys=True) == \
         json.dumps(again, sort_keys=True)
+
+
+def test_slo_summary_golden_snapshot_untraced(qwen):
+    """A disabled tracer samples no clock for its spans: the snapshot
+    counts the engine's measurement points alone."""
+    cfg, params = qwen
+    slo = _golden_run(cfg, params, traced=False)
+    assert slo == pytest.approx(_GOLDEN_SLO_UNTRACED)
 
 
 def test_bench_serve_arrivals_rows_pinned(qwen):

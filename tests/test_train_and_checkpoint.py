@@ -178,6 +178,32 @@ def test_checkpoint_async_and_shape_mismatch():
         shutil.rmtree(tmp)
 
 
+def test_checkpoint_spans_and_bytes(tmp_path):
+    """A traced save is gather, write, crc and commit on the ``ckpt``
+    row, nested in order; an async save's file work runs on the writer
+    lane; ``train_ckpt_bytes`` counts the bytes gathered; the manifest's
+    checksums still restore."""
+    from repro.obs.metrics import MetricsRegistry
+    from repro.obs.trace import SpanTracer, validate_chrome_trace
+    tracer, reg = SpanTracer(), MetricsRegistry()
+    mgr = CheckpointManager(str(tmp_path), tracer=tracer, metrics=reg)
+    state = {"w": jnp.arange(24.0).reshape(4, 6), "b": jnp.ones((3,))}
+    mgr.save(1, state, blocking=True)
+    mgr.save(2, state)  # async
+    mgr.wait()
+    pid = tracer.process("ckpt")
+    begun = [(e["name"], e["tid"]) for e in tracer.events
+             if e["ph"] == "B" and e["pid"] == pid]
+    assert begun == [("gather", 0), ("write", 0), ("crc", 0),
+                     ("commit", 0), ("gather", 0), ("write", 1),
+                     ("crc", 1), ("commit", 1)]
+    assert validate_chrome_trace(tracer.chrome_trace()) == []
+    assert reg.counter("train_ckpt_bytes").value == 2 * (24 + 3) * 4
+    out = mgr.restore(2, state)
+    np.testing.assert_array_equal(np.asarray(out["w"]),
+                                  np.asarray(state["w"]))
+
+
 # -------------------------------------------------- failure recovery
 
 

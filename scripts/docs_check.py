@@ -15,7 +15,7 @@ Verifies that the documentation keeps up with the code:
   5. every ``--flag`` on a ``python ...`` command line inside a fenced
      code block appears verbatim in the source of the script/module the
      command invokes (so documented CLI surfaces can't drift);
-  6. every backticked ``serve_*`` / ``train_*`` metric name in
+  6. every backticked ``serve_*`` / ``train_*`` / ``attn_*`` metric name in
      docs/observability.md exists in ``src/repro/obs/`` (the catalog
      table can't drift from the pinned metric vocabulary);
   7. every ``benchmarks/scenarios/*.json`` validates against the
@@ -166,7 +166,7 @@ def main() -> int:
     if obs_doc.exists():
         obs_src = "\n".join(p.read_text() for p in sorted(
             (ROOT / "src" / "repro" / "obs").glob("*.py")))
-        for m in re.finditer(r"`((?:serve|train)_[a-z0-9_]+)`",
+        for m in re.finditer(r"`((?:serve|train|attn)_[a-z0-9_]+)`",
                              obs_doc.read_text()):
             if m.group(1) not in obs_src:
                 problems.append(

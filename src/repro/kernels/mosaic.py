@@ -44,6 +44,26 @@ def largest_block(n: int, fits: Callable[[int], bool],
     return next((b for b in cands if fits(b)), cands[-1])
 
 
+# Process matmul precisions (``jax.default_matmul_precision``) under which
+# XLA takes f32 dot operands on a TPU in one bf16 MXU pass.
+_ONE_BF16_PASS = (None, "default", "bfloat16", "fastest")
+
+
+def mxu_dtype(dtype, interpret: bool = False):
+    """Operand dtype of an in-kernel dot on ``dtype`` blocks, matching what
+    XLA's own dot would do at the process's matmul precision. On a TPU at
+    the default precision XLA multiplies f32 operands in one bf16 MXU pass
+    with f32 accumulation, so a kernel casts its f32 VMEM blocks to bf16
+    right before the dot (nothing is stored in bf16). Under a finer
+    precision ("highest", ...) they stay f32, and so they do in interpret
+    mode, where the dot is the CPU's, exact in f32 like XLA's there."""
+    dtype = jnp.dtype(dtype)
+    if (dtype == jnp.float32 and not interpret
+            and jax.config.jax_default_matmul_precision in _ONE_BF16_PASS):
+        return jnp.dtype(jnp.bfloat16)
+    return dtype
+
+
 def dot_precision(dtype) -> Optional[jax.lax.Precision]:
     """Precision for an in-kernel dot on ``dtype`` operands. f32 follows
     the process (``jax.default_matmul_precision``); narrower operands are

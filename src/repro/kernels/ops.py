@@ -45,8 +45,9 @@ def matmul(a: Array, b: Array, *, impl: str = "pallas", out_dtype=None,
                                    "block_q", "block_k"))
 def flash_attention(q: Array, k: Array, v: Array, *, impl: str = "pallas",
                     causal: bool = True, window: Optional[int] = None,
-                    block_q: int = 128, block_k: int = 128) -> Array:
-    """(BH, S, D) in/out."""
+                    block_q: Optional[int] = None,
+                    block_k: Optional[int] = None) -> Array:
+    """(BH, S, D) in/out; differentiable. Blocks None: sized from VMEM."""
     if impl == "ref":
         return ref.flash_attention_ref(q, k, v, causal=causal,
                                        window=window)

@@ -40,10 +40,14 @@ def build_trainer(cfg: ModelConfig, *, batch: int, seq: int,
                   failures: Optional[Dict[int, int]] = None,
                   compute_dtype=jnp.float32,
                   metrics=None, tracer=None):
-    # jnp ("xla") attention on every platform: the Pallas kernels have no
-    # backward pass, so the train step cannot differentiate through them
+    # the attention kernels follow the platform, as build_engine's do:
+    # the differentiable flash kernels on a TPU, jnp attention elsewhere
+    attn_impl = "pallas" if jax.default_backend() == "tpu" else "xla"
+    # the checkpoint writer and the model share the trainer's registry
+    metrics = MetricsRegistry() if metrics is None else metrics
     ctx = ModelContext(compute_dtype=compute_dtype, q_chunk=2048,
-                       mamba_chunk=64, rwkv_chunk=16, attn_impl="xla")
+                       mamba_chunk=64, rwkv_chunk=16, attn_impl=attn_impl,
+                       metrics=metrics)
     opt = make_optimizer(optimizer, total_steps=10_000)
     step_fn = jax.jit(make_train_step(
         cfg, ctx, opt, TrainSettings(microbatches=microbatches)),
@@ -51,8 +55,6 @@ def build_trainer(cfg: ModelConfig, *, batch: int, seq: int,
     pipeline = DataPipeline(
         DataConfig(global_batch=batch, seq_len=seq,
                    vocab_size=cfg.vocab_size, seed=seed), cfg)
-    # the checkpoint writer shares the trainer's registry and tracer
-    metrics = MetricsRegistry() if metrics is None else metrics
     ckpt = CheckpointManager(ckpt_dir, tracer=tracer, metrics=metrics)
     sched = OCSPodScheduler(total_cubes=144)  # Ironwood-scale cube count
     sched.allocate("train", 128 * 64)

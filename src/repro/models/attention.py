@@ -2,9 +2,14 @@
 
 Implementations are selectable (``impl``):
   "xla"     — pure jnp, exact, q-chunked so the score matrix never exceeds
-              (chunk x S) per head; the dry-run path (clean HLO).
-  "pallas"  — flash-attention Pallas kernel (TPU target; interpret=True on
-              CPU), used by tests/benchmarks via kernels/ops.py.
+              (chunk x S) per head; the CPU path and the dry-run path
+              (clean HLO).
+  "pallas"  — the flash-attention Pallas kernels
+              (kernels/flash_attention.py), differentiable through their
+              custom VJP, so training and dense prefill both run them on
+              a TPU ("pallas_interpret" runs the same kernel bodies on
+              the CPU). A sequence the kernels cannot tile (not a
+              multiple of 128 tokens) falls back to "xla".
 
 Masks are computed from positions, never materialized at (S x S) outside the
 chunk: causal, sliding-window (Mixtral), bidirectional (Whisper encoder),
@@ -103,16 +108,23 @@ def full_attention(
     attn_type: Optional[str] = None,
     window: Optional[int] = None,
     impl: str = "xla",
+    metrics=None,
 ) -> jax.Array:
     """Training/prefill attention. q: (B,S,H,D); k,v: (B,S,KV,D).
 
     impl="pallas"/"pallas_interpret" routes through the flash-attention
-    kernel (kernels/flash_attention.py): heads fold into the grid's batch
-    dim, KV heads expand to full heads first (GQA)."""
+    kernels (kernels/flash_attention.py) when the sequence tiles: heads
+    fold into the grid's batch dim, KV heads expand to full heads first
+    (GQA). ``metrics`` (an obs.metrics registry) counts, at trace time,
+    which path the compiled program took: ``attn_flash`` or ``attn_xla``."""
     b, s, h, d = q.shape
     atype = attn_type or cfg.attn_type
     win = window if window is not None else cfg.sliding_window
-    if impl in ("pallas", "pallas_interpret") and s >= 128 and s % 128 == 0:
+    flash = (impl in ("pallas", "pallas_interpret") and s % 128 == 0
+             and k.shape[1] == s)
+    if metrics is not None:
+        metrics.compile_event("attn_flash" if flash else "attn_xla")
+    if flash:
         from repro.kernels import ops as kops
         kf = _expand_kv(k, h)
         vf = _expand_kv(v, h)

@@ -51,7 +51,8 @@ class ModelContext:
                  mesh=None, data_axis: str = "data",
                  model_axis: str = "model",
                  moe_dispatch: str = "grouped",
-                 moe_impl: Optional[str] = None):
+                 moe_impl: Optional[str] = None,
+                 metrics=None):
         self.compute_dtype = compute_dtype
         self.q_chunk = q_chunk
         self.shard = shard
@@ -75,6 +76,9 @@ class ModelContext:
         # dispatch. moe_impl=None derives the kernel impl from attn_impl.
         self.moe_dispatch = moe_dispatch
         self.moe_impl = moe_impl
+        # optional obs.metrics registry: the trace-time counters of which
+        # attention path a compiled program lowered through
+        self.metrics = metrics
 
     @property
     def cache_dtype(self):
@@ -185,7 +189,8 @@ def attn_forward(p: Dict[str, Array], x: Array, cfg: ModelConfig,
     k = ctx.shard(k, ("batch", "seq", "kv_heads", None))
     v = ctx.shard(v, ("batch", "seq", "kv_heads", None))
     out = full_attention(q, k, v, cfg, q_chunk=ctx.q_chunk,
-                         attn_type=attn_type, impl=ctx.attn_impl)
+                         attn_type=attn_type, impl=ctx.attn_impl,
+                         metrics=ctx.metrics)
     return jnp.einsum("bshk,hkd->bsd", out, p["wo"].astype(dtype))
 
 

@@ -140,6 +140,12 @@ CATALOG: Dict[str, Tuple[str, str, str]] = {
     "train_failures": ("counter", "failures", "injected cube failures"),
     "train_restores": ("counter", "restores", "checkpoint restores"),
     "train_step_s": ("histogram", "s", "per-step wall time"),
+    # model: which attention path a compiled program lowered through
+    # (trace-time, via compile_event)
+    "attn_flash_compiles": ("counter", "compiles",
+                            "attention lowerings through the flash kernels"),
+    "attn_xla_compiles": ("counter", "compiles",
+                          "attention lowerings through jnp attention"),
 }
 
 

@@ -358,7 +358,8 @@ class ServeEngine:
             decode_cache_dtype=ctx.decode_cache_dtype,
             full_cache_window=ctx.full_cache_window, mesh=mesh,
             data_axis="data", model_axis="model",
-            moe_dispatch=ctx.moe_dispatch, moe_impl=ctx.moe_impl)
+            moe_dispatch=ctx.moe_dispatch, moe_impl=ctx.moe_impl,
+            metrics=ctx.metrics)
 
     def _note_dropped(self, raw=None) -> None:
         """Fold freshly-recorded divisibility fallbacks into the one-time

@@ -60,6 +60,79 @@ def test_flash_attention_matches_ref(bh, s, d, causal, window):
         rtol=2e-2, atol=2e-2)
 
 
+@pytest.mark.parametrize("block_q,block_k", [(128, 128), (256, 128),
+                                             (128, 256)])
+@pytest.mark.parametrize("causal,window", [(True, None), (True, 200),
+                                           (False, None)])
+def test_flash_attention_grad_matches_ref(block_q, block_k, causal, window):
+    """jax.grad through the kernels (custom VJP: flash_fwd, flash_bwd_dq,
+    flash_bwd_dkv) against jax.grad of the oracle. S 512 over 128/256
+    blocks has skipped blocks above the diagonal, diagonal blocks split
+    part-way, and (window 200) blocks cut by the window's edge and blocks
+    skipped below it."""
+    s, d = 512, 64
+    q, k, v, w = (rnd(i, (2, s, d)) for i in (20, 21, 22, 23))
+
+    def loss(attn):
+        return lambda q, k, v: jnp.sum(attn(q, k, v) * w)
+
+    got = jax.grad(loss(lambda q, k, v: ops.flash_attention(
+        q, k, v, impl="interpret", causal=causal, window=window,
+        block_q=block_q, block_k=block_k)), (0, 1, 2))(q, k, v)
+    want = jax.grad(loss(lambda q, k, v: ref.flash_attention_ref(
+        q, k, v, causal=causal, window=window)), (0, 1, 2))(q, k, v)
+    for name, g, r in zip("qkv", got, want):
+        np.testing.assert_allclose(
+            g, r, rtol=1e-5, atol=2e-5 * float(jnp.max(jnp.abs(r))),
+            err_msg=f"d{name}")
+
+
+@hypothesis.settings(max_examples=60, deadline=None)
+@hypothesis.given(m=st.integers(1, 4), causal=st.booleans(),
+                  window=st.one_of(st.none(), st.integers(1, 900)),
+                  kv_major=st.booleans())
+def test_flash_block_table_matches_mask(m, causal, window, kv_major):
+    """The kernels' grid walk holds exactly the blocks with an allowed
+    (query, key) pair, each output block's steps in one ascending run
+    marked FIRST..LAST, and MASKED on exactly the blocks that also hold
+    a disallowed pair."""
+    from repro.kernels.flash_attention import (FIRST, LAST, MASKED,
+                                               _allowed, block_table)
+    bq, bk, s = 96, 64, 192 * m
+    n_q, n_k = s // bq, s // bk
+    pos = np.arange(s)
+    allowed = np.asarray(_allowed(pos[:, None], pos[None, :], causal,
+                                  window))
+    blocks = {(i, j): allowed[i * bq:(i + 1) * bq, j * bk:(j + 1) * bk]
+              for i in range(n_q) for j in range(n_k)}
+    qi, kj, fl = block_table(n_q, n_k, bq, bk, causal, window,
+                             kv_major=kv_major)
+    want = sorted(ij for ij, blk in blocks.items() if blk.any())
+    walk = list(zip(qi.tolist(), kj.tolist()))
+    outer = [ij[int(kv_major)] for ij in walk]
+    assert sorted(walk) == want
+    assert walk == sorted(walk, key=lambda ij: ij[::-1] if kv_major
+                          else ij)
+    for t, (ij, f) in enumerate(zip(walk, fl.tolist())):
+        assert bool(f & MASKED) == (not blocks[ij].all())
+        assert bool(f & FIRST) == (t == 0 or outer[t - 1] != outer[t])
+        assert bool(f & LAST) == (t == len(walk) - 1
+                                  or outer[t + 1] != outer[t])
+
+
+def test_flash_dots_follow_process_precision():
+    """f32 blocks meet the MXU as bf16 at the default precision, as XLA's
+    f32 dots do on a TPU; they stay f32 under "highest" and in interpret
+    mode (the CPU's exact f32 dots)."""
+    from repro.kernels.mosaic import mxu_dtype
+    with jax.default_matmul_precision("default"):
+        assert mxu_dtype(jnp.float32) == jnp.bfloat16
+        assert mxu_dtype(jnp.float32, interpret=True) == jnp.float32
+        assert mxu_dtype(jnp.bfloat16) == jnp.bfloat16
+    with jax.default_matmul_precision("highest"):
+        assert mxu_dtype(jnp.float32) == jnp.float32
+
+
 def test_flash_attention_causality():
     """Changing future keys must not change past outputs."""
     q, k, v = (rnd(i, (2, 256, 64)) for i in (8, 9, 10))

@@ -138,7 +138,32 @@ def test_compile_event_counts_compiles():
 
 
 def test_catalog_names_have_role_prefixes():
-    assert all(n.startswith(("serve_", "train_")) for n in CATALOG)
+    assert all(n.startswith(("serve_", "train_", "attn_")) for n in CATALOG)
+
+
+def test_attention_path_counters(tmp_path):
+    """On the CPU the trainer's compiled step lowers jnp attention, once
+    per compile however many steps run; a context on the flash kernels
+    (interpret mode) counts ``attn_flash`` instead."""
+    from repro.launch.train import build_trainer
+    from repro.models.attention import full_attention
+
+    cfg = get_smoke("qwen2_0_5b")
+    trainer, state = build_trainer(
+        cfg, batch=2, seq=16, ckpt_dir=str(tmp_path / "ckpt"),
+        checkpoint_every=100)
+    trainer.run(state, 3)
+    snap = trainer.metrics.snapshot()
+    assert snap["train_steps"] == 3
+    assert snap["attn_xla_compiles"] == 1
+    assert "attn_flash_compiles" not in snap
+
+    reg = MetricsRegistry()
+    q = jnp.ones((1, 128, cfg.n_heads, cfg.resolved_head_dim))
+    k = jnp.ones((1, 128, cfg.n_kv_heads, cfg.resolved_head_dim))
+    jax.jit(lambda q, k: full_attention(
+        q, k, k, cfg, impl="pallas_interpret", metrics=reg)).lower(q, k)
+    assert reg.snapshot() == {"attn_flash_compiles": 1}
 
 
 # --------------------------------------------------------------- trace

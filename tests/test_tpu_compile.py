@@ -122,6 +122,29 @@ def test_flash_attention(chip, dtype):
     assert "tpu_custom_call" in hlo
 
 
+@pytest.mark.parametrize("window,precision", [
+    (None, "default"), (1024, "default"), (None, "highest")])
+def test_flash_attention_train(chip, window, precision):
+    """The training attention of Qwen2-0.5B at train_4k: forward and both
+    backward kernels of one layer and microbatch (14 heads, S 4096, f32),
+    at the blocks the VMEM count picks: 1024 x 1024 for bf16 MXU
+    operands at the default precision (the trainer's); 512 x 512 for f32
+    operands under "highest", which overflow the scoped VMEM at 1024."""
+    f32, bf16 = jnp.float32, jnp.bfloat16
+    assert flash_attention.flash_block(4096, D, f32, bf16) == 1024
+    assert flash_attention.flash_block(4096, D, f32, f32) == 512
+
+    def fwd_bwd(q, k, v, do):
+        o, back = jax.vjp(lambda q, k, v: flash_attention.flash_attention(
+            q, k, v, window=window), q, k, v)
+        return (o,) + back(do)
+
+    with jax.default_matmul_precision(precision):
+        hlo = _compile(chip, fwd_bwd, *[((H, 4096, D), jnp.float32)] * 4)
+    for name in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"):
+        assert name in hlo, name
+
+
 def test_rwkv_scan(chip):
     """Needed a (1, 1, hd) block for u and no in-kernel cumsum or
     negative indexing, which Mosaic does not lower."""

@@ -78,6 +78,19 @@ def train_token_flops(n: Dict[str, int], seq: int) -> float:
     return 3 * per_seq / seq
 
 
+def flash_train_work(n: Dict[str, int], seq: int, sequences: int,
+                     act_bytes: int = 4) -> Tuple[int, int]:
+    """(FLOPs, bytes) of the training flash-attention kernels (forward
+    and backward) over ``sequences`` causal sequences of ``seq`` tokens,
+    over every layer. FLOPs: three times the forward's scores and
+    weighted sum; the forwards that rematerialisation runs again are not
+    counted. Bytes: q, o and their gradients at the query heads, k, v and
+    their gradients at the KV heads, each read or written once."""
+    flops = 3 * attention_flops(n, span_keys_sum(0, seq)) * sequences
+    per_layer = 4 * (n["H"] + n["KV"]) * n["hd"] * seq * act_bytes
+    return flops, per_layer * n["L"] * sequences
+
+
 # -- the paged attention kernels ---------------------------------------------
 
 

@@ -1,6 +1,7 @@
-"""Forward and backward FLOPs of the traced steps' tokens (three times
-the forward, recomputation not counted; chip_bench/flops.py) over the
-traced window x chips x the chip's published bf16 peak."""
+"""Forward and backward FLOPs of the traced steps' tokens, as the
+configuration's reference module counts them (``train_flops``: three
+times the forward, recomputation not counted; chip_bench/flops.py), over
+the traced window x chips x the chip's published bf16 peak."""
 
 
 def read(r):

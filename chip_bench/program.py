@@ -1,8 +1,9 @@
 """The benchmark's only contact with the program under test: its public
 entry points (``repro.launch.serve.build_engine`` and
 ``repro.launch.train.build_trainer``), its model configuration type,
-and the request type the engine drains. Everything else the benchmark
-needs it computes itself."""
+the request type the engine drains, and its telemetry (the metrics
+registry the trainer counts into, the span tracer it names its work
+with). Everything else the benchmark needs it computes itself."""
 
 from __future__ import annotations
 
@@ -13,6 +14,7 @@ from repro.launch.runtime import use_compile_cache  # noqa: F401
 from repro.launch.serve import build_engine as _build_engine
 from repro.launch.train import build_trainer as _build_trainer
 from repro.models.config import ModelConfig
+from repro.obs.metrics import MetricsRegistry
 from repro.obs.trace import SpanTracer
 from repro.serve.scheduler import Request
 
@@ -74,11 +76,23 @@ def make_request(r: Dict[str, Any]) -> Request:
 
 
 def build_trainer(conf: Dict[str, Any], mix: Dict[str, Any],
-                  ckpt_dir: str, seed: int):
+                  ckpt_dir: str, seed: int, tracer=None):
     """The CLI's trainer for a training mix, never saving inside a run
-    (``checkpoint_every`` past any run's last step)."""
-    return _build_trainer(
+    (``checkpoint_every`` past any run's last step), with the registry
+    that the trainer, its checkpoint writer and its model count into.
+    Returns (trainer, state, registry)."""
+    registry = MetricsRegistry()
+    trainer, state = _build_trainer(
         model_config(conf), batch=mix["batch"], seq=mix["seq"],
         microbatches=mix["microbatches"], ckpt_dir=ckpt_dir,
         checkpoint_every=10 ** 9, seed=seed % (2 ** 31),
-        optimizer=mix["optimizer"]["name"])
+        optimizer=mix["optimizer"]["name"], metrics=registry,
+        tracer=tracer)
+    return trainer, state, registry
+
+
+def counters(registry) -> Dict[str, float]:
+    """The registry's counters and gauges by name (histograms left
+    out)."""
+    return {k: float(v) for k, v in registry.snapshot().items()
+            if not isinstance(v, dict)}

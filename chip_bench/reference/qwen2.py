@@ -32,6 +32,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from chip_bench import flops
+
 Q_BLOCK = 512  # query rows per attention block
 V_BLOCK = 1024  # rows per vocabulary-projection block
 
@@ -43,6 +45,15 @@ def dims(conf: Dict[str, Any]) -> Dict[str, int]:
             "KV": conf["num_key_value_heads"],
             "hd": conf.get("head_dim") or d // h,
             "F": conf["intermediate_size"], "V": conf["vocab_size"]}
+
+
+def train_flops(conf: Dict[str, Any], seq: int, tokens: int,
+                counters: Dict[str, float]) -> float:
+    """Forward and backward FLOPs of ``tokens`` trained tokens in
+    sequences of ``seq`` (three times the forward; ``flops.py``). The
+    work of a dense model follows from its shapes alone, so the
+    program's ``counters`` over those steps are not read."""
+    return flops.train_token_flops(dims(conf), seq) * tokens
 
 
 STATIC_KEYS = ("hidden_size", "num_attention_heads", "num_key_value_heads",

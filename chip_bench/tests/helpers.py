@@ -8,6 +8,7 @@ import time
 from pathlib import Path
 
 DATA = Path(__file__).resolve().parent / "data"
+ROOT = DATA.parents[2]
 
 # Smoke-size limits. On the CPU the program and the reference both
 # compute in float32 at full precision, so a sound run reads 0 (served

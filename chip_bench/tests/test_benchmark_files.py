@@ -1,13 +1,12 @@
-"""Every cell of BENCHMARK.json finds its files by name."""
+"""Every cell of BENCHMARK.json finds its files by name, and every
+configuration's reference module gives what the readers read."""
 
 from __future__ import annotations
 
 import json
-from pathlib import Path
 
-from chip_bench import traffic
-
-ROOT = Path(__file__).resolve().parents[2]
+from chip_bench import common, traffic
+from chip_bench.tests.helpers import ROOT
 
 
 def test_every_cell_finds_its_files():
@@ -23,5 +22,7 @@ def test_every_cell_finds_its_files():
         for part in ("reference", "adapters"):
             assert (ROOT / "chip_bench" / part
                     / f"{conf['model_type']}.py").is_file()
+        ref = common.reference_module(conf)
+        assert callable(ref.dims) and callable(ref.train_flops)
     for m in bench["per_layer"]:
         assert (ROOT / "chip_bench" / "metrics" / f"{m['name']}.py").is_file()

@@ -3,13 +3,14 @@ peaks table."""
 
 from __future__ import annotations
 
+import json
 import math
 
 import pytest
 
 from chip_bench import flops, peaks
 from chip_bench.reference import qwen2
-from chip_bench.tests.helpers import load
+from chip_bench.tests.helpers import ROOT, load
 
 N = {"L": 2, "D": 64, "H": 4, "KV": 2, "hd": 16, "F": 128, "V": 8192}
 
@@ -81,6 +82,27 @@ def test_train_token_is_three_forwards():
     fwd = sum(flops.forward_token_flops(N, i + 1, logits=True)
               for i in range(seq))
     assert flops.train_token_flops(N, seq) == pytest.approx(3 * fwd / seq)
+
+
+def test_qwen2_train_flops_is_the_dense_formula():
+    # the cell's shape: 4 traced steps of 2 x 4096 tokens
+    conf = json.loads((ROOT / "chip_bench" / "configs"
+                       / "qwen2-0.5b.json").read_text())
+    seq, tokens = 4096, 4 * 2 * 4096
+    got = qwen2.train_flops(conf, seq, tokens, {"train_steps": 7.0})
+    want = flops.train_token_flops(qwen2.dims(conf), seq) * tokens
+    assert got.hex() == want.hex()
+
+
+def test_flash_train_work_by_hand():
+    # 3 sequences of 8: causal keys 1 + ... + 8 = 36 a sequence; the
+    # forward's scores and weighted sum 2 x 2 x 4 heads x 16 x 36 a
+    # layer, three times over; q, o, dq, do at 4 heads and k, v, dk, dv
+    # at 2, 8 rows of 16 each, float32
+    f, b = flops.flash_train_work(N, 8, 3)
+    assert f == 3 * (2 * 2 * 4 * 16 * 36) * 2 * 3
+    assert b == (4 * 4 + 4 * 2) * 8 * 16 * 4 * 2 * 3
+    assert flops.flash_train_work(N, 8, 3, act_bytes=2)[1] == b // 2
 
 
 def test_peaks_table():

@@ -93,3 +93,62 @@ def test_breakdown(summary):
                                                reverse=True)
     assert not any(name.startswith("while") for name, _ in b["device_ops"])
     assert b["device_ops"][0][0].startswith("paged_decode_attention")
+
+
+# A synthetic host timeline (ns): the window 0-1000 holds two steps, each
+# train.step > train.batch, train.dispatch (> bench.call), train.sync;
+# a checkpoint span runs past the window's end.
+SPANS = [(0, 480, "train.step"), (0, 40, "train.batch"),
+         (40, 200, "train.dispatch"), (60, 120, "bench.call"),
+         (200, 480, "train.sync"),
+         (500, 980, "train.step"), (500, 530, "train.batch"),
+         (530, 700, "train.dispatch"), (700, 980, "train.sync"),
+         (950, 1400, "ckpt.write"), (-300, -100, "train.batch")]
+
+
+def test_program_spans_inside_the_window():
+    got = trace.program_spans(SPANS, (0, 1000))
+    assert set(got) == {"train.step", "train.batch", "train.dispatch",
+                        "train.sync", "ckpt.write"}
+    assert got["train.batch"] == [pytest.approx(70e-9), 2]
+    assert got["train.step"] == [pytest.approx(960e-9), 2]
+    assert got["ckpt.write"] == [pytest.approx(50e-9), 1]  # clipped
+
+
+def test_idle_time_is_named_by_the_innermost_span():
+    # device busy 100-300 and 380-940: idle 0-100 lies in train.step >
+    # train.batch (0-40), > train.dispatch (40-60) and > bench.call
+    # (60-100); 300-380 in train.step > train.sync; 940-1000 in
+    # train.step > train.sync up to 980 (ckpt.write, longer, covers it
+    # too), then in ckpt.write alone
+    merged = [(100, 300), (380, 940)]
+    got = trace._label_gaps(merged, 0, 1000, SPANS)
+    assert got == [("train.batch", pytest.approx(40e-9)),
+                   ("train.dispatch", pytest.approx(20e-9)),
+                   ("bench.call", pytest.approx(40e-9)),
+                   ("train.sync", pytest.approx(80e-9)),
+                   ("train.sync", pytest.approx(40e-9)),
+                   ("ckpt.write", pytest.approx(20e-9))]
+    # between the two steps no span covers the host
+    got = trace._label_gaps([(0, 490), (520, 1000)], 0, 1000, SPANS)
+    assert got == [("host loop", pytest.approx(10e-9)),
+                   ("train.batch", pytest.approx(20e-9))]
+    assert trace._label_gaps([(0, 500)], 0, 1000, []) == \
+        [("host loop", pytest.approx(500e-9))]
+
+
+def test_chrome_spans_pair_begin_and_end():
+    from repro.obs.trace import SpanTracer
+    clock = iter(range(100)).__next__
+    t = SpanTracer(clock=lambda: float(clock()))
+    pid = t.process("train")
+    with t.span("ckpt", pid=pid):
+        with t.span("gather", pid=t.process("ckpt")):
+            pass
+    with t.span("ckpt", pid=pid):
+        pass
+    assert trace.chrome_processes(t.events) == {0: "train", 1: "ckpt"}
+    got = trace.chrome_spans(t.events)
+    # clock ticks: train.ckpt 0-3 and 4-5, ckpt.gather 1-2
+    assert got == {"train.ckpt": [pytest.approx(4.0), 2],
+                   "ckpt.gather": [pytest.approx(1.0), 1]}

@@ -27,7 +27,7 @@ def test_faults_are_not_correct(tmp_path, monkeypatch, fault):
     real_build = program.build_trainer
 
     def build(*a, **kw):
-        trainer, state = real_build(*a, **kw)
+        trainer, state, registry = real_build(*a, **kw)
         step = trainer.train_step
 
         def broken(state, batch):
@@ -40,7 +40,7 @@ def test_faults_are_not_correct(tmp_path, monkeypatch, fault):
             return step(state, batch)
 
         trainer.train_step = broken
-        return trainer, state
+        return trainer, state, registry
 
     monkeypatch.setattr(program, "build_trainer", build)
     res = execute(make_run("train", tmp_path))

@@ -6,26 +6,29 @@ it. The device planes (``/device:TPU:<i>``) carry one line of XLA
 module executions (one event per call of a jitted program, named after
 it) and one of XLA operations (one event per operation, Pallas kernels
 among them, named after their HLO instruction and kernel). The host
-plane carries the benchmark's own ``TraceAnnotation`` spans: the traced
-window (``chip_bench:<label>``) and, inside it, spans around the calls
-the benchmark makes into the program.
+plane carries the benchmark's own ``TraceAnnotation`` spans (the traced
+window, ``chip_bench:<label>``, and ``bench.<name>`` around calls the
+benchmark makes into the program) and the program's own spans, named
+``<process>.<name>`` (``train.sync``, ``ckpt.write``) by its enabled
+``SpanTracer``.
 
 Busy time is the union of the operation intervals inside the window;
 the idle share is one minus busy over the window. Program and kernel
 times are sums of event durations; all device numbers are means over
-the chips the run uses.
+the chips the run uses. Idle time is named by the innermost host span
+over each part of it.
 """
 
 from __future__ import annotations
 
-import bisect
 import contextlib
 import re
 import shutil
 from pathlib import Path
-from typing import Dict, Iterator, List, Optional, Tuple
+from typing import Dict, Iterable, Iterator, List, Optional, Tuple
 
 WINDOW = "chip_bench:"
+BENCH = "bench."
 MODULE_LINE = "XLA Modules"
 OP_LINE = "XLA Ops"
 TOP = 10
@@ -64,6 +67,33 @@ def annotate(fn, name: str):
         with span(name):
             return fn(*args, **kwargs)
     return wrapped
+
+
+def chrome_processes(events) -> Dict[int, str]:
+    """{pid: process name} of a ``SpanTracer``'s Chrome events."""
+    return {e["pid"]: e["args"]["name"] for e in events
+            if e.get("ph") == "M" and e.get("name") == "process_name"}
+
+
+def chrome_spans(events) -> Dict[str, list]:
+    """{``<process>.<name>``: [seconds, count]} of the closed B/E pairs
+    in a ``SpanTracer``'s Chrome events (microseconds), the same names
+    its spans carry in the profiler's trace."""
+    procs = chrome_processes(events)
+    open_: Dict[Tuple[int, int], list] = {}
+    out: Dict[str, list] = {}
+    for e in events:
+        lane = (e.get("pid", 0), e.get("tid", 0))
+        if e.get("ph") == "B":
+            open_.setdefault(lane, []).append(e)
+        elif e.get("ph") == "E" and open_.get(lane):
+            b = open_[lane].pop()
+            proc = procs.get(b["pid"])
+            name = f"{proc}.{b['name']}" if proc else b["name"]
+            s = out.setdefault(name, [0.0, 0])
+            s[0] += (e["ts"] - b["ts"]) / 1e6
+            s[1] += 1
+    return out
 
 
 def _merge(intervals: List[Tuple[int, int]]) -> List[Tuple[int, int]]:
@@ -105,12 +135,13 @@ class Summary:
 
     def __init__(self, window_s: float, busy_s: float,
                  programs: Dict[str, float], ops: Dict[str, float],
-                 gaps: List[Tuple[str, float]]):
+                 gaps: List[Tuple[str, float]], spans: Dict[str, list]):
         self.window_s = window_s
         self.busy_s = busy_s
         self.programs = programs
         self.ops = ops
         self.gaps = gaps
+        self.spans = spans  # program_spans() of the window
 
     def program_s(self, name: str) -> float:
         """Device seconds of the jitted program ``name``."""
@@ -145,29 +176,49 @@ def xplane_file(directory: Path) -> Path:
     return files[-1]
 
 
-def _host_spans(plane) -> Tuple[Optional[Tuple[int, int]], list]:
-    """(window interval, [(start, end, name)] of the benchmark's spans)."""
+def _host_spans(plane, processes: Iterable[str]
+                ) -> Tuple[Optional[Tuple[int, int]], list]:
+    """(window interval, [(start, end, name)] of the benchmark's
+    ``bench.`` spans and of the spans of the program's ``processes``)."""
+    prefixes = (BENCH,) + tuple(f"{p}." for p in processes)
     window, spans = None, []
     for line in plane.lines:
         for e in line.events:
             a, b = e.start_ns, e.start_ns + e.duration_ns
             if e.name.startswith(WINDOW):
                 window = (a, b)
-            elif e.name.startswith("bench."):
+            elif e.name.startswith(prefixes):
                 spans.append((a, b, e.name))
     return window, spans
 
 
-def reduce(directory: Path, chips: int) -> Summary:
+def program_spans(spans, window: Tuple[int, int]) -> Dict[str, list]:
+    """{name: [seconds inside the window, count]} of the host spans
+    (``(start, end, name)``, in ns) that overlap the window, the
+    benchmark's ``bench.`` spans left out."""
+    lo, hi = window
+    out: Dict[str, list] = {}
+    for a, b, name in spans:
+        a, b = max(a, lo), min(b, hi)
+        if b > a and not name.startswith(BENCH):
+            s = out.setdefault(name, [0.0, 0])
+            s[0] += (b - a) / 1e9
+            s[1] += 1
+    return out
+
+
+def reduce(directory: Path, chips: int,
+           processes: Iterable[str] = ()) -> Summary:
     """Reduce the newest trace under ``directory`` over ``chips`` device
-    planes."""
+    planes; ``processes`` are the program's span processes (``train``,
+    ``ckpt``), whose spans name idle gaps and fill ``Summary.spans``."""
     from jax.profiler import ProfileData
     data = ProfileData.from_file(str(xplane_file(directory)))
     window, spans = None, []
     devices = []
     for plane in data.planes:
         if plane.name.startswith("/host:"):
-            w, s = _host_spans(plane)
+            w, s = _host_spans(plane, processes)
             window = window or w
             spans += s
         elif plane.name.startswith("/device:TPU:") and \
@@ -203,23 +254,31 @@ def reduce(directory: Path, chips: int) -> Summary:
     n = len(devices)
     return Summary(window_s=(hi - lo) / 1e9, busy_s=busy_total / n,
                    programs={k: v / n for k, v in programs.items()},
-                   ops={k: v / n for k, v in ops.items()}, gaps=gaps)
+                   ops={k: v / n for k, v in ops.items()}, gaps=gaps,
+                   spans=program_spans(spans, window))
 
 
 def _label_gaps(merged, lo, hi, spans) -> List[Tuple[str, float]]:
-    """Each idle stretch of the first device, named by the benchmark span
-    around its midpoint (``host loop`` where none is). The benchmark's
-    spans do not nest, so the one that starts last before the midpoint
-    is the only candidate."""
-    spans = sorted(spans)
-    starts = [s[0] for s in spans]
-    out = []
+    """The idle stretches of the first device, each cut where a host
+    span begins or ends, every piece named by the innermost (shortest)
+    span that covers it (``host loop`` where none does): the idle time
+    by what the host was doing."""
+    out: List[Tuple[str, float]] = []
     edges = [lo] + [x for ab in merged for x in ab] + [hi]
     for a, b in zip(edges[0::2], edges[1::2]):
         if b <= a:
             continue
-        mid = (a + b) / 2
-        i = bisect.bisect_right(starts, mid) - 1
-        label = spans[i][2] if i >= 0 and spans[i][1] >= mid else "host loop"
-        out.append((label, (b - a) / 1e9))
+        inside = [sp for sp in spans if sp[0] < b and sp[1] > a]
+        cuts = sorted({a, b} | {t for s, e, _ in inside for t in (s, e)
+                                if a < t < b})
+        pieces: List[list] = []  # [label, ns], one per run of a label
+        for x, y in zip(cuts, cuts[1:]):
+            around = [(e - s, name) for s, e, name in inside
+                      if s <= x and y <= e]
+            label = min(around)[1] if around else "host loop"
+            if pieces and pieces[-1][0] == label:
+                pieces[-1][1] += y - x
+            else:
+                pieces.append([label, y - x])
+        out += [(label, ns / 1e9) for label, ns in pieces]
     return out

@@ -4,12 +4,14 @@ Set-up builds the CLI's trainer (its compiled step and its state), puts
 the weights the benchmark made from the seed into that state, gives the
 trainer the benchmark's feed, and drives the first three steps through
 ``trainer.run`` (the first writes the trainer's bootstrap checkpoint).
+In a ``--trace 1`` run the trainer is given an enabled span tracer, so
+its spans name its work in set-up and in the traced window.
 It reads, from the program's own state, the norms of the first clipped
 gradient (AdamW's first moment after one step, over 1 - b1) and of the
 weights' change over the three steps. The same trainer and state then
 run the window: one ``run`` call per step, each ending in the loss's
 host sync, until ``--seconds`` have passed. A ``--trace 1`` run traces
-``trace_steps`` steps.
+``trace_steps`` steps, and its per-layer readers read ``reading()``.
 
 Once the window has closed and the program's state is freed, the plain
 reference runs the same three steps from the same weights and batches.
@@ -25,7 +27,7 @@ from typing import Any, Dict
 
 import numpy as np
 
-from chip_bench import common, flops, program, traffic
+from chip_bench import common, program, traffic
 from chip_bench import trace as tr
 
 CHECK_STEPS = 3
@@ -113,6 +115,56 @@ def fresh_state(ref, run, state, key):
             "step": jnp.zeros((), jnp.int32)}
 
 
+def window(run, trainer, state, registry):
+    """Drive the window: one ``trainer.run`` call per step, each ending
+    in the loss's host sync, until ``--seconds`` have passed (a
+    ``--trace 1`` run: ``trace_steps`` steps, under the profiler).
+    Returns (state, the window's numbers)."""
+    step = CHECK_STEPS
+    step_s = []
+    # traced, the call is a span of its own: the host time that
+    # ``ResilientTrainer.run`` spends outside its step spans (the step
+    # counter's transfer, the checkpoint listing) is then named
+    run_to = (tr.annotate(trainer.run, "bench.run") if run.trace
+              else trainer.run)
+    counters0 = program.counters(registry)
+    w0 = run.clock()
+    with tr.capture(run.out_dir / "trace", run.trace, "steps"):
+        while True:
+            state, _, _ = run_to(state, step + 1)
+            step_s.append(trainer.records[-1].duration_s)
+            step += 1
+            if run.trace:
+                if step - CHECK_STEPS >= run.mix["trace_steps"]:
+                    break
+            elif run.clock() - w0 >= run.seconds:
+                break
+    return state, {"seconds": run.clock() - w0, "steps": step - CHECK_STEPS,
+                   "step_s": step_s, "counters0": counters0,
+                   "counters": program.counters(registry)}
+
+
+def reading(run, summary, win, setup_spans) -> Dict[str, Any]:
+    """What the per-layer readers read of a traced window: the trace
+    (``Summary``, with the program's spans in the window), the model
+    FLOPs of its tokens from the configuration's reference module, the
+    program's counters at its end and their change over it, and the
+    program's spans in set-up (``trace.chrome_spans``)."""
+    ref = common.reference_module(run.conf)
+    mix = run.mix
+    c0, c1 = win["counters0"], win["counters"]
+    delta = {k: v - c0.get(k, 0.0) for k, v in c1.items()}
+    sequences = win["steps"] * mix["batch"]
+    return {"kind": "train", "trace": summary, "peaks": run.peaks,
+            "chips": len(run.devices), "conf": run.conf,
+            "dims": ref.dims(run.conf), "seq": mix["seq"],
+            "steps": win["steps"], "sequences": sequences,
+            "model_flops": ref.train_flops(run.conf, mix["seq"],
+                                           sequences * mix["seq"], delta),
+            "counters": c1, "counters_window": delta,
+            "spans": summary.spans, "setup_spans": setup_spans}
+
+
 def run_cell(run: common.Run, bench: Dict[str, Any], counter) -> str:
     ref = common.reference_module(run.conf)
     mix = run.mix
@@ -121,8 +173,12 @@ def run_cell(run: common.Run, bench: Dict[str, Any], counter) -> str:
     shutil.rmtree(ckpt, ignore_errors=True)  # the trainer would resume it
     t = run.clock()
     key = common.seed_key(run.seed)
-    trainer, state = program.build_trainer(run.conf, mix, str(ckpt),
-                                           run.seed)
+    # the program names its work (in the profiler's trace and in the
+    # tracer's events) only in the traced run, so that the end-to-end
+    # metrics are measured with its tracer off
+    tracer = program.SpanTracer(clock=run.clock) if run.trace else None
+    trainer, state, registry = program.build_trainer(
+        run.conf, mix, str(ckpt), run.seed, tracer=tracer)
     t = run.mark("trainer", t)
     state = fresh_state(ref, run, state, key)
     t = run.mark("weights", t)
@@ -139,53 +195,35 @@ def run_cell(run: common.Run, bench: Dict[str, Any], counter) -> str:
     t = run.mark("ckpt_sync", t)
     setup_s = run.clock() - run.t_start
 
-    if run.trace:
-        trainer.train_step = tr.annotate(trainer.train_step,
-                                         "bench.step_dispatch")
+    setup_events = list(tracer.events) if tracer else []
     compiles0 = counter.count
-    step = CHECK_STEPS
-    trace_dir = run.out_dir / "trace"
-    step_s = []
-    w0 = run.clock()
-    with tr.capture(trace_dir, run.trace, "steps"):
-        while True:
-            state, _, _ = trainer.run(state, step + 1)
-            step_s.append(trainer.records[-1].duration_s)
-            step += 1
-            if run.trace:
-                if step - CHECK_STEPS >= mix["trace_steps"]:
-                    break
-            elif run.clock() - w0 >= run.seconds:
-                break
-    window_s = run.clock() - w0
-    steps = step - CHECK_STEPS
+    state, win = window(run, trainer, state, registry)
+    steps = win["steps"]
     in_window = counter.count - compiles0
     peak = common.peak_memory_bytes(run.devices)
     tokens = steps * mix["batch"] * mix["seq"]
-    common.log(f"window {window_s:.3f} s, {steps} steps, {tokens} tokens; "
-               f"compiles in window {in_window}; set-up {setup_s:.3f} s "
-               f"{run.setup}")
+    common.log(f"window {win['seconds']:.3f} s, {steps} steps, {tokens} "
+               f"tokens; compiles in window {in_window}; set-up "
+               f"{setup_s:.3f} s {run.setup}")
+    step_s = win["step_s"]
     common.log(f"steps: median {np.median(step_s):.4f} s, slowest "
                f"{max(step_s):.4f} s (window step {int(np.argmax(step_s))})")
     metrics: Dict[str, Any] = {}
     device_extra: Dict[str, Any] = {"memory_peak_bytes": peak}
     breakdown = None
     if run.trace:
-        summary = tr.reduce(trace_dir, len(run.devices))
-        n = ref.dims(run.conf)
-        reading = {"kind": "train", "trace": summary, "peaks": run.peaks,
-                   "chips": len(run.devices), "dims": n,
-                   "model_flops": flops.train_token_flops(n, mix["seq"])
-                   * tokens}
+        summary = tr.reduce(run.out_dir / "trace", len(run.devices),
+                            tr.chrome_processes(tracer.events).values())
+        r = reading(run, summary, win, tr.chrome_spans(setup_events))
         for name, (read, unit) in common.per_layer_readers(
                 bench, run.name).items():
-            v = read(reading)
+            v = read(r)
             if v is not None:
                 metrics[name] = {"value": v, "unit": unit}
         device_extra.update(busy_s=summary.busy_s, window_s=summary.window_s)
         breakdown = summary.breakdown()
     else:
-        metrics = {"train_tok_s": {"value": tokens / window_s,
+        metrics = {"train_tok_s": {"value": tokens / win["seconds"],
                                    "unit": "tokens/s"},
                    "setup_s": {"value": setup_s, "unit": "s"}}
 

@@ -26,6 +26,7 @@ keep it in VMEM. Only blocks that straddle a mask boundary build a mask.
 Bidirectional attention skips nothing.
 
 Layout: (B*H, S, D) — heads flattened into the grid's leading dimension.
+q and k share one width, v and o may have another (MLA: 192 and 128).
 Block sizes come from the VMEM budget (``flash_block``); head_dim rides as
 the minor dimension (Mosaic pads it to 128 lanes).
 """
@@ -51,20 +52,26 @@ _TRANS_B = (((1,), (1,)), ((), ()))  # x @ y.T
 _PLAIN = (((1,), (0,)), ((), ()))  # x @ y
 
 
-def flash_block(s: int, d: int, dtype, mxu) -> int:
+def flash_block(s: int, d: int, dtype, mxu,
+                d_v: Optional[int] = None) -> int:
     """Rows of q and of k per grid step: the largest divisor of ``s``, a
     multiple of 128, whose working set fits the VMEM budget. Counted for
-    the heaviest kernel (dkv), at padded tile size: its six (block, d)
-    streams in ``dtype`` double-buffered (q, dO, k, v in; dK, dV out),
-    the lse and D rows, two f32 (block, d) accumulators, and a step's two
-    (block, block) dot operands (P and dS) in the MXU's ``mxu`` dtype.
-    Against the v5e compiler (S 4096, d 64, f32 blocks): bf16 operands
-    at 1024 x 1024 need 9.4 MiB (counted 11.1, taken); f32 operands need
-    17.9 MiB there (counted 15.1, refused) and 5.7 at 512 x 512."""
+    the heaviest kernel (dkv), at padded tile size: its six streams in
+    ``dtype`` double-buffered (q, k, dK at the q/k width ``d``; dO, v, dV
+    at the v width ``d_v``, default ``d``), the lse and D rows, the f32
+    dK and dV accumulators, and a step's two (block, block) dot operands
+    (P and dS) in the MXU's ``mxu`` dtype. Against the v5e compiler
+    (S 4096, d 64, f32 blocks): bf16 operands at 1024 x 1024 need 9.4 MiB
+    (counted 11.1, taken); f32 operands need 17.9 MiB there (counted
+    15.1, refused) and 5.7 at 512 x 512."""
+    d_v = d if d_v is None else d_v
+
     def fits(b: int) -> bool:
-        stream = 2 * (6 * tile_bytes((b, d), dtype)
+        stream = 2 * (3 * tile_bytes((b, d), dtype)
+                      + 3 * tile_bytes((b, d_v), dtype)
                       + 2 * tile_bytes((1, b), jnp.float32))
-        acc = 2 * tile_bytes((b, d), jnp.float32)
+        acc = (tile_bytes((b, d), jnp.float32)
+               + tile_bytes((b, d_v), jnp.float32))
         work = 2 * tile_bytes((b, b), mxu)
         return stream + acc + work <= BUDGET_BYTES
     return largest_block(s, fits, align=LANES)
@@ -198,14 +205,21 @@ def _fwd_kernel(qi_ref, kj_ref, fl_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
         lse_ref[0] = lse.T[:1]  # one lane-dense (1, bq) row
 
 
-def _specs(block_q: int, block_k: int, d: int):
-    """BlockSpecs of a q-side block, a k-side block and a (1, block_q)
+def _specs(block_q: int, block_k: int, d: int, d_v: int):
+    """BlockSpecs of q-side blocks (q at width ``d``; o and dO at
+    ``d_v``), k-side blocks (k at ``d``, v at ``d_v``) and a (1, block_q)
     statistics row, each placed by the scalar-prefetched block table."""
-    q = pl.BlockSpec((1, block_q, d), lambda h, t, qi, kj, fl: (h, qi[t], 0))
-    k = pl.BlockSpec((1, block_k, d), lambda h, t, qi, kj, fl: (h, kj[t], 0))
+    def q_side(w):
+        return pl.BlockSpec((1, block_q, w),
+                            lambda h, t, qi, kj, fl: (h, qi[t], 0))
+
+    def k_side(w):
+        return pl.BlockSpec((1, block_k, w),
+                            lambda h, t, qi, kj, fl: (h, kj[t], 0))
+
     row = pl.BlockSpec((1, 1, block_q),
                        lambda h, t, qi, kj, fl: (h, 0, qi[t]))
-    return q, k, row
+    return q_side(d), q_side(d_v), k_side(d), k_side(d_v), row
 
 
 def _call(kernel, table: np.ndarray, bh: int, in_specs, out_specs,
@@ -229,20 +243,21 @@ def _forward(q: Array, k: Array, v: Array, *, causal: bool,
              window: Optional[int], block_q: int, block_k: int,
              interpret: bool) -> Tuple[Array, Array]:
     bh, s, d = q.shape
+    d_v = v.shape[-1]
     table = block_table(s // block_q, s // block_k, block_q, block_k,
                         causal, window)
-    q_spec, k_spec, row = _specs(block_q, block_k, d)
+    q_spec, o_spec, k_spec, v_spec, row = _specs(block_q, block_k, d, d_v)
     return _call(
         functools.partial(
             _fwd_kernel, block_q=block_q, block_k=block_k, causal=causal,
             window=window, scale=d ** -0.5,
             mxu=mxu_dtype(q.dtype, interpret)),
-        table, bh, [q_spec, k_spec, k_spec], [q_spec, row],
-        [jax.ShapeDtypeStruct((bh, s, d), q.dtype),
+        table, bh, [q_spec, k_spec, v_spec], [o_spec, row],
+        [jax.ShapeDtypeStruct((bh, s, d_v), q.dtype),
          jax.ShapeDtypeStruct((bh, 1, s), jnp.float32)],
         [pltpu.VMEM((block_q, LANES), jnp.float32),
          pltpu.VMEM((block_q, LANES), jnp.float32),
-         pltpu.VMEM((block_q, d), jnp.float32)],
+         pltpu.VMEM((block_q, d_v), jnp.float32)],
         interpret, "flash_fwd",
     )(*table, q, k, v)
 
@@ -321,14 +336,15 @@ def _dkv_kernel(qi_ref, kj_ref, fl_ref, q_ref, k_ref, v_ref, do_ref,
 def _backward(q, k, v, o, lse, do, *, causal: bool, window: Optional[int],
               block_q: int, block_k: int, interpret: bool):
     bh, s, d = q.shape
+    d_v = v.shape[-1]
     n_q, n_k = s // block_q, s // block_k
     di = jnp.sum(o.astype(jnp.float32) * do.astype(jnp.float32),
                  axis=-1)[:, None, :]  # (bh, 1, s)
     kw = dict(block_q=block_q, block_k=block_k, causal=causal,
               window=window, scale=d ** -0.5,
               mxu=mxu_dtype(q.dtype, interpret))
-    q_spec, k_spec, row = _specs(block_q, block_k, d)
-    ins = [q_spec, k_spec, k_spec, q_spec, row, row]
+    q_spec, o_spec, k_spec, v_spec, row = _specs(block_q, block_k, d, d_v)
+    ins = [q_spec, k_spec, v_spec, o_spec, row, row]
     table = block_table(n_q, n_k, block_q, block_k, causal, window)
     dq = _call(
         functools.partial(_dq_kernel, **kw), table, bh, ins, q_spec,
@@ -343,11 +359,11 @@ def _backward(q, k, v, o, lse, do, *, causal: bool, window: Optional[int],
                         kv_major=True)
     dk, dv = _call(
         functools.partial(_dkv_kernel, **kw), table, bh, ins,
-        [k_spec, k_spec],
+        [k_spec, v_spec],
         [jax.ShapeDtypeStruct(k.shape, k.dtype),
          jax.ShapeDtypeStruct(v.shape, v.dtype)],
         [pltpu.VMEM((block_k, d), jnp.float32),
-         pltpu.VMEM((block_k, d), jnp.float32)],
+         pltpu.VMEM((block_k, d_v), jnp.float32)],
         interpret, "flash_bwd_dkv",
     )(*table, q, k, v, do, lse, di)
     return dq, dk, dv
@@ -386,11 +402,12 @@ def flash_attention(
     block_k: Optional[int] = None,
     interpret: bool = False,
 ) -> Array:
-    """q,k,v: (BH, S, D) -> (BH, S, D), differentiable in q, k and v.
-    ``block_q``/``block_k`` None size the blocks from VMEM
-    (``flash_block``)."""
+    """q, k: (BH, S, D); v: (BH, S, Dv) -> (BH, S, Dv), differentiable in
+    q, k and v; the scores are scaled by D ** -0.5. ``block_q``/``block_k``
+    None size the blocks from VMEM (``flash_block``)."""
     bh, s, d = q.shape
-    block = flash_block(s, d, q.dtype, mxu_dtype(q.dtype, interpret))
+    block = flash_block(s, d, q.dtype, mxu_dtype(q.dtype, interpret),
+                        v.shape[-1])
     block_q = min(block_q or block, s)
     block_k = min(block_k or block, s)
     if s % block_q or s % block_k:

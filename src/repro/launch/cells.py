@@ -20,6 +20,7 @@ from repro.configs.registry import Cell, CellSettings, ShapeSpec, get_cell
 from repro.models import api
 from repro.models.blocks import CACHE_LOGICAL, ModelContext
 from repro.models.config import ModelConfig
+from repro.models.lm import MOE_COUNTERS
 from repro.models.params import axes_tree, shapes_tree
 from repro.optim.optimizers import Optimizer, adafactor, adamw, \
     cosine_schedule
@@ -194,8 +195,9 @@ def build_cell(arch: str, shape: str, mesh: Mesh,
         state_sds = {"params": p_sds, "opt": o_sds, "step": step_sds}
         args = (state_sds, batch_sds)
         in_sh = (_shardings_of(state_sds), _shardings_of(batch_sds))
+        counted = MOE_COUNTERS if cfg.n_experts else ()
         metrics_sh = {k: repl for k in
-                      ("loss", "xent", "tokens", "grad_norm")}
+                      ("loss", "xent", "tokens", "grad_norm", *counted)}
         out_sh = (_shardings_of(state_sds), metrics_sh)
         trips = cfg.n_blocks * cell.settings.microbatches
         return BuiltCell(cell, mesh, step, args, in_sh, out_sh,

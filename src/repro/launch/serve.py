@@ -111,6 +111,12 @@ def build_engine(cfg: ModelConfig, *, window: int, max_batch: int = 4,
     Pallas kernels on a TPU, jnp elsewhere; a caller names it only to
     build a reference engine. Speculation and the prefix cache apply to
     the paged families and are switched off for the others."""
+    if cfg.mla:
+        raise ValueError(
+            f"{cfg.name}: latent attention (MLA) cannot be served yet: the "
+            f"engine has no latent KV cache (pages of kv_lora_rank + "
+            f"qk_rope_dim = {cfg.kv_lora_rank + cfg.qk_rope_dim} values a "
+            f"token) and no latent paged kernel")
     if attn_impl is None:
         attn_impl = "pallas" if jax.default_backend() == "tpu" else "xla"
     ctx = ModelContext(

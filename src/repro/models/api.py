@@ -78,9 +78,13 @@ def decode_span_fn(params, tokens, cache, cfg: ModelConfig,
 
 
 def supports_paged_decode(cfg: ModelConfig) -> bool:
-    """Paged KV applies to pure-attention decoder-only stacks; SSM/RWKV
-    sublayers carry O(1) state and encoder-decoder keeps cross-KV."""
-    return (not cfg.is_encoder_decoder
+    """Paged KV applies to pure-attention decoder-only stacks of one
+    scanned layer stack; SSM/RWKV sublayers carry O(1) state,
+    encoder-decoder keeps cross-KV, and latent attention (MLA) needs
+    latent pages (kv_lora_rank + qk_rope_dim a token) the pool does not
+    have."""
+    return (not cfg.is_encoder_decoder and not cfg.mla
+            and not cfg.first_dense_layers
             and set(cfg.sublayer_kinds()) == {"attn"})
 
 

@@ -110,7 +110,8 @@ def full_attention(
     impl: str = "xla",
     metrics=None,
 ) -> jax.Array:
-    """Training/prefill attention. q: (B,S,H,D); k,v: (B,S,KV,D).
+    """Training/prefill attention. q, k: (B,S,H|KV,D); v: (B,S,KV,Dv)
+(Dv differs from D under MLA). Returns (B,S,H,Dv).
 
     impl="pallas"/"pallas_interpret" routes through the flash-attention
     kernels (kernels/flash_attention.py) when the sequence tiles: heads
@@ -128,12 +129,13 @@ def full_attention(
         from repro.kernels import ops as kops
         kf = _expand_kv(k, h)
         vf = _expand_kv(v, h)
-        fold = lambda x: x.transpose(0, 2, 1, 3).reshape(b * h, s, d)
+        fold = lambda x: x.transpose(0, 2, 1, 3).reshape(  # noqa: E731
+            b * h, s, x.shape[-1])
         out = kops.flash_attention(
             fold(q), fold(kf), fold(vf),
             impl="interpret" if impl == "pallas_interpret" else "pallas",
             causal=atype != "bidirectional", window=win)
-        return out.reshape(b, h, s, d).transpose(0, 2, 1, 3)
+        return out.reshape(b, h, s, -1).transpose(0, 2, 1, 3)
     kpos = _positions((b,), k.shape[1])
     if s % q_chunk:  # largest divisor of s that fits the requested chunk
         from repro.models.mamba import fit_chunk
@@ -153,7 +155,7 @@ def full_attention(
     q_chunks = q.reshape(b, n_chunks, q_chunk, h, d).transpose(1, 0, 2, 3, 4)
     starts = (jnp.arange(n_chunks) * q_chunk)[:, None].repeat(b, 1)
     _, out = jax.lax.scan(body, None, (q_chunks, starts))
-    return out.transpose(1, 0, 2, 3, 4).reshape(b, s, h, d)
+    return out.transpose(1, 0, 2, 3, 4).reshape(b, s, h, v.shape[-1])
 
 
 def decode_span_attention(

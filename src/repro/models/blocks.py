@@ -26,9 +26,9 @@ from repro.models.attention import (apply_positional, decode_attention,
 from repro.models.config import ModelConfig
 from repro.models.mamba import (mamba_decode_step, mamba_forward,
                                 mamba_param_specs)
-from repro.models.moe import (dense_ffn, dense_ffn_specs, moe_ffn,
-                              moe_param_specs)
-from repro.models.ops import rms_norm
+from repro.models.moe import (GROUPED_BLOCK_M, PALLAS_BLOCK_M, dense_ffn,
+                              dense_ffn_specs, moe_ffn, moe_param_specs)
+from repro.models.ops import apply_rope_interleaved, rms_norm
 from repro.models.params import ParamSpec, ones_init, zeros_init
 from repro.models.rwkv6 import (rwkv_channel_mix, rwkv_channel_specs,
                                 rwkv_param_specs, rwkv_time_mix)
@@ -70,10 +70,10 @@ class ModelContext:
         self.mesh = mesh
         self.data_axis = data_axis
         self.model_axis = model_axis
-        # serving MoE dispatch: "grouped" (sort-based dropless through the
-        # m-grouped GEMM kernel; the default) or "capacity" (the legacy
-        # dense dropless buffer). Training forwards always use capacity
-        # dispatch. moe_impl=None derives the kernel impl from attn_impl.
+        # MoE dispatch, training and serving alike: "grouped" (sort-based
+        # dropless through the m-grouped GEMM kernel; the default) or
+        # "capacity" (the dense dropless buffer, kept as the tests'
+        # oracle). moe_impl=None derives the kernel impl from attn_impl.
         self.moe_dispatch = moe_dispatch
         self.moe_impl = moe_impl
         # optional obs.metrics registry: the trace-time counters of which
@@ -85,14 +85,17 @@ class ModelContext:
         return self.decode_cache_dtype or self.compute_dtype
 
     def moe_kwargs(self) -> Dict[str, Any]:
-        """Serving-path moe_ffn kwargs for this context (dropless)."""
+        """moe_ffn kwargs for this context (dropless): the compiled
+        kernel takes MXU-aligned m-tiles."""
         if self.moe_dispatch != "grouped":
             return {"dropless": True}
         impl = self.moe_impl or {"pallas": "pallas",
                                  "pallas_interpret": "interpret"}.get(
                                      self.attn_impl, "ref")
         return {"dispatch": "grouped", "impl": impl, "mesh": self.mesh,
-                "expert_axis": self.data_axis}
+                "expert_axis": self.data_axis,
+                "block_m": PALLAS_BLOCK_M if impl == "pallas"
+                else GROUPED_BLOCK_M}
 
 
 # ---------------------------------------------------------------------------
@@ -100,7 +103,37 @@ class ModelContext:
 # ---------------------------------------------------------------------------
 
 
+# RMSNorm epsilon of MLA's latent norms (``q_a_layernorm``,
+# ``kv_a_layernorm`` in DeepSeek-V3's modelling code, which keep the
+# norm's default rather than ``rms_norm_eps``)
+MLA_NORM_EPS = 1e-6
+
+
+def mla_param_specs(cfg: ModelConfig) -> Dict[str, ParamSpec]:
+    """MLA: q from x (or through a ``q_lora_rank`` latent), the joint
+    ``wkv_a`` projection to the kv latent and the shared rotary key, the
+    latent's norm, and ``wkv_b`` up to per-head k_nope and v."""
+    d, h, r = cfg.d_model, cfg.n_heads, cfg.kv_lora_rank
+    dqk, dn, dv = cfg.qk_head_dim, cfg.qk_nope_dim, cfg.v_head_dim
+    specs = {
+        "wkv_a": ParamSpec((d, r + cfg.qk_rope_dim), ("embed", None)),
+        "kv_norm": ParamSpec((r,), (None,), init=ones_init()),
+        "wkv_b": ParamSpec((r, h, dn + dv), (None, "heads", "head_dim")),
+        "wo": ParamSpec((h, dv, d), ("heads", "head_dim", "embed")),
+    }
+    if cfg.q_lora_rank:
+        ql = cfg.q_lora_rank
+        specs["wq_a"] = ParamSpec((d, ql), ("embed", None))
+        specs["q_norm"] = ParamSpec((ql,), (None,), init=ones_init())
+        specs["wq_b"] = ParamSpec((ql, h, dqk), (None, "heads", "head_dim"))
+    else:
+        specs["wq"] = ParamSpec((d, h, dqk), ("embed", "heads", "head_dim"))
+    return specs
+
+
 def attn_param_specs(cfg: ModelConfig) -> Dict[str, ParamSpec]:
+    if cfg.mla:
+        return mla_param_specs(cfg)
     d, h, kv, hd = (cfg.d_model, cfg.n_heads, cfg.n_kv_heads,
                     cfg.resolved_head_dim)
     specs = {
@@ -162,8 +195,41 @@ def stack_specs(specs: Any, n: int) -> Any:
 # ---------------------------------------------------------------------------
 
 
+def _mla_qkv(p: Dict[str, Array], x: Array, cfg: ModelConfig, dtype,
+             positions: Array) -> Tuple[Array, Array, Array]:
+    """Per-head q, k (``qk_head_dim`` wide) and v (``v_head_dim``) of
+    MLA: the rotary parts of q and of the one shared rotary key are
+    rotated (DeepSeek-V3's interleaved layout), the key's is broadcast
+    to every head."""
+    r, dn = cfg.kv_lora_rank, cfg.qk_nope_dim
+    if cfg.q_lora_rank:
+        qa = rms_norm(x @ p["wq_a"].astype(dtype), p["q_norm"],
+                      MLA_NORM_EPS)
+        q = jnp.einsum("bsr,rhk->bshk", qa, p["wq_b"].astype(dtype))
+    else:
+        q = jnp.einsum("bsd,dhk->bshk", x, p["wq"].astype(dtype))
+    kv = x @ p["wkv_a"].astype(dtype)  # (B, S, r + rope)
+    latent = rms_norm(kv[..., :r], p["kv_norm"], MLA_NORM_EPS)
+    kvb = jnp.einsum("bsr,rhk->bshk", latent, p["wkv_b"].astype(dtype))
+    k_rope = apply_rope_interleaved(kv[..., None, r:], positions,
+                                    cfg.rope_theta)  # (B, S, 1, rope)
+    q = jnp.concatenate([q[..., :dn], apply_rope_interleaved(
+        q[..., dn:], positions, cfg.rope_theta)], axis=-1)
+    k = jnp.concatenate([kvb[..., :dn], jnp.broadcast_to(
+        k_rope, (*k_rope.shape[:2], cfg.n_heads, k_rope.shape[-1]))],
+        axis=-1)
+    return q, k, kvb[..., dn:]
+
+
 def _project_qkv(p: Dict[str, Array], x: Array, cfg: ModelConfig,
-                 dtype) -> Tuple[Array, Array, Array]:
+                 dtype, positions: Optional[Array] = None,
+                 mrope_positions: Optional[Array] = None
+                 ) -> Tuple[Array, Array, Array]:
+    """q, k, v of one attention sublayer at ``positions`` (B, S), with
+    their positional encoding applied (None: a stack without rotary
+    embeddings)."""
+    if cfg.mla:
+        return _mla_qkv(p, x, cfg, dtype, positions)
     q = jnp.einsum("bsd,dhk->bshk", x, p["wq"].astype(dtype))
     k = jnp.einsum("bsd,dhk->bshk", x, p["wk"].astype(dtype))
     v = jnp.einsum("bsd,dhk->bshk", x, p["wv"].astype(dtype))
@@ -171,6 +237,7 @@ def _project_qkv(p: Dict[str, Array], x: Array, cfg: ModelConfig,
         q = q + p["bq"].astype(dtype)
         k = k + p["bk"].astype(dtype)
         v = v + p["bv"].astype(dtype)
+    q, k = apply_positional(q, k, cfg, positions, mrope_positions)
     return q, k, v
 
 
@@ -181,10 +248,9 @@ def attn_forward(p: Dict[str, Array], x: Array, cfg: ModelConfig,
                  attn_type: Optional[str] = None) -> Array:
     b, s, _ = x.shape
     dtype = ctx.compute_dtype
-    q, k, v = _project_qkv(p, x, cfg, dtype)
     if positions is None:
         positions = jnp.broadcast_to(jnp.arange(s), (b, s))
-    q, k = apply_positional(q, k, cfg, positions, mrope_positions)
+    q, k, v = _project_qkv(p, x, cfg, dtype, positions, mrope_positions)
     q = ctx.shard(q, ("batch", "seq", "heads", None))
     k = ctx.shard(k, ("batch", "seq", "kv_heads", None))
     v = ctx.shard(v, ("batch", "seq", "kv_heads", None))
@@ -216,7 +282,8 @@ def sublayer_forward(p: Dict[str, Any], x: Array, cfg: ModelConfig,
     if kind == "rwkv":
         mlp = rwkv_channel_mix(p["mlp"], h, cfg, dtype)
     elif cfg.sublayer_has_moe(idx):
-        mlp, aux = moe_ffn(p["mlp"], h, cfg, dtype, shard=ctx.shard)
+        mlp, aux = moe_ffn(p["mlp"], h, cfg, dtype, shard=ctx.shard,
+                           with_counts=True, **ctx.moe_kwargs())
     else:
         mlp = dense_ffn(p["mlp"], h, cfg, dtype)
     x = x + mlp
@@ -245,15 +312,16 @@ def block_forward(block_params: Dict[str, Any], x: Array, cfg: ModelConfig,
 def sublayer_cache_spec(cfg: ModelConfig, idx: int, batch: int,
                         window: int, ctx: ModelContext) -> Dict[str, Any]:
     kind = cfg.sublayer_kinds()[idx]
-    hd = cfg.resolved_head_dim
     cdt = ctx.cache_dtype
     if kind == "attn":
         w = window
         if cfg.sliding_window is not None and not ctx.full_cache_window:
             w = min(window, cfg.sliding_window)
+        # MLA keeps each head's expanded k and v here (no latent cache)
+        kv = cfg.kv_heads
         return {
-            "k": jax.ShapeDtypeStruct((batch, w, cfg.n_kv_heads, hd), cdt),
-            "v": jax.ShapeDtypeStruct((batch, w, cfg.n_kv_heads, hd), cdt),
+            "k": jax.ShapeDtypeStruct((batch, w, kv, cfg.qk_head_dim), cdt),
+            "v": jax.ShapeDtypeStruct((batch, w, kv, cfg.v_dim), cdt),
         }
     if kind == "mamba":
         return {
@@ -320,9 +388,9 @@ def sublayer_prefill(p, x, cache, cfg: ModelConfig, ctx: ModelContext, idx,
             raise ValueError(
                 "seq_mask (front padding) requires a state-family stack; "
                 "attention positions would shift")
-        q, k, v = _project_qkv(p["core"], h, cfg, dtype)
         positions = jnp.broadcast_to(jnp.arange(s), (b, s))
-        q, k = apply_positional(q, k, cfg, positions, mrope_positions)
+        q, k, v = _project_qkv(p["core"], h, cfg, dtype, positions,
+                               mrope_positions)
         out = full_attention(q, k, v, cfg, q_chunk=ctx.q_chunk)
         core = jnp.einsum("bshk,hkd->bsd", out, p["core"]["wo"].astype(dtype))
         w = cache["k"].shape[1]
@@ -380,8 +448,8 @@ def sublayer_decode(p, x, cache, pos, cfg: ModelConfig, ctx: ModelContext,
     b = x.shape[0]
     h = rms_norm(x, p["ln1"], cfg.norm_eps)
     if kind == "attn":
-        q, k, v = _project_qkv(p["core"], h, cfg, dtype)
-        q, k = apply_positional(q, k, cfg, pos[:, None], mrope_positions)
+        q, k, v = _project_qkv(p["core"], h, cfg, dtype, pos[:, None],
+                               mrope_positions)
         w = cache["k"].shape[1]
         bidx = jnp.arange(b)
         slot = pos % w  # (B,) per-request ring slot
@@ -469,9 +537,9 @@ def sublayer_decode_span(p, x, cache, pos, live, cfg: ModelConfig,
     b, t, _ = x.shape
     h = rms_norm(x, p["ln1"], cfg.norm_eps)
     if kind == "attn":
-        q, k, v = _project_qkv(p["core"], h, cfg, dtype)
         posn = pos[:, None] + jnp.arange(t)[None, :]  # (B, T)
-        q, k = apply_positional(q, k, cfg, posn, mrope_positions)
+        q, k, v = _project_qkv(p["core"], h, cfg, dtype, posn,
+                               mrope_positions)
         w = cache["k"].shape[1]
         bidx = jnp.arange(b)[:, None]
         # dead positions write out of bounds and are dropped
@@ -632,8 +700,7 @@ def sublayer_decode_paged(p, x, pages, page_table, pos, cfg: ModelConfig,
     b = x.shape[0]
     page_size = pages["k"].shape[1]
     h = rms_norm(x, p["ln1"], cfg.norm_eps)
-    q, k, v = _project_qkv(p["core"], h, cfg, dtype)
-    q, k = apply_positional(q, k, cfg, pos[:, None], None)
+    q, k, v = _project_qkv(p["core"], h, cfg, dtype, pos[:, None])
     bidx = jnp.arange(b)
     pid = page_table[bidx, pos // page_size]  # (B,) owning page
     slot = pos % page_size
@@ -714,9 +781,9 @@ def sublayer_decode_span_paged(p, x, pages, page_table, pos, live,
     b, t, _ = x.shape
     page_size = pages["k"].shape[1]
     h = rms_norm(x, p["ln1"], cfg.norm_eps)
-    q, k, v = _project_qkv(p["core"], h, cfg, dtype)
     posn = pos[:, None] + jnp.arange(t)[None, :]  # (B, T)
-    q, k = apply_positional(q, k, cfg, posn, mrope_positions)
+    q, k, v = _project_qkv(p["core"], h, cfg, dtype, posn,
+                           mrope_positions)
     bidx = jnp.arange(b)[:, None]
     # page-table reads beyond the row clamp; dead slots write to trash 0
     pid = jnp.where(live, page_table[bidx, posn // page_size], 0)

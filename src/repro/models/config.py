@@ -10,6 +10,18 @@ Layer stacks are described as repeated *blocks* of ``block_len`` sublayers
 (scan runs over blocks). ``sublayer_kinds()`` expands the per-block pattern:
 most archs are 1 block-layer of kind "attn"; Jamba uses block_len=8 with
 attention at one position and Mamba elsewhere, MoE on every 2nd sublayer.
+DeepSeek-V3-style stacks put ``first_dense_layers`` dense layers ahead of
+the scanned blocks (``leading_dense``).
+
+Attention is grouped-query (GQA) unless ``kv_lora_rank`` is set: then it
+is multi-head latent attention (MLA, DeepSeek-V2/V3), whose keys and
+values come up from a ``kv_lora_rank``-wide latent, with a decoupled
+``qk_rope_dim``-wide rotary part shared by all heads; q/k heads are
+``qk_nope_dim + qk_rope_dim`` wide and v heads ``v_head_dim``.
+
+An expert layer may hold a share of its experts: it routes over all
+``n_experts`` and computes only ``experts_held`` of them, from
+``first_expert`` on (the chip's share under expert parallelism).
 """
 
 from __future__ import annotations
@@ -37,6 +49,12 @@ class ModelConfig:
     rope_theta: float = 1e4
     pos_emb: str = "rope"  # rope | mrope | learned | none
     mrope_sections: Tuple[int, ...] = ()  # head_dim/2 split for M-RoPE
+    # multi-head latent attention (on when kv_lora_rank > 0)
+    kv_lora_rank: int = 0
+    q_lora_rank: Optional[int] = None  # None: q projected from x directly
+    qk_nope_dim: int = 0
+    qk_rope_dim: int = 0
+    v_head_dim: int = 0
 
     # mlp
     mlp_act: str = "swiglu"  # swiglu | gelu
@@ -47,6 +65,17 @@ class ModelConfig:
     moe_every: int = 1  # MoE on sublayers where (idx % moe_every)==moe_offset
     moe_offset: int = 0
     capacity_factor: float = 1.25
+    d_expert: Optional[int] = None  # routed/shared expert width; None: d_ff
+    n_shared_experts: int = 0  # always-on experts, one MLP n_shared*d_expert
+    # "softmax": renormalised top-k of softmax probabilities (Switch aux
+    # losses); "sigmoid": DeepSeek-V3 noaux_tc, top-k of sigmoid scores
+    # plus a correction bias (selection only), gates renormalised over the
+    # chosen k, sequence-wise balance loss
+    router: str = "softmax"
+    routed_scaling: float = 1.0
+    experts_held: Optional[int] = None  # None: all n_experts
+    first_expert: int = 0
+    first_dense_layers: int = 0  # dense layers ahead of the scanned blocks
 
     # ssm (mamba) / rwkv
     ssm_state_dim: int = 16
@@ -72,14 +101,61 @@ class ModelConfig:
     # ------------------------------------------------------------------
 
     def __post_init__(self) -> None:
-        if self.n_layers % self.block_len:
-            raise ValueError("n_layers must be a multiple of block_len")
+        if (self.n_layers - self.first_dense_layers) % self.block_len:
+            raise ValueError("n_layers - first_dense_layers must be a "
+                             "multiple of block_len")
         if self.n_heads and self.n_kv_heads and self.n_heads % self.n_kv_heads:
             raise ValueError("n_heads must divide by n_kv_heads")
+        if self.mla and self.qk_rope_dim % 2:
+            raise ValueError("qk_rope_dim must be even")
+        if self.n_experts and not (
+                0 <= self.first_expert
+                and self.first_expert + self.n_held <= self.n_experts):
+            raise ValueError("held experts must lie within n_experts")
+        if self.router not in ("softmax", "sigmoid"):
+            raise ValueError(f"unknown router {self.router!r}")
 
     @property
     def n_blocks(self) -> int:
-        return self.n_layers // self.block_len
+        """Scanned blocks (the leading dense layers not among them)."""
+        return (self.n_layers - self.first_dense_layers) // self.block_len
+
+    @property
+    def mla(self) -> bool:
+        return self.kv_lora_rank > 0
+
+    @property
+    def qk_head_dim(self) -> int:
+        """Width of a q/k head (v heads: ``v_dim``)."""
+        if self.mla:
+            return self.qk_nope_dim + self.qk_rope_dim
+        return self.resolved_head_dim
+
+    @property
+    def v_dim(self) -> int:
+        return self.v_head_dim if self.mla else self.resolved_head_dim
+
+    @property
+    def kv_heads(self) -> int:
+        """Key/value heads attention sees (MLA: one per query head)."""
+        return self.n_heads if self.mla else self.n_kv_heads
+
+    @property
+    def expert_width(self) -> int:
+        return self.d_expert or self.d_ff
+
+    @property
+    def n_held(self) -> int:
+        return self.n_experts if self.experts_held is None \
+            else self.experts_held
+
+    def leading_dense(self) -> "ModelConfig":
+        """The configuration of the leading dense layers: the same
+        sublayers with a dense MLP of width ``d_ff``."""
+        return dataclasses.replace(
+            self, n_layers=self.first_dense_layers * self.block_len,
+            first_dense_layers=0, n_experts=0, experts_per_token=0,
+            n_shared_experts=0, experts_held=None, first_expert=0)
 
     @property
     def resolved_head_dim(self) -> int:
@@ -134,6 +210,14 @@ class ModelConfig:
     # ---- parameter counting (used for 6*N*D and config validation) -------
 
     def attn_params(self) -> int:
+        if self.mla:
+            d, h = self.d_model, self.n_heads
+            r, ql = self.kv_lora_rank, self.q_lora_rank
+            q = (d * ql + ql + ql * h * self.qk_head_dim if ql
+                 else d * h * self.qk_head_dim)
+            kv = (d * (r + self.qk_rope_dim) + r
+                  + r * h * (self.qk_nope_dim + self.v_head_dim))
+            return q + kv + h * self.v_head_dim * d
         hd = self.resolved_head_dim
         q = self.d_model * self.n_heads * hd
         kv = 2 * self.d_model * self.n_kv_heads * hd
@@ -147,7 +231,7 @@ class ModelConfig:
 
     def expert_mlp_params(self) -> int:
         mult = 3 if self.mlp_act == "swiglu" else 2
-        return mult * self.d_model * self.d_ff
+        return mult * self.d_model * self.expert_width
 
     def mamba_params(self) -> int:
         di, n, dr = self.d_inner, self.ssm_state_dim, self.dt_rank
@@ -179,8 +263,11 @@ class ModelConfig:
             # rwkv channel-mix replaces the MLP (2 mats)
             mlp = 2 * self.d_model * self.d_ff
         elif self.sublayer_has_moe(idx):
-            mlp = self.n_experts * self.expert_mlp_params() + (
-                self.d_model * self.n_experts)  # router
+            # held and shared experts, the router and its correction bias
+            bias = self.n_experts if self.router == "sigmoid" else 0
+            mlp = ((self.n_held + self.n_shared_experts)
+                   * self.expert_mlp_params()
+                   + self.d_model * self.n_experts + bias)
         else:
             mlp = self.dense_mlp_params()
         norms = 2 * self.d_model
@@ -190,6 +277,8 @@ class ModelConfig:
         per_block = sum(self.params_per_sublayer(i)
                         for i in range(self.block_len))
         total = per_block * self.n_blocks
+        if self.first_dense_layers:
+            total += self.leading_dense().total_params() - self._io_params()
         total += self.vocab_size * self.d_model  # embedding
         if not self.tie_embeddings:
             total += self.vocab_size * self.d_model  # lm head
@@ -206,20 +295,24 @@ class ModelConfig:
             # (models/encdec.MAX_DEC_POSITIONS, sized for decode_32k)
         return int(total)
 
+    def _io_params(self) -> int:
+        """Embedding, head and final norm."""
+        return (self.vocab_size * self.d_model
+                * (1 if self.tie_embeddings else 2) + self.d_model)
+
     def active_params(self) -> int:
-        """Parameters touched per token (MoE: only routed experts)."""
+        """Parameters touched per token (MoE: the routed experts a token
+        is expected to reach among those held)."""
         if not self.n_experts:
-            total = self.total_params()
-        else:
-            per_block = 0
-            for i in range(self.block_len):
-                p = self.params_per_sublayer(i)
-                if self.sublayer_has_moe(i):
-                    p -= (self.n_experts - self.experts_per_token) * \
-                        self.expert_mlp_params()
-                per_block += p
-            total = per_block * self.n_blocks
-            total += self.vocab_size * self.d_model * (
-                1 if self.tie_embeddings else 2)
-            total += self.d_model
+            return self.total_params()
+        routed = self.experts_per_token * self.n_held / self.n_experts
+        per_block = 0
+        for i in range(self.block_len):
+            p = self.params_per_sublayer(i)
+            if self.sublayer_has_moe(i):
+                p -= (self.n_held - routed) * self.expert_mlp_params()
+            per_block += p
+        total = per_block * self.n_blocks + self._io_params()
+        if self.first_dense_layers:
+            total += self.leading_dense().total_params() - self._io_params()
         return int(total)

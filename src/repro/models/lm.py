@@ -2,12 +2,15 @@
 
 Layers run under ``lax.scan`` over stacked block parameters with
 ``jax.checkpoint`` (remat) around the body — the paper-era recipe for
-training big models on HBM-limited accelerators.
+training big models on HBM-limited accelerators. Leading dense layers
+(``cfg.first_dense_layers``, DeepSeek-V3's first_k_dense_replace) are a
+stack of their own, ``dense_blocks``, run before the scanned ``blocks``
+under their own ``jax.checkpoint``.
 """
 
 from __future__ import annotations
 
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -24,15 +27,29 @@ from repro.models.params import ParamSpec, ones_init
 Array = jax.Array
 
 AUX_WEIGHTS = {"load_balance": 0.01, "router_z": 0.001}
+# DeepSeek-V3's sequence-wise balance loss, weighted by its alpha and
+# summed over the expert layers (arXiv:2412.19437 §2.1.2)
+SEQ_BALANCE_ALPHA = 1e-4
+# rows of the expert layers, summed over layers: metrics, not losses
+MOE_COUNTERS = ("moe_held_rows", "moe_padded_rows")
+
+
+def layer_stacks(cfg: ModelConfig) -> List[Tuple[str, ModelConfig]]:
+    """(parameter key, configuration) of each layer stack, in order: the
+    leading dense layers, then the scanned blocks."""
+    out = [("dense_blocks", cfg.leading_dense())] \
+        if cfg.first_dense_layers else []
+    return out + [("blocks", cfg)]
 
 
 def lm_specs(cfg: ModelConfig) -> Dict[str, Any]:
     d, v = cfg.d_model, cfg.vocab_size
     specs: Dict[str, Any] = {
         "embed": ParamSpec((v, d), ("vocab", "embed")),
-        "blocks": stack_specs(block_specs(cfg), cfg.n_blocks),
         "final_norm": ParamSpec((d,), ("embed",), init=ones_init()),
     }
+    for name, c in layer_stacks(cfg):
+        specs[name] = stack_specs(block_specs(c), c.n_blocks)
     if not cfg.tie_embeddings:
         specs["lm_head"] = ParamSpec((d, v), ("embed", "vocab"))
     return specs
@@ -56,13 +73,18 @@ def lm_loss(params: Dict[str, Any], batch: Dict[str, Array],
     x = embed_lookup(params["embed"], tokens, ctx.compute_dtype)
     x = ctx.shard(x, ("batch", "act_seq", "embed"))
 
-    def body(x, bp):
-        x, aux = block_forward(bp, x, cfg, ctx, mrope)
-        out_aux = {k: jnp.asarray(aux.get(k, 0.0), jnp.float32)
-                   for k in AUX_WEIGHTS}
-        return x, out_aux
+    keys = (*AUX_WEIGHTS, "seq_balance", *MOE_COUNTERS)
 
-    x, auxs = jax.lax.scan(jax.checkpoint(body), x, params["blocks"])
+    def stack_body(c: ModelConfig):
+        def body(x, bp):
+            x, aux = block_forward(bp, x, c, ctx, mrope)
+            return x, {k: jnp.asarray(aux.get(k, 0.0), jnp.float32)
+                       for k in keys}
+        return body
+
+    for name, c in layer_stacks(cfg):
+        x, auxs = jax.lax.scan(jax.checkpoint(stack_body(c)), x,
+                               params[name])
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)
     logits = _logits(params, x, cfg, ctx)
     mask = batch.get("loss_mask")
@@ -70,10 +92,15 @@ def lm_loss(params: Dict[str, Any], batch: Dict[str, Array],
     metrics = {"xent": loss, "tokens": count}
     total = loss
     for key, w in AUX_WEIGHTS.items():
-        if key in auxs:
-            val = auxs[key].mean()
-            metrics[key] = val
-            total = total + w * val
+        val = auxs[key].mean()
+        metrics[key] = val
+        total = total + w * val
+    if cfg.n_experts:
+        if cfg.router == "sigmoid":
+            metrics["seq_balance"] = auxs["seq_balance"].sum()
+            total = total + SEQ_BALANCE_ALPHA * metrics["seq_balance"]
+        for key in MOE_COUNTERS:
+            metrics[key] = auxs[key].sum()
     metrics["loss"] = total
     return total, metrics
 
@@ -85,12 +112,13 @@ def lm_loss(params: Dict[str, Any], batch: Dict[str, Array],
 
 def lm_cache_spec(cfg: ModelConfig, batch: int, window: int,
                   ctx: ModelContext) -> Dict[str, Any]:
-    blocks = block_cache_spec(cfg, batch, window, ctx)
-    stacked = jax.tree.map(
-        lambda s: jax.ShapeDtypeStruct((cfg.n_blocks, *s.shape), s.dtype),
-        blocks)
-    return {"blocks": stacked,
-            "pos": jax.ShapeDtypeStruct((batch,), jnp.int32)}
+    out: Dict[str, Any] = {
+        "pos": jax.ShapeDtypeStruct((batch,), jnp.int32)}
+    for name, c in layer_stacks(cfg):
+        out[name] = jax.tree.map(
+            lambda s: jax.ShapeDtypeStruct((c.n_blocks, *s.shape), s.dtype),
+            block_cache_spec(c, batch, window, ctx))
+    return out
 
 
 def lm_prefill(params: Dict[str, Any], tokens: Array, cfg: ModelConfig,
@@ -122,16 +150,18 @@ def lm_prefill(params: Dict[str, Any], tokens: Array, cfg: ModelConfig,
         live = jnp.arange(s)[None, :] >= pad_left[:, None]  # (B, S)
         x = x * live[..., None].astype(x.dtype)
     x = ctx.shard(x, ("batch", "act_seq", "embed"))
-    cache0 = jax.tree.map(
-        lambda sd: jnp.zeros(sd.shape, sd.dtype),
-        block_cache_spec(cfg, b, window, ctx))
+    caches: Dict[str, Any] = {}
+    for name, c in layer_stacks(cfg):
+        cache0 = jax.tree.map(
+            lambda sd: jnp.zeros(sd.shape, sd.dtype),
+            block_cache_spec(c, b, window, ctx))
 
-    def body(x, bp):
-        x, new_cache = block_prefill(bp, x, cache0, cfg, ctx,
-                                     mrope_positions, seq_mask=live)
-        return x, new_cache
+        def body(x, bp, c=c, cache0=cache0):
+            return block_prefill(bp, x, cache0, c, ctx, mrope_positions,
+                                 seq_mask=live)
 
-    x, caches = jax.lax.scan(jax.checkpoint(body), x, params["blocks"])
+        x, caches[name] = jax.lax.scan(jax.checkpoint(body), x,
+                                       params[name])
     if logits_at is None:
         xl = x[:, -1:]
         pos = jnp.full((b,), s, jnp.int32)
@@ -141,7 +171,7 @@ def lm_prefill(params: Dict[str, Any], tokens: Array, cfg: ModelConfig,
         pos = logits_at.astype(jnp.int32) + 1
     xl = rms_norm(xl, params["final_norm"], cfg.norm_eps)
     logits = _logits(params, xl, cfg, ctx)
-    return logits, {"blocks": caches, "pos": pos}
+    return logits, {**caches, "pos": pos}
 
 
 def lm_decode_step(params: Dict[str, Any], token: Array,
@@ -152,16 +182,16 @@ def lm_decode_step(params: Dict[str, Any], token: Array,
     x = embed_lookup(params["embed"], token, ctx.compute_dtype)
     x = ctx.shard(x, ("batch", None, "embed"))
 
-    def body(x, xs):
-        bp, bc = xs
-        x, nc = block_decode(bp, x, bc, pos, cfg, ctx)
-        return x, nc
+    new: Dict[str, Any] = {}
+    for name, c in layer_stacks(cfg):
+        def body(x, xs, c=c):
+            bp, bc = xs
+            return block_decode(bp, x, bc, pos, c, ctx)
 
-    x, new_blocks = jax.lax.scan(body, x, (params["blocks"],
-                                           cache["blocks"]))
+        x, new[name] = jax.lax.scan(body, x, (params[name], cache[name]))
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)
     logits = _logits(params, x, cfg, ctx)
-    return logits, {"blocks": new_blocks, "pos": pos + 1}
+    return logits, {**new, "pos": pos + 1}
 
 
 def _span_logits_slice(x: Array, logits_at: Optional[Array]) -> Array:
@@ -204,18 +234,18 @@ def lm_decode_span(params: Dict[str, Any], tokens: Array,
     x = x * live[..., None].astype(x.dtype)
     x = ctx.shard(x, ("batch", None, "embed"))
 
-    def body(x, xs):
-        bp, bc = xs
-        x, nc = block_decode_span(bp, x, bc, pos, live, cfg, ctx,
-                                  mrope_positions)
-        return x, nc
+    new: Dict[str, Any] = {}
+    for name, c in layer_stacks(cfg):
+        def body(x, xs, c=c):
+            bp, bc = xs
+            return block_decode_span(bp, x, bc, pos, live, c, ctx,
+                                     mrope_positions)
 
-    x, new_blocks = jax.lax.scan(body, x, (params["blocks"],
-                                           cache["blocks"]))
+        x, new[name] = jax.lax.scan(body, x, (params[name], cache[name]))
     x = _span_logits_slice(x, logits_at)
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)
     logits = _logits(params, x, cfg, ctx)
-    return logits, {"blocks": new_blocks, "pos": pos}
+    return logits, {**new, "pos": pos}
 
 
 # -- paged serving state ----------------------------------------------------

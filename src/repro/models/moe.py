@@ -1,18 +1,18 @@
-"""Mixture-of-Experts FFN: capacity dispatch (training) + sort-based
-dropless dispatch (serving).
+"""Mixture-of-Experts FFN: sort-based dropless dispatch (training and
+serving) and capacity dispatch (the tests' oracle).
 
 The dispatch pattern is the paper's SparseCore story at the framework level:
 fine-grained sort/scatter of per-token vectors (vs the dense AllReduce of
 parameter tensors). Two dispatch modes share one router:
 
-* ``dispatch="capacity"`` — GShard-style training dispatch: top-k routing
+* ``dispatch="capacity"`` — GShard-style dispatch: top-k routing
   -> position-in-expert via one-hot cumsum (top-1 assignments take priority
   over top-2, etc.) -> scatter into an (E, capacity, D) buffer (overflow
   tokens drop, ``mode="drop"``) -> batched expert matmuls -> gather back
   and combine with renormalized gate weights. ``dropless=True`` sizes the
   buffer so nothing can drop — correct, but it burns an (E, T, D) buffer.
 
-* ``dispatch="grouped"`` — sort-based dropless serving dispatch: stable-
+* ``dispatch="grouped"`` — sort-based dropless dispatch: stable-
   argsort the (T*k) assignments by expert, pad each expert's group to a
   ``block_m`` boundary, run the m-grouped contiguous GEMM Pallas kernel
   (kernels/moe_gemm.py) over the sorted rows with a scalar-prefetched
@@ -23,8 +23,26 @@ parameter tensors). Two dispatch modes share one router:
   kernel via per-expert scales; experts shard over the "data" mesh axis
   through the shard_map wrapper in kernels/ops.py (expert parallelism).
 
-Aux losses (returned, weighted by the trainer): Switch-style load-balance
-loss and router z-loss — identical across dispatch modes.
+A layer may hold a share of the experts (``cfg.experts_held`` from
+``cfg.first_expert``, the chip's share under expert parallelism): it
+routes over all ``n_experts``, its held experts' rows go through the
+grouped GEMM, and assignments to the others sort past the held groups
+and are never computed. Shared experts (one MLP of ``n_shared_experts``
+times the expert width) are added once; the routed part is scaled by
+``routed_scaling``. What the absent experts would add is left out.
+
+Two routers: "softmax" (renormalised top-k of softmax probabilities) and
+"sigmoid" (DeepSeek-V3's ``noaux_tc``: gating in float32, top-k of the
+sigmoid scores plus a correction bias that picks experts but takes no
+gradient, gates renormalised over the chosen k).
+
+Aux losses (returned, weighted by the trainer), identical across
+dispatch modes: the softmax router's Switch-style load-balance loss and
+router z-loss; the sigmoid router's sequence-wise balance loss
+(DeepSeek-V3, arXiv:2412.19437 §2.1.2) over all ``n_experts``. The
+grouped path also returns, as ``moe_held_rows`` and ``moe_padded_rows``,
+the assignments routed to held experts and the rows the grouped kernels
+compute for them (block padding included).
 """
 
 from __future__ import annotations
@@ -39,21 +57,29 @@ import jax.numpy as jnp
 from repro.kernels import ops as kops
 from repro.models.config import ModelConfig
 from repro.models.ops import swiglu, gelu
-from repro.models.params import ParamSpec, normal_init
+from repro.models.params import ParamSpec, zeros_init
 
 Array = jax.Array
 
 
 def moe_param_specs(cfg: ModelConfig) -> Dict[str, ParamSpec]:
-    d, f, e = cfg.d_model, cfg.d_ff, cfg.n_experts
+    """The router over all experts, the held experts' weights, the
+    sigmoid router's correction bias and the shared experts."""
+    d, f, e = cfg.d_model, cfg.expert_width, cfg.n_held
     specs = {
-        "router": ParamSpec((d, e), ("embed", None)),
+        "router": ParamSpec((d, cfg.n_experts), ("embed", None)),
         "w_up": ParamSpec((e, d, f), ("expert", "embed", "expert_mlp")),
         "w_down": ParamSpec((e, f, d), ("expert", "expert_mlp", "embed")),
     }
     if cfg.mlp_act == "swiglu":
         specs["w_gate"] = ParamSpec((e, d, f),
                                     ("expert", "embed", "expert_mlp"))
+    if cfg.router == "sigmoid":
+        specs["router_bias"] = ParamSpec((cfg.n_experts,), (None,),
+                                         init=zeros_init())
+    if cfg.n_shared_experts:
+        specs["shared"] = _mlp_specs(d, cfg.n_shared_experts * f,
+                                     cfg.mlp_act)
     return specs
 
 
@@ -70,10 +96,18 @@ def capacity(tokens: int, cfg: ModelConfig) -> int:
 # and the grouped buffer never exceeds ~chunk_tokens * k rows.
 MOE_CHUNK_TOKENS = 65536
 
+# Tokens per grouped dispatch on one device: the router sees the whole
+# sequence (its balance loss is the sequence's), then the sorted buffer,
+# sized for the worst case of each chunk, is built and run one chunk of
+# tokens at a time, each chunk rematerialised in the backward, so the
+# buffers of one chunk are live at a time.
+GROUPED_DISPATCH_TOKENS = 2048
+
 # Default m-tile for the grouped GEMM. CI exercises interpret mode at
 # smoke scale, where a small tile keeps padding (≤ E*(block_m-1) wasted
-# rows) negligible; on TPU hardware raise this to the MXU-aligned 128.
+# rows) negligible; the compiled kernel takes the MXU-aligned 128.
 GROUPED_BLOCK_M = 8
+PALLAS_BLOCK_M = 128
 
 _EXPERT_WEIGHTS = ("w_up", "w_gate", "w_down")
 
@@ -115,24 +149,58 @@ def _weight(params: Dict[str, Array], name: str, compute_dtype) -> Array:
             * scale[:, None, None]).astype(compute_dtype)
 
 
-def _route(params: Dict[str, Array], xt: Array, compute_dtype, k: int):
-    """Shared router: fp32 logits/probs, renormalized top-k gates."""
-    logits = (xt @ params["router"].astype(compute_dtype)).astype(
-        jnp.float32)
-    probs = jax.nn.softmax(logits, axis=-1)  # (T, E)
-    gate_w, gate_idx = jax.lax.top_k(probs, k)  # (T, k)
-    gate_w = gate_w / jnp.maximum(gate_w.sum(-1, keepdims=True), 1e-9)
-    return logits, probs, gate_w, gate_idx
+def _route(params: Dict[str, Array], xt: Array, cfg: ModelConfig,
+           compute_dtype):
+    """Shared router over all ``n_experts``: fp32 logits and scores, the
+    chosen k experts (T, k) and their gates, renormalised over the k
+    and scaled by ``routed_scaling``."""
+    k = cfg.experts_per_token
+    if cfg.router == "sigmoid":
+        # DeepSeek-V3 computes its gating in float32
+        logits = jnp.dot(xt.astype(jnp.float32),
+                         params["router"].astype(jnp.float32),
+                         precision=jax.lax.Precision.HIGHEST)
+        probs = jax.nn.sigmoid(logits)  # (T, E)
+        bias = jax.lax.stop_gradient(params["router_bias"])
+        _, gate_idx = jax.lax.top_k(probs + bias.astype(jnp.float32), k)
+        gate_w = jnp.take_along_axis(probs, gate_idx, axis=-1)
+        gate_w = gate_w / (gate_w.sum(-1, keepdims=True) + 1e-20)
+    else:
+        logits = (xt @ params["router"].astype(compute_dtype)).astype(
+            jnp.float32)
+        probs = jax.nn.softmax(logits, axis=-1)  # (T, E)
+        gate_w, gate_idx = jax.lax.top_k(probs, k)  # (T, k)
+        gate_w = gate_w / jnp.maximum(gate_w.sum(-1, keepdims=True), 1e-9)
+    return logits, probs, gate_w * cfg.routed_scaling, gate_idx
 
 
 def _aux_losses(logits: Array, probs: Array, gate_idx: Array,
-                t: int, k: int, e: int) -> Dict[str, Array]:
+                cfg: ModelConfig, b: int, s: int) -> Dict[str, Array]:
+    k, e = cfg.experts_per_token, cfg.n_experts
+    if cfg.router == "sigmoid":
+        # sequence-wise balance: per row, E/(k S) * assignments per
+        # expert times the expert's mean normalised score
+        p = probs.reshape(b, s, e)
+        p = (p / p.sum(-1, keepdims=True)).mean(axis=1)  # (B, E)
+        rows = jnp.arange(b)[:, None]
+        f = jnp.zeros((b, e), jnp.float32).at[
+            rows, gate_idx.reshape(b, s * k)].add(e / (k * s))
+        return {"seq_balance": jnp.mean(jnp.sum(f * p, axis=-1))}
+    t = b * s
     me = probs.mean(axis=0)  # mean router prob per expert
     ce = jnp.zeros((e,), jnp.float32).at[gate_idx.reshape(-1)].add(
         1.0 / (t * k))  # fraction of assignments per expert
     load_balance = e * jnp.sum(me * ce)
     z_loss = jnp.mean(jax.scipy.special.logsumexp(logits, axis=-1) ** 2)
     return {"load_balance": load_balance, "router_z": z_loss}
+
+
+def _held(gate_idx: Array, cfg: ModelConfig) -> Tuple[Array, Array]:
+    """(held expert's local id, or 0; whether the expert is held) of
+    each assignment."""
+    local = gate_idx - cfg.first_expert
+    mine = (local >= 0) & (local < cfg.n_held)
+    return jnp.where(mine, local, 0), mine
 
 
 # ---------------------------------------------------------------------------
@@ -145,12 +213,14 @@ class GroupedDispatch(NamedTuple):
 
     ``row_src`` (M_pad,): source token row per sorted slot (-1 = pad row).
     ``dest`` (T*k,): sorted slot of each token-major assignment, i.e. the
-    inverse permutation the combine gathers through.
+    inverse permutation the combine gathers through (-1: an expert not
+    held here).
     ``block_experts`` (M_pad // block_m,): expert id per m-tile (-1 =
     pad-only tile) — the scalar-prefetched kernel metadata.
-    ``counts`` (E,): assignments per expert; ``offsets`` (E+1,): their
-    cumsum (monotone, offsets[-1] == T*k); ``padded_starts`` (E,): each
-    expert's block-aligned group start in the sorted buffer.
+    ``counts`` (E,): assignments per held expert; ``offsets`` (E+1,):
+    their cumsum (monotone; offsets[-1] == T*k when every expert is
+    held); ``padded_starts`` (E,): each expert's block-aligned group
+    start in the sorted buffer.
     """
     row_src: Array
     dest: Array
@@ -165,23 +235,30 @@ class GroupedDispatch(NamedTuple):
 
 
 def grouped_dispatch_plan(gate_idx: Array, *, n_experts: int,
-                          block_m: int = GROUPED_BLOCK_M
+                          block_m: int = GROUPED_BLOCK_M,
+                          first: int = 0, held: Optional[int] = None
                           ) -> GroupedDispatch:
-    """Build the sorted, block-aligned dispatch plan from (T, k) routing.
+    """Build the sorted, block-aligned dispatch plan from (T, k) routing
+    over ``n_experts``, of which ``held`` (default all) from ``first``
+    are held here; the rest sort past the held groups and get no row.
 
-    All shapes are static: the sorted buffer is sized at the worst-case
-    round_up(T*k + E*(block_m-1), block_m) — every expert's group padded
-    to a block_m boundary — so each m-tile maps to exactly one expert."""
+    All shapes are static: the sorted buffer is sized at the worst case
+    round_up(T*min(k, held) + held*(block_m-1), block_m) — every held
+    expert's group padded to a block_m boundary — so each m-tile maps to
+    exactly one expert."""
     t, k = gate_idx.shape
     tk = t * k
-    e, bm = n_experts, block_m
-    m_pad = -(-(tk + e * (bm - 1)) // bm) * bm
+    e = n_experts if held is None else held
+    bm = block_m
+    m_pad = -(-(t * min(k, e) + e * (bm - 1)) // bm) * bm
     nb = m_pad // bm
 
-    flat_e = gate_idx.reshape(-1).astype(jnp.int32)  # token-major (T*k,)
+    local = gate_idx.reshape(-1).astype(jnp.int32) - first  # token-major
+    mine = (local >= 0) & (local < e)
+    flat_e = jnp.where(mine, local, e)  # the others sort last
     order = jnp.argsort(flat_e)  # stable: ties keep token order
     sorted_e = flat_e[order]
-    counts = jnp.zeros((e,), jnp.int32).at[flat_e].add(1)
+    counts = jnp.zeros((e + 1,), jnp.int32).at[flat_e].add(1)[:e]
     offsets = jnp.concatenate(
         [jnp.zeros((1,), jnp.int32), jnp.cumsum(counts)])
     padded = -(-counts // bm) * bm
@@ -189,12 +266,15 @@ def grouped_dispatch_plan(gate_idx: Array, *, n_experts: int,
     padded_starts = pad_ends - padded
 
     # Rank of each sorted assignment within its expert group, then its
-    # destination slot in the block-aligned buffer.
-    rank = jnp.arange(tk, dtype=jnp.int32) - offsets[sorted_e]
-    dest_sorted = padded_starts[sorted_e] + rank
+    # destination slot in the block-aligned buffer (m_pad: none).
+    held_e = jnp.minimum(sorted_e, e - 1)
+    rank = jnp.arange(tk, dtype=jnp.int32) - offsets[held_e]
+    dest_sorted = jnp.where(sorted_e < e, padded_starts[held_e] + rank,
+                            m_pad)
     dest = jnp.zeros((tk,), jnp.int32).at[order].set(dest_sorted)
+    dest = jnp.where(dest < m_pad, dest, -1)
     row_src = jnp.full((m_pad,), -1, jnp.int32).at[dest_sorted].set(
-        order // k)
+        order // k, mode="drop")
 
     tile_starts = jnp.arange(nb, dtype=jnp.int32) * bm
     block_experts = jnp.searchsorted(pad_ends, tile_starts,
@@ -217,23 +297,27 @@ def grouped_permute(xt: Array, plan: GroupedDispatch, dtype) -> Array:
 def grouped_combine(y: Array, plan: GroupedDispatch, gate_w: Array,
                     t: int, k: int) -> Array:
     """Unpermute (M_pad, D) expert outputs back to token order and
-    combine the k assignments with renormalized gate weights -> (T, D)."""
-    gathered = y[plan.dest]  # (T*k, D) token-major
+    combine the k assignments with renormalized gate weights -> (T, D);
+    an assignment to an expert not held adds nothing."""
+    held = plan.dest[:, None] >= 0
+    gathered = y[jnp.maximum(plan.dest, 0)]  # (T*k, D) token-major
     weights = gate_w.reshape(-1).astype(y.dtype)  # (T*k,)
     d = y.shape[-1]
-    return (gathered * weights[:, None]).reshape(t, k, d).sum(axis=1)
+    part = jnp.where(held, gathered * weights[:, None], 0)
+    return part.reshape(t, k, d).sum(axis=1)
 
 
 def _moe_ffn_grouped(params: Dict[str, Array], x: Array, cfg: ModelConfig,
                      compute_dtype, shard, impl: str, block_m: int, mesh,
-                     expert_axis: str) -> Tuple[Array, Dict[str, Array]]:
+                     expert_axis: str, with_counts: bool
+                     ) -> Tuple[Array, Dict[str, Array]]:
     b, s, d = x.shape
     t = b * s
-    k, e = cfg.experts_per_token, cfg.n_experts
+    k, e = cfg.experts_per_token, cfg.n_held
     xt = x.reshape(t, d)
 
-    logits, probs, gate_w, gate_idx = _route(params, xt, compute_dtype, k)
-    aux = _aux_losses(logits, probs, gate_idx, t, k, e)
+    logits, probs, gate_w, gate_idx = _route(params, xt, cfg, compute_dtype)
+    aux = _aux_losses(logits, probs, gate_idx, cfg, b, s)
 
     names = [n for n in _EXPERT_WEIGHTS if n in params]
     has_scale = any(n + "_scale" in params for n in names)
@@ -265,8 +349,9 @@ def _moe_ffn_grouped(params: Dict[str, Array], x: Array, cfg: ModelConfig,
     def run(xt_, gate_w_, gate_idx_, *wx):
         ws_ = wx[:len(names)]
         scs_ = wx[len(names):] if has_scale else (None,) * len(names)
-        plan = grouped_dispatch_plan(gate_idx_, n_experts=e,
-                                     block_m=block_m)
+        plan = grouped_dispatch_plan(gate_idx_, n_experts=cfg.n_experts,
+                                     block_m=block_m,
+                                     first=cfg.first_expert, held=e)
         xs = grouped_permute(xt_, plan, compute_dtype)
         gids = plan.block_experts
         if ep > 1:
@@ -286,18 +371,36 @@ def _moe_ffn_grouped(params: Dict[str, Array], x: Array, cfg: ModelConfig,
         down = gm(h.astype(compute_dtype), "w_down")
         if ep > 1:
             down = jax.lax.psum(down, expert_axis)
-        return grouped_combine(down, plan, gate_w_, t, k)
+        return (grouped_combine(down, plan, gate_w_, xt_.shape[0], k),
+                plan.counts)
 
     args = [xt, gate_w, gate_idx] + ws + (scales if has_scale else [])
-    if mesh is None:
-        out = run(*args)
+    dispatch_tokens = GROUPED_DISPATCH_TOKENS
+    if mesh is None and t > dispatch_tokens and t % dispatch_tokens == 0:
+        n = t // dispatch_tokens
+
+        def chunk(_, rows):
+            return None, run(*rows, *args[3:])
+
+        _, (outs, counts) = jax.lax.scan(
+            jax.checkpoint(chunk), None,
+            tuple(a.reshape(n, dispatch_tokens, *a.shape[1:])
+                  for a in args[:3]))
+        out = outs.reshape(t, d)  # counts: (chunks, E), padded per chunk
+    elif mesh is None:
+        out, counts = run(*args)
     else:
         P = jax.sharding.PartitionSpec
         wspec = P(expert_axis) if ep > 1 else P()
         in_specs = [P(), P(), P()] + [wspec] * len(names) * (
             2 if has_scale else 1)
-        out = jax.shard_map(run, mesh=mesh, in_specs=tuple(in_specs),
-                            out_specs=P(), check_vma=False)(*args)
+        out, counts = jax.shard_map(run, mesh=mesh,
+                                    in_specs=tuple(in_specs),
+                                    out_specs=(P(), P()),
+                                    check_vma=False)(*args)
+    if with_counts:
+        aux["moe_held_rows"] = counts.sum()
+        aux["moe_padded_rows"] = (-(-counts // block_m) * block_m).sum()
     return out.reshape(b, s, d), aux
 
 
@@ -315,9 +418,10 @@ def moe_ffn(params: Dict[str, Array], x: Array, cfg: ModelConfig,
             impl: str = "ref",
             block_m: int = GROUPED_BLOCK_M,
             mesh=None,
-            expert_axis: str = "data"
+            expert_axis: str = "data",
+            with_counts: bool = False
             ) -> Tuple[Array, Dict[str, Array]]:
-    """x: (B, S, D) -> (out, aux_losses).
+    """x: (B, S, D) -> (out, aux).
 
     Token count above ``chunk_tokens`` is processed in sequence-chunks
     (scan), bounding the dispatch working set — (E, C, D) for capacity,
@@ -325,25 +429,27 @@ def moe_ffn(params: Dict[str, Array], x: Array, cfg: ModelConfig,
     which is the standard serving/prefill trade-off; grouped results are
     chunk-invariant (each row's GEMM is independent of group packing).
 
-    ``dispatch="capacity"`` is the GShard training path. ``dropless=True``
-    sizes its buffer so no assignment can overflow (capacity = chunk
-    token count): each token's output becomes independent of the rest of
-    the batch. Serving paths require this — with capacity drops, prefill
-    results depend on how many other tokens share the dispatch, so an
-    incremental decode can never bit-match a longer prefill. Training
-    keeps the capacity-dropping dispatch (the load-balance pressure the
-    aux losses assume).
+    ``dispatch="capacity"`` is GShard's dispatch, kept as the oracle of
+    the grouped path. ``dropless=True`` sizes its buffer so no
+    assignment can overflow (capacity = chunk token count): each token's
+    output becomes independent of the rest of the batch. Without it,
+    overflowing assignments drop.
 
-    ``dispatch="grouped"`` is the sort-based dropless serving path (see
-    module docstring): dropless by construction, routed through the
-    m-grouped GEMM kernel. ``impl`` selects the kernel body ("pallas" /
-    "interpret" / "ref"); ``mesh`` + ``expert_axis`` enable the
-    expert-parallel shard_map wrapper."""
+    ``dispatch="grouped"`` is the sort-based dropless path of training
+    and serving (see module docstring): dropless by construction, routed
+    through the m-grouped GEMM kernel. ``impl`` selects the kernel body
+    ("pallas" / "interpret" / "ref"); ``mesh`` + ``expert_axis`` enable
+    the expert-parallel shard_map wrapper; ``with_counts`` adds the
+    ``moe_held_rows`` and ``moe_padded_rows`` counts to ``aux``; off a
+    mesh, the dispatch runs ``GROUPED_DISPATCH_TOKENS`` tokens at a time.
+
+    The output holds the held experts' part of the routed result, scaled
+    by ``routed_scaling``, plus the shared experts."""
     b, s, d = x.shape
     if dispatch == "grouped":
         flat = partial(_moe_ffn_grouped, compute_dtype=compute_dtype,
                        shard=shard, impl=impl, block_m=block_m, mesh=mesh,
-                       expert_axis=expert_axis)
+                       expert_axis=expert_axis, with_counts=with_counts)
     elif dispatch == "capacity":
         flat = partial(_moe_ffn_flat, compute_dtype=compute_dtype,
                        shard=shard, dropless=dropless)
@@ -361,9 +467,14 @@ def moe_ffn(params: Dict[str, Array], x: Array, cfg: ModelConfig,
 
         _, (outs, auxs) = jax.lax.scan(body, None, xc)
         out = outs.transpose(1, 0, 2, 3).reshape(b, s, d)
-        aux = jax.tree.map(lambda a: a.mean(0), auxs)
-        return out, aux
-    return flat(params, x, cfg)
+        # losses average over the chunks, row counts add up
+        aux = {k: a.sum(0) if k.startswith("moe_") else a.mean(0)
+               for k, a in auxs.items()}
+    else:
+        out, aux = flat(params, x, cfg)
+    if cfg.n_shared_experts:
+        out = out + dense_ffn(params["shared"], x, cfg, compute_dtype)
+    return out, aux
 
 
 def _moe_ffn_flat(params: Dict[str, Array], x: Array, cfg: ModelConfig,
@@ -371,26 +482,30 @@ def _moe_ffn_flat(params: Dict[str, Array], x: Array, cfg: ModelConfig,
                   ) -> Tuple[Array, Dict[str, Array]]:
     b, s, d = x.shape
     t = b * s
-    k, e = cfg.experts_per_token, cfg.n_experts
+    k, e = cfg.experts_per_token, cfg.n_held
     xt = x.reshape(t, d)
 
-    logits, probs, gate_w, gate_idx = _route(params, xt, compute_dtype, k)
+    logits, probs, gate_w, gate_idx = _route(params, xt, cfg, compute_dtype)
+    local, mine = _held(gate_idx, cfg)
 
     # An expert receives at most one assignment per token (top-k indices are
     # distinct), so capacity = t can never drop.
     cap = t if dropless else capacity(t, cfg)
     # Priority order: all top-1 assignments, then top-2, ... (GShard).
-    flat_idx = gate_idx.T.reshape(-1)  # (k*T,)
-    onehot = jax.nn.one_hot(flat_idx, e, dtype=jnp.int32)  # (kT, E)
+    flat_idx = local.T.reshape(-1)  # (k*T,)
+    flat_mine = mine.T.reshape(-1)
+    onehot = jax.nn.one_hot(flat_idx, e, dtype=jnp.int32) \
+        * flat_mine[:, None]  # (kT, E)
     pos = jnp.cumsum(onehot, axis=0) - 1
     pos_in_e = jnp.take_along_axis(pos, flat_idx[:, None], axis=1)[:, 0]
-    keep = pos_in_e < cap
+    keep = flat_mine & (pos_in_e < cap)
 
     xk = jnp.broadcast_to(xt[None], (k, t, d)).reshape(k * t, d)
     xk = jnp.where(keep[:, None], xk, jnp.zeros((), compute_dtype))
     xk = shard(xk, ("batch", "embed"))
+    slot = jnp.where(keep, pos_in_e, cap)  # cap: dropped
     dispatched = jnp.zeros((e, cap, d), compute_dtype).at[
-        flat_idx, pos_in_e].add(xk, mode="drop")
+        flat_idx, slot].add(xk, mode="drop")
     dispatched = shard(dispatched, ("expert", "exp_cap", None))
 
     # Expert matmuls: E sharded over data (EP), hidden over model (TP).
@@ -407,7 +522,7 @@ def _moe_ffn_flat(params: Dict[str, Array], x: Array, cfg: ModelConfig,
                       _weight(params, "w_down", compute_dtype))
     down = shard(down, ("expert", "exp_cap", None))
 
-    gathered = down.at[flat_idx, pos_in_e].get(
+    gathered = down.at[flat_idx, slot].get(
         mode="fill", fill_value=0)  # (kT, D)
     gathered = shard(gathered, ("batch", "embed"))
     gathered = jnp.where(keep[:, None], gathered,
@@ -415,7 +530,7 @@ def _moe_ffn_flat(params: Dict[str, Array], x: Array, cfg: ModelConfig,
     weights = (gate_w.T.reshape(-1) * keep).astype(compute_dtype)  # (kT,)
     out = (gathered * weights[:, None]).reshape(k, t, d).sum(axis=0)
 
-    aux = _aux_losses(logits, probs, gate_idx, t, k, e)
+    aux = _aux_losses(logits, probs, gate_idx, cfg, b, s)
     return out.reshape(b, s, d), aux
 
 
@@ -429,12 +544,15 @@ def dense_ffn(params: Dict[str, Array], x: Array, cfg: ModelConfig,
     return h @ params["w_down"].astype(compute_dtype)
 
 
-def dense_ffn_specs(cfg: ModelConfig) -> Dict[str, ParamSpec]:
-    d, f = cfg.d_model, cfg.d_ff
+def _mlp_specs(d: int, f: int, act: str) -> Dict[str, ParamSpec]:
     specs = {
         "w_up": ParamSpec((d, f), ("embed", "mlp")),
         "w_down": ParamSpec((f, d), ("mlp", "embed")),
     }
-    if cfg.mlp_act == "swiglu":
+    if act == "swiglu":
         specs["w_gate"] = ParamSpec((d, f), ("embed", "mlp"))
     return specs
+
+
+def dense_ffn_specs(cfg: ModelConfig) -> Dict[str, ParamSpec]:
+    return _mlp_specs(cfg.d_model, cfg.d_ff, cfg.mlp_act)

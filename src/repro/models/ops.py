@@ -71,6 +71,16 @@ def apply_rope(x: jax.Array, positions: jax.Array,
     return out.astype(x.dtype)
 
 
+def apply_rope_interleaved(x: jax.Array, positions: jax.Array,
+                           theta: float) -> jax.Array:
+    """RoPE over interleaved pairs, as DeepSeek-V3's
+    ``apply_rotary_pos_emb`` applies it: the (even, odd) lanes are first
+    gathered into halves, then rotated as ``apply_rope`` does. The result
+    stays in the halves layout (q and k alike, so q.k is unchanged)."""
+    x = jnp.concatenate([x[..., 0::2], x[..., 1::2]], axis=-1)
+    return apply_rope(x, positions, theta)
+
+
 def apply_mrope(x: jax.Array, positions: jax.Array, theta: float,
                 sections: Tuple[int, ...]) -> jax.Array:
     """Multimodal RoPE (Qwen2-VL): the rotary half-dim is split into

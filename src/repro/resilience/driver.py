@@ -29,6 +29,7 @@ from repro.checkpoint.manager import CheckpointManager
 from repro.core.goodput import GoodputLedger
 from repro.core.ocs import OCSPodScheduler
 from repro.data.pipeline import DataPipeline
+from repro.models.lm import MOE_COUNTERS
 from repro.obs.metrics import MetricsRegistry
 from repro.obs.steptrace import StepTrace
 from repro.obs.trace import SpanTracer
@@ -125,7 +126,10 @@ class ResilientTrainer:
                  ) -> Tuple[PyTree, float, float]:
         """One ``train_step`` call on ``step``'s batch, ending in the
         loss's host sync, under ``batch`` / ``dispatch`` / ``sync``
-        spans. Returns (state, loss, seconds from dispatch to sync)."""
+        spans. The same transfer brings the step's expert-row counts
+        (``moe_held_rows``, ``moe_padded_rows``), which the registry's
+        counters of those names add up. Returns (state, loss, seconds
+        from dispatch to sync)."""
         tr, pid = self.tracer, self._trace_pid
         with tr.span("batch", pid=pid, cat="train"):
             batch = self.pipeline.batch_for_step(step)
@@ -133,8 +137,12 @@ class ResilientTrainer:
         with tr.span("dispatch", pid=pid, cat="train"):
             state, metrics = self.train_step(state, batch)
         with tr.span("sync", pid=pid, cat="train"):
-            loss = float(jax.device_get(metrics["loss"]))
-        return state, loss, time.monotonic() - t0
+            got = jax.device_get({k: metrics[k] for k in
+                                  ("loss", *MOE_COUNTERS) if k in metrics})
+        for k in MOE_COUNTERS:
+            if k in got:
+                self.metrics.counter(k).inc(float(got[k]))
+        return state, float(got["loss"]), time.monotonic() - t0
 
     def run(self, state: PyTree, num_steps: int,
             ledger: Optional[GoodputLedger] = None

@@ -28,6 +28,7 @@ import jax.numpy as jnp
 from repro.models import api
 from repro.models.blocks import ModelContext
 from repro.models.config import ModelConfig
+from repro.models.lm import MOE_COUNTERS
 from repro.optim.optimizers import Optimizer, clip_by_global_norm
 
 Array = jax.Array
@@ -78,10 +79,16 @@ def make_train_step(
               Tuple[Dict[str, Any], Dict[str, Array]]]:
     """``grad_shard``: optional tree-map applying the params' sharding
     constraints to grad-shaped trees (keeps accumulation sharded like the
-    parameters rather than whatever propagation picks)."""
+    parameters rather than whatever propagation picks).
+
+    The step's metrics are the loss, cross entropy, token count and
+    gradient norm; an expert model's add ``moe_held_rows`` (assignments
+    routed to the held experts) and ``moe_padded_rows`` (rows the grouped
+    kernels computed for them), summed over layers and microbatches."""
     if grad_shard is None:
         grad_shard = lambda tree: tree  # noqa: E731
     n = settings.microbatches
+    counted = MOE_COUNTERS if cfg.n_experts else ()
 
     def total_loss(params_acc, batch):
         # params_acc: params cast to accum dtype — grads inherit this dtype.
@@ -95,12 +102,12 @@ def make_train_step(
             loss, metrics = api.loss_fn(params_acc, mb, cfg, ctx)
             m = {"loss": metrics["loss"] / n, "xent": metrics["xent"] / n,
                  "tokens": metrics["tokens"]}
+            m.update({k: metrics[k] for k in counted})
             return acc + loss / n, m
 
         loss, ms = jax.lax.scan(jax.checkpoint(body),
                                 jnp.zeros((), jnp.float32), mbs)
-        return loss, {"loss": ms["loss"].sum(), "xent": ms["xent"].sum(),
-                      "tokens": ms["tokens"].sum()}
+        return loss, {k: v.sum() for k, v in ms.items()}
 
     grad_fn = jax.value_and_grad(total_loss, has_aux=True)
 
@@ -124,6 +131,7 @@ def make_train_step(
                      "step": state["step"] + 1}
         out_metrics = {"loss": metrics["loss"], "xent": metrics["xent"],
                        "tokens": metrics["tokens"], "grad_norm": gnorm}
+        out_metrics.update({k: metrics[k] for k in counted})
         return new_state, out_metrics
 
     return train_step

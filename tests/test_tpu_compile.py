@@ -178,3 +178,38 @@ def test_bf16_kernels_under_highest_precision(chip, kernel):
     fn, shapes = calls[kernel]
     with jax.default_matmul_precision("highest"):
         assert "tpu_custom_call" in _compile(chip, fn, *shapes)
+
+
+def test_flash_attention_mla_train(chip):
+    """Moonlight-16B-A3B's training attention: q/k at 192, v at 128, 16
+    heads at S 8192 (f32, the default precision): forward and both
+    backward kernels, at the 512 x 512 blocks the VMEM count picks."""
+    f32, bf16 = jnp.float32, jnp.bfloat16
+    assert flash_attention.flash_block(8192, 192, f32, bf16, 128) == 512
+
+    def fwd_bwd(q, k, v, do):
+        o, back = jax.vjp(flash_attention.flash_attention, q, k, v)
+        return (o,) + back(do)
+
+    qk, vo = ((16, 8192, 192), f32), ((16, 8192, 128), f32)
+    hlo = _compile(chip, fwd_bwd, qk, qk, vo, vo)
+    for name in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"):
+        assert name in hlo, name
+
+
+def test_grouped_gemm_train_moonlight(chip):
+    """The held experts' GEMMs of a Moonlight MoE layer (8 experts of
+    2048 x 1408, f32) over one dispatch's worst-case buffer of 2048
+    tokens x 6 rows at block_m 128: the forward and its custom VJP (dx
+    through ``gmm`` on the transposed weights, dw through ``gmm_dw``)."""
+    m, d, f, e = 2048 * 6 + 8 * 128, 2048, 1408, 8
+
+    def fwd_bwd(x, w, g, dy):
+        y, back = jax.vjp(lambda x, w: moe_gemm.grouped_matmul(x, w, g),
+                          x, w)
+        return (y,) + back(dy)
+
+    hlo = _compile(chip, fwd_bwd, ((m, d), jnp.float32),
+                   ((e, d, f), jnp.float32), ((m // 128,), jnp.int32),
+                   ((m, f), jnp.float32))
+    assert "gmm_dw" in hlo and "gmm" in hlo

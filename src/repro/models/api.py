@@ -24,10 +24,14 @@ def model_specs(cfg: ModelConfig) -> Dict[str, Any]:
     return lm.lm_specs(cfg)
 
 
-def loss_fn(params, batch, cfg: ModelConfig, ctx: ModelContext):
+def loss_fn(params, batch, cfg: ModelConfig, ctx: ModelContext,
+            remat_head: bool = False):
+    """``remat_head``: the head's logits are recomputed in the backward,
+    never kept as residuals (``lm.lm_loss``, ``encdec.encdec_loss``)."""
     if cfg.is_encoder_decoder:
-        return encdec.encdec_loss(params, batch, cfg, ctx)
-    return lm.lm_loss(params, batch, cfg, ctx)
+        return encdec.encdec_loss(params, batch, cfg, ctx,
+                                  remat_head=remat_head)
+    return lm.lm_loss(params, batch, cfg, ctx, remat_head=remat_head)
 
 
 def cache_spec(cfg: ModelConfig, batch: int, window: int,

@@ -114,8 +114,11 @@ def encode(params, enc_feats: Array, cfg: ModelConfig,
     return _ln(params["enc_norm"], x, cfg.norm_eps)
 
 
-def encdec_loss(params, batch, cfg: ModelConfig, ctx: ModelContext
-                ) -> Tuple[Array, Dict[str, Array]]:
+def encdec_loss(params, batch, cfg: ModelConfig, ctx: ModelContext,
+                remat_head: bool = False) -> Tuple[Array, Dict[str, Array]]:
+    """``remat_head`` runs the final norm, the logits and the cross
+    entropy under a ``jax.checkpoint`` of their own, as ``lm.lm_loss``
+    does."""
     tokens, labels = batch["tokens"], batch["labels"]
     b, s = tokens.shape
     enc_out = encode(params, batch["enc_feats"], cfg, ctx)
@@ -136,11 +139,16 @@ def encdec_loss(params, batch, cfg: ModelConfig, ctx: ModelContext
         return x, None
 
     x, _ = jax.lax.scan(jax.checkpoint(body), x, params["dec_blocks"])
-    x = _ln(params["final_norm"], x, cfg.norm_eps)
-    logits = ctx.shard(x @ params["lm_head"].astype(ctx.compute_dtype),
-                       ("batch", "seq", "vocab"))
-    loss, count = softmax_cross_entropy(logits, labels,
-                                        batch.get("loss_mask"))
+    mask = batch.get("loss_mask")
+
+    def head(hp, x):
+        x = _ln(hp["final_norm"], x, cfg.norm_eps)
+        logits = ctx.shard(x @ hp["lm_head"].astype(ctx.compute_dtype),
+                           ("batch", "seq", "vocab"))
+        return softmax_cross_entropy(logits, labels, mask)
+
+    hp = {k: params[k] for k in ("final_norm", "lm_head")}
+    loss, count = (jax.checkpoint(head) if remat_head else head)(hp, x)
     return loss, {"xent": loss, "loss": loss, "tokens": count}
 
 

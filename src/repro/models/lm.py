@@ -66,8 +66,13 @@ def _logits(params: Dict[str, Any], x: Array, cfg: ModelConfig,
 
 
 def lm_loss(params: Dict[str, Any], batch: Dict[str, Array],
-            cfg: ModelConfig, ctx: ModelContext
+            cfg: ModelConfig, ctx: ModelContext, remat_head: bool = False
             ) -> Tuple[Array, Dict[str, Array]]:
+    """``remat_head`` runs the final norm, the logits and the cross
+    entropy under a ``jax.checkpoint`` of their own, so that the logits
+    are recomputed in the backward and never kept as residuals (the
+    train step asks for it when it keeps each microbatch's layer
+    inputs: with more than one microbatch)."""
     tokens, labels = batch["tokens"], batch["labels"]
     mrope = batch.get("positions")
     x = embed_lookup(params["embed"], tokens, ctx.compute_dtype)
@@ -85,10 +90,15 @@ def lm_loss(params: Dict[str, Any], batch: Dict[str, Array],
     for name, c in layer_stacks(cfg):
         x, auxs = jax.lax.scan(jax.checkpoint(stack_body(c)), x,
                                params[name])
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-    logits = _logits(params, x, cfg, ctx)
     mask = batch.get("loss_mask")
-    loss, count = softmax_cross_entropy(logits, labels, mask)
+
+    def head(hp, x):
+        x = rms_norm(x, hp["final_norm"], cfg.norm_eps)
+        return softmax_cross_entropy(_logits(hp, x, cfg, ctx), labels, mask)
+
+    hp = {k: params[k] for k in
+          ("final_norm", "embed" if cfg.tie_embeddings else "lm_head")}
+    loss, count = (jax.checkpoint(head) if remat_head else head)(hp, x)
     metrics = {"xent": loss, "tokens": count}
     total = loss
     for key, w in AUX_WEIGHTS.items():

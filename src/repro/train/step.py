@@ -1,10 +1,19 @@
 """Train-step factory: microbatched grad accumulation, clipping, optimizer.
 
 Microbatching is implemented *inside the differentiated function*: the loss
-scans over microbatches with ``jax.checkpoint`` around the body, so scan-AD
-itself accumulates parameter gradients in a single buffer (measured: the
-manual accumulate-outside-grad formulation kept 3 fp32 grad trees alive in
-the loop carry on this XLA build — 3x the memory).
+scans over microbatches, so scan-AD itself accumulates parameter gradients
+in a single buffer (measured: the manual accumulate-outside-grad
+formulation kept 3 fp32 grad trees alive in the loop carry on this XLA
+build — 3x the memory).
+
+Each layer runs under its own ``jax.checkpoint`` (``models.lm``,
+``models.encdec``), so a microbatch's residuals are its layer inputs. The
+step keeps them from the forward pass, and the head's logits are
+recomputed under a checkpoint of their own: every layer runs forward twice
+a step. The microbatch loop is unrolled two microbatches at a time: rolled,
+the v5e compiler's buffers for a two-microbatch 8k-token MoE step
+(Moonlight's block) took 1.16 GiB more, past the chip's 16 GB. Two is the
+only count measured; a longer loop keeps two copies of the body.
 
 Gradient dtype = accumulation dtype is controlled by casting parameters at
 the loss boundary (forward compute casts to bf16 at use regardless), so
@@ -99,14 +108,15 @@ def make_train_step(
         mbs = split_microbatches(batch, n)
 
         def body(acc, mb):
-            loss, metrics = api.loss_fn(params_acc, mb, cfg, ctx)
+            loss, metrics = api.loss_fn(params_acc, mb, cfg, ctx,
+                                        remat_head=True)
             m = {"loss": metrics["loss"] / n, "xent": metrics["xent"] / n,
                  "tokens": metrics["tokens"]}
             m.update({k: metrics[k] for k in counted})
             return acc + loss / n, m
 
-        loss, ms = jax.lax.scan(jax.checkpoint(body),
-                                jnp.zeros((), jnp.float32), mbs)
+        loss, ms = jax.lax.scan(body, jnp.zeros((), jnp.float32), mbs,
+                                unroll=min(n, 2))
         return loss, {k: v.sum() for k, v in ms.items()}
 
     grad_fn = jax.value_and_grad(total_loss, has_aux=True)

@@ -12,6 +12,7 @@ import pytest
 
 from repro.checkpoint.manager import CheckpointManager
 from repro.configs.registry import get_smoke
+from repro.core.hlo_analysis import analyze_compiled_text
 from repro.core.sdc import FBIST, FaultModel, faulty_wrap
 from repro.data.pipeline import DataConfig, DataPipeline
 from repro.launch.train import build_trainer
@@ -79,6 +80,43 @@ def test_microbatch_equivalence():
     for a, b in zip(l1, l4):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    rtol=2e-3, atol=2e-4)
+
+
+def _dot_flops(fn, *args):
+    """Executed dot FLOPs of ``fn`` compiled for ``args`` (loop trips
+    counted)."""
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    return analyze_compiled_text(text, (1,), ("data",)).flops
+
+
+@pytest.mark.parametrize("arch",
+                         ["qwen2_0_5b", "kimi_k2_1t_a32b", "whisper_small"])
+def test_microbatches_keep_layer_inputs(arch):
+    """Two microbatches give the one-microbatch step's loss and updated
+    parameters, and run each layer's forward no more often: beside the
+    one-microbatch step they add the head's recompute, well under half a
+    forward pass, where rerunning each microbatch's forward in its
+    backward adds a whole one."""
+    cfg = get_smoke(arch)
+    opt = adamw(cosine_schedule(1e-3, 10, 1000))
+    state = init_train_state(jax.random.key(0), cfg, opt)
+    pipe = DataPipeline(DataConfig(global_batch=4, seq_len=32,
+                                   vocab_size=cfg.vocab_size), cfg)
+    batch = to_jax(pipe.batch_for_step(0))
+    out = {}
+    for n in (1, 2):
+        step = make_train_step(cfg, CTX, opt, TrainSettings(microbatches=n))
+        new, m = jax.jit(step)(state, batch)
+        out[n] = (_dot_flops(step, state, batch), float(m["loss"]),
+                  jax.tree.leaves(new["params"]))
+    forward = _dot_flops(lambda p, b: api.loss_fn(p, b, cfg, CTX)[0],
+                         state["params"], batch)
+    (f1, loss1, params1), (f2, loss2, params2) = out[1], out[2]
+    np.testing.assert_allclose(loss2, loss1, rtol=1e-5)
+    for a, b in zip(params1, params2):
+        np.testing.assert_allclose(np.asarray(b), np.asarray(a),
+                                   rtol=2e-3, atol=2e-4)
+    assert f2 - f1 < 0.5 * forward, (f1, f2, forward)
 
 
 def test_grad_clip():

@@ -50,6 +50,19 @@ class GoodputLedger:
     def record_idle(self, seconds: float, note: str = "") -> None:
         self._record("idle", seconds, 0, note)
 
+    def void_tail(self, seconds: float) -> None:
+        """Take ``seconds`` back off the newest events, newest first: time
+        charged in advance for a window that an interruption cut short
+        never happened. A shortened event keeps its share of its steps."""
+        for e in reversed(self.events):
+            if seconds <= 0:
+                break
+            cut = min(seconds, e.seconds)
+            if e.steps:
+                e.steps = int(e.steps * (e.seconds - cut) / e.seconds)
+            e.seconds -= cut
+            seconds -= cut
+
     def _record(self, kind: str, seconds: float, steps: int, note: str) -> None:
         if seconds < 0:
             raise ValueError("negative duration")

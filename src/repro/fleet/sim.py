@@ -228,6 +228,13 @@ class FleetSimulator:
         job.base_step = cur
         job.segment_start = t0
 
+    def _cut_window(self, job: JobRuntime, now: float) -> None:
+        """An interruption before ``segment_start`` lands in a window the
+        ledger charged in advance (detect/restore/rework, a write stall):
+        the rest of that window never happens, so it comes off the ledger
+        before the interruption's own charges go on."""
+        job.ledger.void_tail(job.segment_start - now)
+
     def _schedule_segment(self, job: JobRuntime) -> None:
         """(Re)issue the job's timeline events from the current segment.
         Bumps the epoch so events from the previous timeline are stale."""
@@ -353,6 +360,7 @@ class FleetSimulator:
             self._settle_ckpt(job, now)
             steps_now = job.steps_at(now)
             self._charge_progress(job, steps_now)
+            self._cut_window(job, now)
             # the in-flight step fraction is abandoned by the re-shard:
             # charge it with the snapshot (it is wall time already spent)
             # but only the write itself delays the new timeline
@@ -454,6 +462,7 @@ class FleetSimulator:
         self._settle_ckpt(job, now)
         steps_now = job.steps_at(now)
         self._charge_progress(job, steps_now)
+        self._cut_window(job, now)
         self._abort_write(job)  # a write in flight is lost with the slice
         # a stochastic failure lands mid-step: the aborted in-flight
         # fraction is wall time too, folded into the detection charge
@@ -805,6 +814,7 @@ class FleetSimulator:
         self._settle_ckpt(job, now)
         steps_now = job.steps_at(now)
         self._charge_progress(job, steps_now)
+        self._cut_window(job, now)
         self._abort_write(job)  # an in-flight snapshot is poisoned too
         # every checkpoint since the corruption is poisoned: roll back to
         # the newest snapshot at or before the corruption step

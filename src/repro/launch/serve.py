@@ -98,8 +98,7 @@ def dump_telemetry(engine, args) -> None:
 def build_engine(cfg: ModelConfig, *, window: int, max_batch: int = 4,
                  chunk: int = 8, page_size: int = 16, draft_k: int = 0,
                  prefix_cache: bool = True, prefill_chunk: int = 128,
-                 kv_int8: bool = False, moe_dispatch: str = "grouped",
-                 temperature: float = 0.0, mesh=None,
+                 kv_int8: bool = False, temperature: float = 0.0, mesh=None,
                  rules: str = "baseline_dp_tp", disaggregate: bool = False,
                  prefill_workers: int = 1, link: str = "ici",
                  tracer=None, faults=None, admission=None,
@@ -122,8 +121,7 @@ def build_engine(cfg: ModelConfig, *, window: int, max_batch: int = 4,
     ctx = ModelContext(
         compute_dtype=jnp.float32, q_chunk=1024, mamba_chunk=16,
         rwkv_chunk=8, attn_impl=attn_impl,
-        decode_cache_dtype=jnp.int8 if kv_int8 else None,
-        moe_dispatch=moe_dispatch)
+        decode_cache_dtype=jnp.int8 if kv_int8 else None)
     paged = api.supports_paged_decode(cfg)
     return ServeEngine(cfg, ctx, window=window, max_batch=max_batch,
                        chunk=chunk, page_size=page_size,
@@ -159,11 +157,6 @@ def main() -> None:
                          "window; final partial chunk buckets to pow2)")
     ap.add_argument("--quantize", choices=["none", "int8", "fp8"],
                     default="none")
-    ap.add_argument("--moe-dispatch", choices=["grouped", "capacity"],
-                    default="grouped",
-                    help="MoE serving dispatch: sort-based dropless "
-                         "grouped GEMM (default) or the dense capacity "
-                         "buffer (legacy)")
     ap.add_argument("--kv-int8", action="store_true",
                     help="int8 paged KV pages (attention archs)")
     ap.add_argument("--temperature", type=float, default=0.0)
@@ -247,8 +240,8 @@ def main() -> None:
         page_size=args.page_size, draft_k=args.draft_k,
         prefix_cache=not args.no_prefix_cache,
         prefill_chunk=args.prefill_chunk, kv_int8=args.kv_int8,
-        moe_dispatch=args.moe_dispatch, temperature=args.temperature,
-        mesh=mesh, rules=args.rules, disaggregate=args.disaggregate,
+        temperature=args.temperature, mesh=mesh, rules=args.rules,
+        disaggregate=args.disaggregate,
         prefill_workers=args.prefill_workers, link=args.link,
         tracer=tracer, faults=faults, admission=admission)
     mode = "paged" if engine.paged else "dense"

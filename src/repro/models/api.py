@@ -101,24 +101,17 @@ def paged_state_spec(cfg: ModelConfig, num_pages: int, page_size: int,
                                   max_pages_per_seq, ctx)
 
 
-def decode_paged_fn(params, token, state, cfg: ModelConfig,
-                    ctx: ModelContext):
-    """One decode step against the paged KV pool (see blocks.py)."""
-    if not supports_paged_decode(cfg):
-        raise ValueError(f"{cfg.name}: no paged decode for this family")
-    return lm.lm_decode_step_paged(params, token, state, cfg, ctx)
-
-
 def decode_span_paged_fn(params, tokens, state, cfg: ModelConfig,
                          ctx: ModelContext, valid=None, logits_at=None,
                          mrope_positions=None):
     """T-token span decode against the paged pool: one batched paged-
-    attention call scores T consecutive tokens per request (speculative
-    draft-verify; suffix/chunked prefill). ``logits_at`` (B,) gathers a
-    single position's logits before the lm head (prefill chunks);
-    ``mrope_positions`` (3,B,T) carries explicit multimodal rope rows
-    (None = text default); ``pos`` in the returned state is unchanged —
-    the caller owns acceptance/rollback (see lm.lm_decode_span_paged)."""
+    attention call scores T consecutive tokens per request (plain decode
+    at T = 1; speculative draft-verify; suffix/chunked prefill).
+    ``logits_at`` (B,) gathers a single position's logits before the lm
+    head (prefill chunks); ``mrope_positions`` (3,B,T) carries explicit
+    multimodal rope rows (None = text default); ``pos`` in the returned
+    state is unchanged — the caller owns acceptance/rollback (see
+    lm.lm_decode_span_paged)."""
     if not supports_paged_decode(cfg):
         raise ValueError(f"{cfg.name}: no paged decode for this family")
     return lm.lm_decode_span_paged(params, tokens, state, cfg, ctx,

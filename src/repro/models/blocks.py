@@ -50,7 +50,6 @@ class ModelContext:
                  decode_cache_dtype=None, full_cache_window: bool = False,
                  mesh=None, data_axis: str = "data",
                  model_axis: str = "model",
-                 moe_dispatch: str = "grouped",
                  moe_impl: Optional[str] = None,
                  metrics=None):
         self.compute_dtype = compute_dtype
@@ -70,11 +69,8 @@ class ModelContext:
         self.mesh = mesh
         self.data_axis = data_axis
         self.model_axis = model_axis
-        # MoE dispatch, training and serving alike: "grouped" (sort-based
-        # dropless through the m-grouped GEMM kernel; the default) or
-        # "capacity" (the dense dropless buffer, kept as the tests'
-        # oracle). moe_impl=None derives the kernel impl from attn_impl.
-        self.moe_dispatch = moe_dispatch
+        # the MoE kernel body, training and serving alike (moe_ffn's sort-
+        # based grouped dispatch); None derives it from attn_impl
         self.moe_impl = moe_impl
         # optional obs.metrics registry: the trace-time counters of which
         # attention path a compiled program lowered through
@@ -85,10 +81,8 @@ class ModelContext:
         return self.decode_cache_dtype or self.compute_dtype
 
     def moe_kwargs(self) -> Dict[str, Any]:
-        """moe_ffn kwargs for this context (dropless): the compiled
-        kernel takes MXU-aligned m-tiles."""
-        if self.moe_dispatch != "grouped":
-            return {"dropless": True}
+        """moe_ffn kwargs for this context (grouped, dropless): the
+        compiled kernel takes MXU-aligned m-tiles."""
         impl = self.moe_impl or {"pallas": "pallas",
                                  "pallas_interpret": "interpret"}.get(
                                      self.attn_impl, "ref")
@@ -266,7 +260,6 @@ def sublayer_forward(p: Dict[str, Any], x: Array, cfg: ModelConfig,
                      ) -> Tuple[Array, Dict[str, Array]]:
     kind = cfg.sublayer_kinds()[idx]
     dtype = ctx.compute_dtype
-    aux: Dict[str, Array] = {}
     h = rms_norm(x, p["ln1"], cfg.norm_eps)
     if kind == "attn":
         core = attn_forward(p["core"], h, cfg, ctx,
@@ -278,30 +271,57 @@ def sublayer_forward(p: Dict[str, Any], x: Array, cfg: ModelConfig,
         core = rwkv_time_mix(p["core"], h, cfg, dtype, chunk=ctx.rwkv_chunk)
     x = x + core
     x = ctx.shard(x, ("batch", "act_seq", "embed"))
-    h = rms_norm(x, p["ln2"], cfg.norm_eps)
-    if kind == "rwkv":
-        mlp = rwkv_channel_mix(p["mlp"], h, cfg, dtype)
-    elif cfg.sublayer_has_moe(idx):
-        mlp, aux = moe_ffn(p["mlp"], h, cfg, dtype, shard=ctx.shard,
-                           with_counts=True, **ctx.moe_kwargs())
-    else:
-        mlp = dense_ffn(p["mlp"], h, cfg, dtype)
-    x = x + mlp
+    x, _, aux = _ffn_half(p, x, cfg, ctx, idx, with_counts=True)
     x = ctx.shard(x, ("batch", "act_seq", "embed"))
     return x, aux
 
 
-def block_forward(block_params: Dict[str, Any], x: Array, cfg: ModelConfig,
-                  ctx: ModelContext,
-                  mrope_positions: Optional[Array] = None
-                  ) -> Tuple[Array, Dict[str, Array]]:
-    aux_total: Dict[str, Array] = {}
+def _ffn_half(p: Dict[str, Any], x: Array, cfg: ModelConfig,
+              ctx: ModelContext, idx: int, *, cm_prev: Optional[Array] = None,
+              with_counts: bool = False, live: Optional[Array] = None
+              ) -> Tuple[Array, Dict[str, Array], Dict[str, Array]]:
+    """The second half of sublayer ``idx``: norm -> RWKV channel mix, MoE
+    or dense FFN -> residual. ``cm_prev`` is the channel mix's carried
+    token (None: a sequence start); ``live`` (B,T) zeroes the FFN output
+    at a span's dead positions. Returns (x, the channel mix's cache
+    entry — ``{"cm_tok": ...}``, empty for other kinds — and the MoE's
+    aux dict)."""
+    dtype = ctx.compute_dtype
+    h = rms_norm(x, p["ln2"], cfg.norm_eps)
+    state, aux = {}, {}
+    if cfg.sublayer_kinds()[idx] == "rwkv":
+        mlp, state["cm_tok"] = rwkv_channel_mix(
+            p["mlp"], h, cfg, dtype, prev=cm_prev, return_state=True)
+    elif cfg.sublayer_has_moe(idx):
+        mlp, aux = moe_ffn(p["mlp"], h, cfg, dtype, shard=ctx.shard,
+                           with_counts=with_counts, **ctx.moe_kwargs())
+    else:
+        mlp = dense_ffn(p["mlp"], h, cfg, dtype)
+    if live is not None:
+        mlp = jnp.where(live[..., None], mlp, 0.0).astype(dtype)
+    return x + mlp, state, aux
+
+
+def block_apply(sublayer: Callable[[Dict[str, Any], Array, Any, int],
+                                   Tuple[Array, Any]],
+                block_params: Dict[str, Any], x: Array, cfg: ModelConfig,
+                cache: Optional[Dict[str, Any]] = None
+                ) -> Tuple[Array, Dict[str, Any]]:
+    """One block: ``sublayer(p, x, its cache or None, idx)`` for each of
+    the ``cfg.block_len`` sublayers in order. With a ``cache`` the
+    sublayers' new caches come back keyed like it; without one, their aux
+    dicts come back summed."""
+    out: Dict[str, Any] = {}
     for i in range(cfg.block_len):
-        x, aux = sublayer_forward(block_params[f"sl{i}"], x, cfg, ctx, i,
-                                  mrope_positions)
-        for key, val in aux.items():
-            aux_total[key] = aux_total.get(key, 0.0) + val
-    return x, aux_total
+        name = f"sl{i}"
+        x, res = sublayer(block_params[name], x,
+                          None if cache is None else cache[name], i)
+        if cache is not None:
+            out[name] = res
+            continue
+        for key, val in res.items():
+            out[key] = out.get(key, 0.0) + val
+    return x, out
 
 
 # ---------------------------------------------------------------------------
@@ -417,20 +437,9 @@ def sublayer_prefill(p, x, cache, cfg: ModelConfig, ctx: ModelContext, idx,
             p["core"], h, cfg, dtype, chunk=ctx.rwkv_chunk,
             return_state=True)
         new_cache = {"tok": tok, "wkv": wkv}
-    x = x + core
-    h = rms_norm(x, p["ln2"], cfg.norm_eps)
-    if kind == "rwkv":
-        mlp, cm_tok = rwkv_channel_mix(p["mlp"], h, cfg, dtype,
-                                       return_state=True)
-        new_cache["cm_tok"] = cm_tok
-    elif cfg.sublayer_has_moe(idx):
-        mlp, _ = moe_ffn(p["mlp"], h, cfg, dtype, shard=ctx.shard,
-                         **ctx.moe_kwargs())
-    else:
-        mlp = dense_ffn(p["mlp"], h, cfg, dtype)
-    x = x + mlp
+    x, cm, _ = _ffn_half(p, x + core, cfg, ctx, idx)
     x = ctx.shard(x, ("batch", "act_seq", "embed"))
-    return x, new_cache
+    return x, {**new_cache, **cm}
 
 
 # -- decode: one token against caches ---------------------------------------
@@ -471,40 +480,9 @@ def sublayer_decode(p, x, cache, pos, cfg: ModelConfig, ctx: ModelContext,
             p["core"], h, cfg, dtype, chunk=1,
             init_state=(cache["tok"], cache["wkv"]), return_state=True)
         new_cache = {"tok": tok, "wkv": wkv}
-    x = x + core
-    h = rms_norm(x, p["ln2"], cfg.norm_eps)
-    if kind == "rwkv":
-        mlp, cm_tok = rwkv_channel_mix(p["mlp"], h, cfg, dtype,
-                                       prev=cache["cm_tok"],
-                                       return_state=True)
-        new_cache["cm_tok"] = cm_tok
-    elif cfg.sublayer_has_moe(idx):
-        mlp, _ = moe_ffn(p["mlp"], h, cfg, dtype, shard=ctx.shard,
-                         **ctx.moe_kwargs())
-    else:
-        mlp = dense_ffn(p["mlp"], h, cfg, dtype)
-    x = x + mlp
-    return x, new_cache
-
-
-def block_prefill(block_params, x, cache, cfg, ctx, mrope_positions=None,
-                  seq_mask=None):
-    new_cache = {}
-    for i in range(cfg.block_len):
-        x, new_cache[f"sl{i}"] = sublayer_prefill(
-            block_params[f"sl{i}"], x, cache[f"sl{i}"], cfg, ctx, i,
-            mrope_positions, seq_mask)
-    return x, new_cache
-
-
-def block_decode(block_params, x, cache, pos, cfg, ctx,
-                 mrope_positions=None):
-    new_cache = {}
-    for i in range(cfg.block_len):
-        x, new_cache[f"sl{i}"] = sublayer_decode(
-            block_params[f"sl{i}"], x, cache[f"sl{i}"], pos, cfg, ctx, i,
-            mrope_positions)
-    return x, new_cache
+    x, cm, _ = _ffn_half(p, x + core, cfg, ctx, idx,
+                         cm_prev=cache.get("cm_tok"))
+    return x, {**new_cache, **cm}
 
 
 # -- dense span decode: T consecutive tokens against per-slot caches --------
@@ -568,30 +546,9 @@ def sublayer_decode_span(p, x, cache, pos, live, cfg: ModelConfig,
     # query's attention output is garbage (all-masked softmax) and would
     # otherwise leak into the next sublayer's conv window
     core = jnp.where(live[..., None], core, 0.0).astype(dtype)
-    x = x + core
-    h = rms_norm(x, p["ln2"], cfg.norm_eps)
-    if kind == "rwkv":
-        mlp, cm_tok = rwkv_channel_mix(p["mlp"], h, cfg, dtype,
-                                       prev=cache["cm_tok"],
-                                       return_state=True)
-        new_cache["cm_tok"] = cm_tok
-    elif cfg.sublayer_has_moe(idx):
-        mlp, _ = moe_ffn(p["mlp"], h, cfg, dtype, shard=ctx.shard,
-                         **ctx.moe_kwargs())
-    else:
-        mlp = dense_ffn(p["mlp"], h, cfg, dtype)
-    x = x + jnp.where(live[..., None], mlp, 0.0).astype(dtype)
-    return x, new_cache
-
-
-def block_decode_span(block_params, x, cache, pos, live, cfg, ctx,
-                      mrope_positions=None):
-    new_cache = {}
-    for i in range(cfg.block_len):
-        x, new_cache[f"sl{i}"] = sublayer_decode_span(
-            block_params[f"sl{i}"], x, cache[f"sl{i}"], pos, live, cfg,
-            ctx, i, mrope_positions)
-    return x, new_cache
+    x, cm, _ = _ffn_half(p, x + core, cfg, ctx, idx,
+                         cm_prev=cache.get("cm_tok"), live=live)
+    return x, {**new_cache, **cm}
 
 
 # -- paged decode: block/paged KV cache (serving) ---------------------------
@@ -693,78 +650,18 @@ def _paged_gather(pages: Dict[str, Array], page_table: Array, dtype
     return kg.reshape(shape), vg.reshape(shape)
 
 
-def sublayer_decode_paged(p, x, pages, page_table, pos, cfg: ModelConfig,
-                          ctx: ModelContext, idx):
-    """One-token decode against the paged pool. x: (B,1,D); pos: (B,)."""
-    dtype = ctx.compute_dtype
-    b = x.shape[0]
-    page_size = pages["k"].shape[1]
-    h = rms_norm(x, p["ln1"], cfg.norm_eps)
-    q, k, v = _project_qkv(p["core"], h, cfg, dtype, pos[:, None])
-    bidx = jnp.arange(b)
-    pid = page_table[bidx, pos // page_size]  # (B,) owning page
-    slot = pos % page_size
-    kq, ks = paged_quantize(k[:, 0], ctx.cache_dtype)
-    vq, vs = paged_quantize(v[:, 0], ctx.cache_dtype)
-    new_pages = dict(pages)
-    new_pages["k"] = pages["k"].at[pid, slot].set(kq)
-    new_pages["v"] = pages["v"].at[pid, slot].set(vq)
-    if ks is not None:
-        new_pages["k_scale"] = pages["k_scale"].at[pid, slot].set(ks)
-        new_pages["v_scale"] = pages["v_scale"].at[pid, slot].set(vs)
-    new_pages = _constrain_pages(new_pages, ctx)
-    if ctx.attn_impl in ("pallas", "pallas_interpret"):
-        # stream pages straight through the scalar-prefetch Pallas kernel
-        # — no HBM materialization of a contiguous per-request cache.
-        # int8 pages stream natively: the (N, P, KV) scale pages ride the
-        # same table entry and dequantize in VMEM, so quantized caches
-        # read half the bytes per token instead of paying a gather.
-        from repro.kernels import ops as kops
-        out = kops.paged_decode_attention(
-            q[:, 0], new_pages["k"], new_pages["v"], page_table, pos + 1,
-            k_scale=new_pages.get("k_scale"),
-            v_scale=new_pages.get("v_scale"),
-            impl=("interpret" if ctx.attn_impl == "pallas_interpret"
-                  else "pallas"),
-            window=cfg.sliding_window, mesh=ctx.mesh,
-            data_axis=ctx.data_axis, model_axis=ctx.model_axis)[:, None]
-    else:
-        # jnp gather-dequant oracle (the correctness contract for the
-        # kernel route; materializes a contiguous per-request view)
-        kg, vg = _paged_gather(new_pages, page_table, dtype)
-        out = decode_attention(q, kg, vg, pos + 1, cfg)
-    core = jnp.einsum("bshk,hkd->bsd", out, p["core"]["wo"].astype(dtype))
-    x = x + core
-    h = rms_norm(x, p["ln2"], cfg.norm_eps)
-    if cfg.sublayer_has_moe(idx):
-        mlp, _ = moe_ffn(p["mlp"], h, cfg, dtype, shard=ctx.shard,
-                         **ctx.moe_kwargs())
-    else:
-        mlp = dense_ffn(p["mlp"], h, cfg, dtype)
-    x = x + mlp
-    return x, new_pages
-
-
-def block_decode_paged(block_params, x, pages, page_table, pos, cfg, ctx):
-    new_pages = {}
-    for i in range(cfg.block_len):
-        x, new_pages[f"sl{i}"] = sublayer_decode_paged(
-            block_params[f"sl{i}"], x, pages[f"sl{i}"], page_table, pos,
-            cfg, ctx, i)
-    return x, new_pages
-
-
 # -- paged span decode: T consecutive tokens in one batched call ------------
 #
-# The datapath behind speculative decoding, prefix-cache suffix prefill,
-# AND chunked cold prefill (every paged serving path is a page-stream now):
-# a span of T tokens per request is scored in ONE paged-attention call —
-# the span's k/v are scattered into the pages first (append-only), then
-# query t attends causally through absolute position pos + t. Rolling back
-# rejected draft tokens is just a position rewind: their k/v stay in the
-# pool as garbage beyond the validity frontier and are overwritten before
-# the frontier ever reaches them (the paper's hardware-replay framing —
-# a deterministic datapath plus a replayable frontier beats bespoke undo).
+# The one paged serving datapath: plain decode (a span of one token),
+# speculative verify, prefix-cache suffix prefill AND chunked cold prefill
+# are all page-streams through it. A span of T tokens per request is
+# scored in ONE paged-attention call — the span's k/v are scattered into
+# the pages first (append-only), then query t attends causally through
+# absolute position pos + t. Rolling back rejected draft tokens is just a
+# position rewind: their k/v stay in the pool as garbage beyond the
+# validity frontier and are overwritten before the frontier ever reaches
+# them (the paper's hardware-replay framing — a deterministic datapath
+# plus a replayable frontier beats bespoke undo).
 
 
 def sublayer_decode_span_paged(p, x, pages, page_table, pos, live,
@@ -773,8 +670,10 @@ def sublayer_decode_span_paged(p, x, pages, page_table, pos, live,
     """T-token span decode against the paged pool.
 
     x: (B,T,D) at absolute positions ``pos .. pos+T-1``; live: (B,T)
-    bool — False marks padded span slots whose writes are routed to the
-    trash page (suffix prefills pad to a bucketed compile length).
+    bool or None (every slot live) — False marks padded span slots whose
+    writes are routed to the trash page (suffix prefills pad to a
+    bucketed compile length). A span of one token is scored by the
+    one-query decode kernel, a longer one by the span kernel.
     ``mrope_positions`` (3,B,T): explicit multimodal rope rows for the
     span (None = text default)."""
     dtype = ctx.compute_dtype
@@ -784,11 +683,16 @@ def sublayer_decode_span_paged(p, x, pages, page_table, pos, live,
     posn = pos[:, None] + jnp.arange(t)[None, :]  # (B, T)
     q, k, v = _project_qkv(p["core"], h, cfg, dtype, posn,
                            mrope_positions)
-    bidx = jnp.arange(b)[:, None]
+    bidx, wpos = jnp.arange(b)[:, None], posn
+    if t == 1:  # (B,) indices: (B, 1) ones leave index copies on a TPU
+        bidx, wpos, k, v = bidx[:, 0], posn[:, 0], k[:, 0], v[:, 0]
+        live = None if live is None else live[:, 0]
     # page-table reads beyond the row clamp; dead slots write to trash 0
-    pid = jnp.where(live, page_table[bidx, posn // page_size], 0)
-    slot = posn % page_size
-    kq, ks = paged_quantize(k, ctx.cache_dtype)  # (B, T, KV, D)
+    pid = page_table[bidx, wpos // page_size]
+    if live is not None:
+        pid = jnp.where(live, pid, 0)
+    slot = wpos % page_size
+    kq, ks = paged_quantize(k, ctx.cache_dtype)  # (B, [T,] KV, D)
     vq, vs = paged_quantize(v, ctx.cache_dtype)
     new_pages = dict(pages)
     new_pages["k"] = pages["k"].at[pid, slot].set(kq)
@@ -798,37 +702,31 @@ def sublayer_decode_span_paged(p, x, pages, page_table, pos, live,
         new_pages["v_scale"] = pages["v_scale"].at[pid, slot].set(vs)
     new_pages = _constrain_pages(new_pages, ctx)
     if ctx.attn_impl in ("pallas", "pallas_interpret"):
-        # same page stream as single-token decode: int8 scale pages DMA
-        # through the table, dequantize in VMEM — no gather oracle
+        # stream pages straight through the scalar-prefetch Pallas kernels
+        # — no HBM materialization of a contiguous per-request cache.
+        # int8 pages stream natively: the (N, P, KV) scale pages ride the
+        # same table entry and dequantize in VMEM, so quantized caches
+        # read half the bytes per token instead of paying a gather.
         from repro.kernels import ops as kops
-        out = kops.paged_decode_span_attention(
-            q, new_pages["k"], new_pages["v"], page_table, pos,
-            k_scale=new_pages.get("k_scale"),
-            v_scale=new_pages.get("v_scale"),
-            impl=("interpret" if ctx.attn_impl == "pallas_interpret"
-                  else "pallas"),
-            window=cfg.sliding_window, mesh=ctx.mesh,
-            data_axis=ctx.data_axis, model_axis=ctx.model_axis)
+        kw = dict(k_scale=new_pages.get("k_scale"),
+                  v_scale=new_pages.get("v_scale"),
+                  impl=("interpret" if ctx.attn_impl == "pallas_interpret"
+                        else "pallas"),
+                  window=cfg.sliding_window, mesh=ctx.mesh,
+                  data_axis=ctx.data_axis, model_axis=ctx.model_axis)
+        if t == 1:
+            out = kops.paged_decode_attention(
+                q[:, 0], new_pages["k"], new_pages["v"], page_table,
+                pos + 1, **kw)[:, None]
+        else:
+            out = kops.paged_decode_span_attention(
+                q, new_pages["k"], new_pages["v"], page_table, pos, **kw)
     else:
+        # jnp gather-dequant oracle (the correctness contract for the
+        # kernel route; materializes a contiguous per-request view)
         kg, vg = _paged_gather(new_pages, page_table, dtype)
-        out = decode_span_attention(q, kg, vg, pos, cfg)
+        out = (decode_attention(q, kg, vg, pos + 1, cfg) if t == 1
+               else decode_span_attention(q, kg, vg, pos, cfg))
     core = jnp.einsum("bshk,hkd->bsd", out, p["core"]["wo"].astype(dtype))
-    x = x + core
-    h = rms_norm(x, p["ln2"], cfg.norm_eps)
-    if cfg.sublayer_has_moe(idx):
-        mlp, _ = moe_ffn(p["mlp"], h, cfg, dtype, shard=ctx.shard,
-                         **ctx.moe_kwargs())
-    else:
-        mlp = dense_ffn(p["mlp"], h, cfg, dtype)
-    x = x + mlp
-    return x, new_pages
-
-
-def block_decode_span_paged(block_params, x, pages, page_table, pos, live,
-                            cfg, ctx, mrope_positions=None):
-    new_pages = {}
-    for i in range(cfg.block_len):
-        x, new_pages[f"sl{i}"] = sublayer_decode_span_paged(
-            block_params[f"sl{i}"], x, pages[f"sl{i}"], page_table, pos,
-            live, cfg, ctx, i, mrope_positions)
+    x, _, _ = _ffn_half(p, x + core, cfg, ctx, idx)
     return x, new_pages

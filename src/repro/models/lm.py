@@ -15,11 +15,12 @@ from typing import Any, Dict, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.models.blocks import (ModelContext, block_cache_spec,
-                                 block_decode, block_decode_paged,
-                                 block_decode_span, block_decode_span_paged,
-                                 block_forward, block_prefill, block_specs,
-                                 paged_block_cache_spec, stack_specs)
+from repro.models.blocks import (ModelContext, block_apply,
+                                 block_cache_spec, block_specs,
+                                 paged_block_cache_spec, stack_specs,
+                                 sublayer_decode, sublayer_decode_span,
+                                 sublayer_decode_span_paged, sublayer_forward,
+                                 sublayer_prefill)
 from repro.models.config import ModelConfig
 from repro.models.ops import embed_lookup, rms_norm, softmax_cross_entropy
 from repro.models.params import ParamSpec, ones_init
@@ -82,7 +83,9 @@ def lm_loss(params: Dict[str, Any], batch: Dict[str, Array],
 
     def stack_body(c: ModelConfig):
         def body(x, bp):
-            x, aux = block_forward(bp, x, c, ctx, mrope)
+            x, aux = block_apply(
+                lambda p, x, _, i: sublayer_forward(p, x, c, ctx, i, mrope),
+                bp, x, c)
             return x, {k: jnp.asarray(aux.get(k, 0.0), jnp.float32)
                        for k in keys}
         return body
@@ -167,8 +170,10 @@ def lm_prefill(params: Dict[str, Any], tokens: Array, cfg: ModelConfig,
             block_cache_spec(c, b, window, ctx))
 
         def body(x, bp, c=c, cache0=cache0):
-            return block_prefill(bp, x, cache0, c, ctx, mrope_positions,
-                                 seq_mask=live)
+            return block_apply(
+                lambda p, x, sc, i: sublayer_prefill(
+                    p, x, sc, c, ctx, i, mrope_positions, seq_mask=live),
+                bp, x, c, cache0)
 
         x, caches[name] = jax.lax.scan(jax.checkpoint(body), x,
                                        params[name])
@@ -196,7 +201,9 @@ def lm_decode_step(params: Dict[str, Any], token: Array,
     for name, c in layer_stacks(cfg):
         def body(x, xs, c=c):
             bp, bc = xs
-            return block_decode(bp, x, bc, pos, c, ctx)
+            return block_apply(
+                lambda p, x, sc, i: sublayer_decode(p, x, sc, pos, c, ctx, i),
+                bp, x, c, bc)
 
         x, new[name] = jax.lax.scan(body, x, (params[name], cache[name]))
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)
@@ -248,8 +255,10 @@ def lm_decode_span(params: Dict[str, Any], tokens: Array,
     for name, c in layer_stacks(cfg):
         def body(x, xs, c=c):
             bp, bc = xs
-            return block_decode_span(bp, x, bc, pos, live, c, ctx,
-                                     mrope_positions)
+            return block_apply(
+                lambda p, x, sc, i: sublayer_decode_span(
+                    p, x, sc, pos, live, c, ctx, i, mrope_positions),
+                bp, x, c, bc)
 
         x, new[name] = jax.lax.scan(body, x, (params[name], cache[name]))
     x = _span_logits_slice(x, logits_at)
@@ -277,29 +286,6 @@ def lm_paged_state_spec(cfg: ModelConfig, num_pages: int, page_size: int,
     }
 
 
-def lm_decode_step_paged(params: Dict[str, Any], token: Array,
-                         state: Dict[str, Any], cfg: ModelConfig,
-                         ctx: ModelContext) -> Tuple[Array, Dict[str, Any]]:
-    """token: (B, 1) int32 against the paged pool.
-
-    Returns (logits (B,1,V), new state with pos advanced). Callers that
-    freeze finished requests overwrite ``pos`` afterwards."""
-    pos = state["pos"]
-    table = state["page_table"]
-    x = embed_lookup(params["embed"], token, ctx.compute_dtype)
-    x = ctx.shard(x, ("batch", None, "embed"))
-
-    def body(x, xs):
-        bp, layer_pages = xs
-        x, np_ = block_decode_paged(bp, x, layer_pages, table, pos, cfg, ctx)
-        return x, np_
-
-    x, new_pages = jax.lax.scan(body, x, (params["blocks"], state["pages"]))
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-    logits = _logits(params, x, cfg, ctx)
-    return logits, {"pages": new_pages, "page_table": table, "pos": pos + 1}
-
-
 def lm_decode_span_paged(params: Dict[str, Any], tokens: Array,
                          state: Dict[str, Any], cfg: ModelConfig,
                          ctx: ModelContext,
@@ -307,8 +293,8 @@ def lm_decode_span_paged(params: Dict[str, Any], tokens: Array,
                          logits_at: Optional[Array] = None,
                          mrope_positions: Optional[Array] = None
                          ) -> Tuple[Array, Dict[str, Any]]:
-    """T-token span decode against the paged pool (speculative verify /
-    suffix prefill / chunked cold prefill).
+    """T-token span decode against the paged pool (decode as a span of
+    one / speculative verify / suffix prefill / chunked cold prefill).
 
     tokens: (B,T) int32 at absolute positions ``pos .. pos+T-1``;
     ``valid`` (B,): number of real tokens in the span (default all T) —
@@ -324,18 +310,18 @@ def lm_decode_span_paged(params: Dict[str, Any], tokens: Array,
     ones are simply never covered by it)."""
     pos = state["pos"]
     table = state["page_table"]
-    b, t = tokens.shape
-    if valid is None:
-        valid = jnp.full((b,), t, jnp.int32)
-    live = jnp.arange(t)[None, :] < valid[:, None]  # (B, T)
+    t = tokens.shape[1]
+    live = (None if valid is None
+            else jnp.arange(t)[None, :] < valid[:, None])  # (B, T)
     x = embed_lookup(params["embed"], tokens, ctx.compute_dtype)
     x = ctx.shard(x, ("batch", None, "embed"))
 
     def body(x, xs):
         bp, layer_pages = xs
-        x, np_ = block_decode_span_paged(bp, x, layer_pages, table, pos,
-                                         live, cfg, ctx, mrope_positions)
-        return x, np_
+        return block_apply(
+            lambda p, x, lp, i: sublayer_decode_span_paged(
+                p, x, lp, table, pos, live, cfg, ctx, i, mrope_positions),
+            bp, x, cfg, layer_pages)
 
     x, new_pages = jax.lax.scan(body, x, (params["blocks"], state["pages"]))
     x = _span_logits_slice(x, logits_at)

@@ -358,7 +358,7 @@ class ServeEngine:
             decode_cache_dtype=ctx.decode_cache_dtype,
             full_cache_window=ctx.full_cache_window, mesh=mesh,
             data_axis="data", model_axis="model",
-            moe_dispatch=ctx.moe_dispatch, moe_impl=ctx.moe_impl,
+            moe_impl=ctx.moe_impl,
             metrics=ctx.metrics)
 
     def _note_dropped(self, raw=None) -> None:
@@ -570,7 +570,7 @@ class ServeEngine:
             tok, pos, done, n_out, max_new, key, cache = carry
             if self.paged:
                 state = {"pages": cache, "page_table": table, "pos": pos}
-                logits, new_state = api.decode_paged_fn(
+                logits, new_state = api.decode_span_paged_fn(
                     params, tok, state, cfg, ctx)
                 new_cache = new_state["pages"]
             else:

@@ -136,6 +136,30 @@ def test_goodput_bounds_and_invariants_property(seed, mtbf, njobs):
     assert 0.0 <= fs["min_goodput"] <= 1.0
 
 
+@pytest.mark.parametrize("policy,seed,mtbf,njobs", [
+    ("queue", 8475, 66.0, 3),
+    ("shrink", 5027, 177.61362497447726, 1),
+])
+def test_failure_inside_recovery_window_charges_it_once(policy, seed, mtbf,
+                                                        njobs):
+    """A second failure that lands while the first one's restore/rework is
+    still running cuts that window short: the ledger drops the part that
+    never happened, so it still partitions arrival-to-completion."""
+    cfg = FleetConfig(tpu="tpu_v4", total_cubes=24,
+                      host_mtbf_hours=mtbf, repair_hours=1.0, seed=seed)
+    jobs = [JobSpec(name=f"j{i}", chips=256, total_steps=2000,
+                    step_time_s=1.0, checkpoint_every_steps=100,
+                    scale_policy=policy) for i in range(njobs)]
+    sim = FleetSimulator(cfg, jobs)
+    sim.run(40_000.0)
+    done = [j for j in sim.jobs.values() if j.state == "done"]
+    assert done
+    for job in done:
+        assert job.ledger.total_seconds == pytest.approx(
+            job.completed_at - job.spec.arrival_s)
+        assert job.ledger.effective_steps == job.spec.total_steps
+
+
 def test_reconfigs_do_not_starve_with_spares():
     """Ironwood headline: four 2K-chip jobs on 144 cubes ride through
     failures on 16 spares — substitutions happen, nobody starves."""

@@ -420,7 +420,8 @@ def test_span_kernel_t1_matches_single_token_kernel():
 
 def test_span_decode_matches_sequential_paged_decode(qwen):
     """Model-level: one span call over T tokens reproduces T sequential
-    paged decode steps (logits and written pages)."""
+    paged decode steps — spans of one token, ``pos`` advanced by the
+    caller as the decode chunk does (logits and written pages)."""
     cfg, params = qwen
     b, p_, m, n = 2, 8, 4, 16
     spec = api.paged_state_spec(cfg, n, p_, b, m, CTX)
@@ -433,7 +434,9 @@ def test_span_decode_matches_sequential_paged_decode(qwen):
           "pos": jnp.zeros((b,), jnp.int32)}
     seq_logits = []
     for t in range(5):
-        lg, st = api.decode_paged_fn(params, toks[:, t:t + 1], st, cfg, CTX)
+        lg, st = api.decode_span_paged_fn(params, toks[:, t:t + 1], st,
+                                          cfg, CTX)
+        st = {**st, "pos": st["pos"] + 1}
         seq_logits.append(lg[:, 0])
     seq_logits = jnp.stack(seq_logits, 1)
     st2 = {"pages": pages, "page_table": table,
@@ -447,3 +450,56 @@ def test_span_decode_matches_sequential_paged_decode(qwen):
         np.testing.assert_allclose(np.asarray(a, dtype=np.float32),
                                    np.asarray(bb, dtype=np.float32),
                                    rtol=1e-5, atol=1e-5)
+
+
+def _pallas_kernels(jaxpr) -> list:
+    """Names of the kernel functions of every ``pallas_call`` in a jaxpr,
+    nested jaxprs (scan bodies, shard_maps) included."""
+    from jax.extend import core as jcore
+    names = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            names.append(eqn.params["jaxpr"].debug_info.func_name)
+        for val in eqn.params.values():
+            for sub in (val if isinstance(val, (list, tuple)) else [val]):
+                if isinstance(sub, jcore.ClosedJaxpr):
+                    names += _pallas_kernels(sub.jaxpr)
+                elif isinstance(sub, jcore.Jaxpr):
+                    names += _pallas_kernels(sub)
+    return names
+
+
+@pytest.mark.parametrize("page_dtype", [jnp.bfloat16, jnp.int8],
+                         ids=["bf16", "int8"])
+def test_span_of_one_scores_through_the_decode_kernel(qwen, page_dtype):
+    """A paged span of one token is plain decode: it lowers through the
+    one-query paged decode kernel, not the span kernel, and its logits and
+    written pages match the gather oracle's."""
+    cfg, params = qwen
+    b, p_, m, n = 2, 8, 4, 16
+    ctxs = {impl: ModelContext(compute_dtype=jnp.float32, q_chunk=64,
+                               attn_impl=impl, decode_cache_dtype=page_dtype)
+            for impl in ("pallas_interpret", "xla")}
+    spec = api.paged_state_spec(cfg, n, p_, b, m, ctxs["xla"])
+    rng = np.random.default_rng(17)
+    pages = jax.tree.map(
+        lambda s: jnp.asarray(rng.normal(size=s.shape), s.dtype),
+        spec["pages"])
+    st = {"pages": pages,
+          "page_table": jnp.array([[1, 2, 3, 0], [4, 5, 6, 7]], jnp.int32),
+          "pos": jnp.array([13, 26], jnp.int32)}
+    tok = jnp.asarray(rng.integers(0, cfg.vocab_size, (b, 1)), jnp.int32)
+
+    def run(impl):
+        return api.decode_span_paged_fn(params, tok, st, cfg, ctxs[impl])
+
+    kernels = _pallas_kernels(
+        jax.make_jaxpr(lambda: run("pallas_interpret"))().jaxpr)
+    assert kernels and set(kernels) == {"_paged_decode_kernel"}, kernels
+    (lk, sk), (lo, so) = run("pallas_interpret"), run("xla")
+    np.testing.assert_allclose(np.asarray(lk), np.asarray(lo),
+                               rtol=1e-5, atol=1e-5)
+    for a, bb in zip(jax.tree.leaves(sk["pages"]),
+                     jax.tree.leaves(so["pages"])):
+        np.testing.assert_array_equal(np.asarray(a, dtype=np.float32),
+                                      np.asarray(bb, dtype=np.float32))
